@@ -103,20 +103,27 @@ def test(
     env = make_env(cfg, cfg.seed, 0, run_name=log_dir, prefix="test")()
     cnn_keys = tuple(cfg.algo.cnn_keys.encoder)
     mlp_keys = tuple(cfg.algo.mlp_keys.encoder)
-    key = jax.random.PRNGKey(cfg.seed)
     obs, _ = env.reset(seed=cfg.seed)
     carry = None
     done, cum_reward = False, 0.0
-    while not done:
-        batched = {k: np.asarray(v)[None] for k, v in obs.items()}
-        o = prepare_obs(batched, cnn_keys, mlp_keys)
-        key, sk = jax.random.split(key)
-        carry, env_action = player_step_fn(player_state, carry, o, sk, greedy)
-        obs, reward, terminated, truncated, _ = env.step(
-            actions_for_env(np.asarray(env_action), env.action_space)[0]
-        )
-        done = bool(terminated or truncated)
-        cum_reward += float(reward)
+    # everything the episode creates (key, observations, the first carry) is
+    # uncommitted: make it on the PLAYER's device.  On the default device (the
+    # accelerator, with a host player) the first step would reach player_step
+    # with one placement and every later one, whose carry comes back from the
+    # player's device, with another — a recompile only a chip shows.
+    player_device = next(iter(jax.tree.leaves(player_state)[0].devices()))
+    with jax.default_device(player_device):
+        key = jax.random.PRNGKey(cfg.seed)
+        while not done:
+            batched = {k: np.asarray(v)[None] for k, v in obs.items()}
+            o = prepare_obs(batched, cnn_keys, mlp_keys)
+            key, sk = jax.random.split(key)
+            carry, env_action = player_step_fn(player_state, carry, o, sk, greedy)
+            obs, reward, terminated, truncated, _ = env.step(
+                actions_for_env(np.asarray(env_action), env.action_space)[0]
+            )
+            done = bool(terminated or truncated)
+            cum_reward += float(reward)
     env.close()
     if logger is not None:
         logger.log_metrics({"Test/cumulative_reward": cum_reward}, 0)

@@ -31,12 +31,10 @@ from sheeprl_tpu.algos.dreamer_v3.utils import normalize_obs_block
 from sheeprl_tpu.algos.sac_ae.agent import build_agent
 from sheeprl_tpu.data.buffers import ReplayBuffer
 from sheeprl_tpu.data.device_replay import (
-    DeviceReplay,
-    HostSpill,
-    estimate_step_bytes,
-    fit_hbm_window,
+    build_device_replay,
     fused_uniform_train,
     resolve_device_replay,
+    sampled_bytes,
     steady_guard,
     update_chunks,
 )
@@ -332,29 +330,7 @@ def main(fabric: Any, cfg: Any) -> None:
     capacity = int(cfg.buffer.size) // num_envs
     memmap_dir = os.path.join(log_dir, "memmap_buffer", f"rank_{rank}") if cfg.buffer.memmap else None
     use_device_replay = resolve_device_replay(cfg, fabric.accelerator)
-    if use_device_replay:
-        # next_<k> copies double the obs bytes; actions/reward/flag row tail
-        step_bytes = estimate_step_bytes(
-            obs_space, obs_keys, extra_bytes=4 * (act_dim + 2), copies_per_key=2
-        )
-        hbm_window, spill_needed = fit_hbm_window(
-            capacity, num_envs, step_bytes, cfg.buffer.get("hbm_window")
-        )
-        spill = (
-            HostSpill(capacity, num_envs, memmap=cfg.buffer.memmap, memmap_dir=memmap_dir)
-            if spill_needed
-            else None
-        )
-        rb: Any = DeviceReplay(
-            hbm_window, num_envs, mesh=fabric.mesh, data_axis=fabric.data_axis, spill=spill
-        )
-    else:
-        rb = ReplayBuffer(capacity, num_envs, memmap=cfg.buffer.memmap, memmap_dir=memmap_dir)
-    if state and cfg.buffer.checkpoint and "rb" in state:
-        rb.load_state_dict(state["rb"])
-
     batch_size = int(cfg.algo.per_rank_batch_size) * fabric.local_world_size
-
     train_phase_dev = None
     if use_device_replay:
         def _prep_batch(b):
@@ -375,15 +351,41 @@ def main(fabric: Any, cfg: Any) -> None:
                     out[src] = x.reshape(*x.shape[:2], -1)
             return out
 
-        train_phase_dev = fused_uniform_train(
-            fabric,
-            train_phase,
-            rb,
-            batch_size,
-            _prep_batch,
-            name=f"{cfg.algo.name}.train_phase_device",
-            max_recompiles=cfg.algo.get("max_recompiles"),
+        def _make_fused(ring):
+            return fused_uniform_train(
+                fabric,
+                train_phase,
+                ring,
+                batch_size,
+                _prep_batch,
+                name=f"{cfg.algo.name}.train_phase_device",
+                max_recompiles=cfg.algo.get("max_recompiles"),
+            )
+
+        # the ring's rows, exactly as the loop below stores them: every obs
+        # key also keeps its next_<k> row
+        leaf_specs = {
+            "actions": ((act_dim,), np.float32),
+            "rewards": ((1,), np.float32),
+            "terminated": ((1,), np.float32),
+        }
+        for k in obs_keys:
+            spec = (tuple(obs_space[k].shape) or (1,), obs_space[k].dtype)
+            leaf_specs[k] = leaf_specs[f"next_{k}"] = spec
+        # what Ratio will owe at the first train window (the burst)
+        burst = Ratio(cfg.algo.replay_ratio, pretrain_steps=cfg.algo.per_rank_pretrain_steps)(
+            max(learning_starts, 1) * policy_steps_per_iter / fabric.world_size
         )
+        rb, train_phase_dev = build_device_replay(
+            fabric, cfg, capacity, num_envs, leaf_specs, _make_fused,
+            train_state=(params, opt_state), first_window=burst,
+            batch_bytes=sampled_bytes(leaf_specs, batch_size), memmap_dir=memmap_dir,
+        )
+    else:
+        rb = ReplayBuffer(capacity, num_envs, memmap=cfg.buffer.memmap, memmap_dir=memmap_dir)
+    if state and cfg.buffer.checkpoint and "rb" in state:
+        rb.load_state_dict(state["rb"])
+
     guard_on = bool(cfg.buffer.get("transfer_guard", False)) and use_device_replay
 
     # rank-offset: each process's envs must be distinct streams or

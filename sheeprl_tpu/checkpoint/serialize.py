@@ -54,17 +54,15 @@ def snapshot_tree(tree: Any) -> Any:
     """Point-in-time copy of a live state tree, safe to hand to a writer
     thread while training continues.
 
-    * ``jax.Array`` leaves (including typed PRNG keys): on-device ``.copy()``
-      — an asynchronously-dispatched device op, so this does NOT block on
-      the training step that produces the value.  The copy also breaks the
-      donation alias: the original may be donated to the next jitted update
-      while the copy is fetched at leisure.  On backends where
-      ``block_until_ready`` is trustworthy (cpu / gpu / local tpu) plain
-      arrays are host-fetched HERE instead: ``device_get`` there is a
-      memcpy, while the on-device copy route compiles one tiny XLA program
-      per distinct leaf shape per process — multi-second overhead for a
-      small checkpoint.  Fetching on the caller thread is donation-safe by
-      construction (the value is on host before save() returns).
+    * fully-addressable ``jax.Array`` leaves are host-fetched HERE
+      (``device_get``): donation-safe by construction — the value is on host
+      before save() returns, so the original may be donated to the next
+      jitted update.  (An on-device ``.copy()`` per leaf would compile one
+      tiny XLA program per distinct leaf shape per process — multi-second
+      overhead for a small checkpoint.)
+    * typed PRNG keys and the process-local replica of multi-host arrays
+      take an on-device ``.copy()`` — an asynchronously-dispatched device op
+      that breaks the donation alias; the writer thread fetches it later.
     * numpy leaves: host memcpy (the env loop keeps writing into replay
       storage; the checkpoint must capture THIS step's contents).
     * ``MemmapArray`` leaves: kept as references — their persistence IS the
@@ -73,15 +71,12 @@ def snapshot_tree(tree: Any) -> Any:
       pytree mapping already rebuilds fresh containers.
     """
     from sheeprl_tpu.data.memmap import MemmapArray
-    from sheeprl_tpu.utils.utils import _untrusted_block_until_ready
-
-    fast_host = not _untrusted_block_until_ready()
 
     def leaf(x: Any) -> Any:
         if isinstance(x, MemmapArray):
             return x
         if isinstance(x, jax.Array):
-            if fast_host and x.is_fully_addressable and not _is_key_array(x):
+            if x.is_fully_addressable and not _is_key_array(x):
                 # np.array (not asarray): device_get on the CPU backend can
                 # be zero-copy, and the caller may donate the original
                 # buffer right after save() returns

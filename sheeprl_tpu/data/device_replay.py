@@ -35,6 +35,7 @@ signatures (asserted by ``tests/test_data/test_device_replay.py``).
 from __future__ import annotations
 
 import contextlib
+import math
 import os
 import queue
 import threading
@@ -61,41 +62,212 @@ def resolve_device_replay(cfg: Any, fabric_accelerator: str) -> bool:
     return bool(mode)
 
 
-def estimate_step_bytes(
-    obs_space: Any, obs_keys: Sequence[str], extra_bytes: int = 64, copies_per_key: int = 1
-) -> int:
-    """Per-(env, step) ring bytes estimated from the observation space —
-    sized BEFORE allocation so :func:`fit_hbm_window` can shrink the HBM
-    window (and arm the spill tier) instead of dying in an HBM alloc.
-    ``extra_bytes`` covers actions/rewards/flags; ``copies_per_key`` is 2 for
-    layouts that also store ``next_<k>`` rows (SAC-AE)."""
-    total = int(extra_bytes)
-    for k in obs_keys:
-        space = obs_space[k]
-        total += int(np.prod(space.shape)) * np.dtype(space.dtype).itemsize * int(copies_per_key)
+#: ring row layout: key -> (per-step feature shape, dtype)
+LeafSpecs = Dict[str, Tuple[Tuple[int, ...], Any]]
+
+
+def _zeros_program(shape: Tuple[int, ...], dtype: Any, sharding: Any) -> Any:
+    """THE ring allocation program: zeros born directly in their final
+    placement (never staged whole on device 0 and re-laid).  ``_ensure``
+    runs it; :func:`ring_device_bytes` asks its compiled form for the bytes."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=sharding)
+
+
+def ring_device_bytes(
+    leaf_specs: LeafSpecs, window: int, n_envs: int, sharding: Any = None
+) -> float:
+    """REAL per-device bytes of a ``(window, n_envs, *feat)`` ring: the output
+    size of the compiled allocation.  ``nbytes`` of the abstract shape is not
+    enough — the device layout may pad, and by how much depends on the whole
+    shape, so the question is asked at the size that will be allocated."""
+    import jax
+
+    total = 0
+    for feat, dtype in leaf_specs.values():
+        shape = (int(window), int(n_envs)) + tuple(int(d) for d in feat)
+        try:
+            compiled = _zeros_program(shape, dtype, sharding).lower().compile()
+        except jax.errors.JaxRuntimeError as e:
+            if "RESOURCE_EXHAUSTED" not in str(e):
+                raise
+            return math.inf  # the compiler refuses it outright: larger than the device
+        total += int(compiled.memory_analysis().output_size_in_bytes)
     return total
 
 
+def device_memory(device: Any) -> Tuple[int, int]:
+    """``(bytes_limit, bytes_in_use)`` as the device itself reports them.
+
+    A CPU device reports nothing; its arrays live in host RAM, so its limit
+    is the machine's physical memory (the forced-on CPU test path).  Any
+    other device that reports no limit is an error: the ring is never sized
+    against an assumed capacity."""
+    stats = device.memory_stats() or {}
+    if stats.get("bytes_limit"):
+        return int(stats["bytes_limit"]), int(stats.get("bytes_in_use", 0))
+    if device.platform == "cpu":
+        return int(os.sysconf("SC_PHYS_PAGES")) * int(os.sysconf("SC_PAGE_SIZE")), 0
+    raise RuntimeError(
+        f"{device} reports no memory limit (memory_stats()={stats!r}); the "
+        "device replay ring cannot be sized — run with buffer.device=False"
+    )
+
+
+def program_extra_bytes(compiled: Any) -> int:
+    """Device bytes a compiled program needs BEYOND its resident arguments
+    (XLA's own memory analysis): temporaries, outputs that alias no donated
+    input, and its code."""
+    m = compiled.memory_analysis()
+    return int(
+        m.temp_size_in_bytes
+        + m.output_size_in_bytes
+        - m.alias_size_in_bytes
+        + m.generated_code_size_in_bytes
+    )
+
+
 def fit_hbm_window(
-    capacity: int, n_envs: int, step_bytes: int, requested: Optional[int] = None
+    capacity: int,
+    n_envs: int,
+    leaf_specs: LeafSpecs,
+    budget_bytes: int,
+    sharding: Any = None,
+    requested: Optional[int] = None,
+    min_window: int = 1,
 ) -> Tuple[int, bool]:
-    """``(hbm_window_steps, spill_needed)`` under the device byte budget
-    (``SHEEPRL_REPLAY_BUDGET_BYTES``, default 8 GiB).  The window is the
-    per-env ring length kept in HBM; anything beyond pages to the host spill
-    tier.  An explicit ``buffer.hbm_window`` is honored (still budget-capped)."""
-    budget = float(os.environ.get("SHEEPRL_REPLAY_BUDGET_BYTES", 8 * 2**30))
+    """``(hbm_window_steps, spill_needed)``: the longest per-env ring, at most
+    the requested window (``buffer.hbm_window``, default the full capacity),
+    whose REAL device bytes (:func:`ring_device_bytes`) fit ``budget_bytes``
+    per device.  Anything beyond the window pages to the host spill tier.
+    The storage layout is whatever the device gives the ring's shape — this
+    only measures it."""
     window = int(capacity) if requested is None else min(int(requested), int(capacity))
-    fits = max(1, int(budget // max(step_bytes * n_envs, 1)))
-    if window > fits:
-        print(
-            f"[sheeprl_tpu] buffer.device: HBM window shrunk {window} -> {fits} "
-            f"steps/env (~{step_bytes * n_envs * fits / 2**30:.2f} GiB ring; raise "
-            "SHEEPRL_REPLAY_BUDGET_BYTES to widen) — older data pages to the host "
-            "spill tier",
-            flush=True,
+    need = ring_device_bytes(leaf_specs, window, n_envs, sharding)
+    while need > budget_bytes and window > min_window:
+        # padding makes the real bytes non-linear in the window: rescale
+        # (halve while the compiler refuses the allocation outright), then
+        # ask the compiled allocation again
+        scale = budget_bytes / need if math.isfinite(need) else 0.5
+        window = max(int(min_window), min(window - 1, int(window * scale)))
+        need = ring_device_bytes(leaf_specs, window, n_envs, sharding)
+    if need > budget_bytes:
+        raise RuntimeError(
+            f"a {window}-step replay ring needs {need} device bytes but only "
+            f"{int(budget_bytes)} are left beside the train program; shrink the "
+            "model/batch or run with buffer.device=False"
         )
-        window = fits
     return window, window < int(capacity)
+
+
+def build_device_replay(
+    fabric: Any,
+    cfg: Any,
+    capacity: int,
+    n_envs: int,
+    leaf_specs: LeafSpecs,
+    make_fused: Callable[["DeviceReplay"], Any],
+    train_state: Tuple[Any, ...],
+    first_window: int,
+    batch_bytes: float,
+    sequential: bool = False,
+    memmap_dir: Optional[Union[str, os.PathLike]] = None,
+    min_window: int = 1,
+) -> Tuple["DeviceReplay", Any]:
+    """Size, build and return ``(ring, fused train program)`` for one loop.
+
+    ``make_fused(ring)`` builds the loop's :func:`fused_uniform_train` /
+    :func:`fused_sequence_train` program; ``train_state`` is what it takes ahead of
+    the ring (``(params, opt_state)``, plus the health state when guarded),
+    ``first_window`` the updates the loop's first train window will owe and
+    ``batch_bytes`` one update's gathered bytes — together they name the
+    largest dispatch the run makes (:func:`update_chunks`).
+
+    The ring gets what the device has left: its own reported limit, less
+    what is already resident (params, optimizer state), less what XLA's
+    memory analysis says the programs that touch the ring need on top of
+    their arguments.  Nothing about that need is assumed:
+
+    * the fused program is AOT-compiled at that largest dispatch against a
+      probe ring that takes an eighth of the free memory;
+    * the ring's own gather and donated-scatter programs are compiled at the
+      same size (:meth:`DeviceReplay.access_extra_bytes`).  On a TPU both
+      re-lay the WHOLE ring into temporaries, so their need — and the train
+      program's share that is the gather's — grows with the ring; the write
+      may be in flight beside a train dispatch, so both count.
+
+    ``need(ring) = ring * (1 + (read + write) / probe_ring) + (train - read)``
+    then gives the longest window that fits.  When that is the probe's own
+    window (every ring under an eighth of the memory) the probe's executable
+    reaches the first real dispatch through the persistent compilation cache.
+    """
+    from sheeprl_tpu.parallel.sharding import replay_sharding
+
+    limit, in_use = device_memory(fabric.mesh.devices.flat[0])
+    free = limit - in_use
+    sharding = replay_sharding(fabric.mesh, n_envs, fabric.data_axis)
+    requested = cfg.buffer.get("hbm_window")
+
+    def make(window: int) -> Tuple["DeviceReplay", Any]:
+        ring = DeviceReplay(window, n_envs, mesh=fabric.mesh, data_axis=fabric.data_axis)
+        return ring, make_fused(ring)
+
+    def fit(budget: float) -> Tuple[int, bool]:
+        return fit_hbm_window(
+            capacity, n_envs, leaf_specs, int(budget), sharding, requested, min_window
+        )
+
+    probe_window, _ = fit(free / 8)
+    rb, fused = make(probe_window)
+    probe_ring = ring_device_bytes(leaf_specs, probe_window, n_envs, sharding)
+    import jax
+
+    largest = update_chunks(max(int(first_window), 1), bytes_per_update=batch_bytes)[0]
+    train = program_extra_bytes(
+        fused.lower(
+            # a stand-in key and counter: only their shapes are lowered against
+            *train_state, rb.abstract_buffers(leaf_specs), rb.cursor, jax.random.PRNGKey(0),
+            fabric.replicate(np.int32(0)), n_samples=largest,
+        ).compile()
+    )
+    read, write = rb.access_extra_bytes(leaf_specs)
+    per_ring_byte = 1.0 + (read + write) / probe_ring
+    fixed = train - read
+    window, spill_needed = fit((free - fixed) / per_ring_byte)
+    print(
+        f"[sheeprl_tpu] buffer.device: {limit / 2**30:.2f} GiB limit, "
+        f"{in_use / 2**30:.2f} GiB resident; at a {probe_ring / 2**30:.2f} GiB probe ring the "
+        f"U={largest} train program needs {train / 2**30:.2f} GiB, ring reads "
+        f"{read / 2**30:.2f} GiB, ring writes {write / 2**30:.2f} GiB -> {per_ring_byte:.2f} "
+        f"device bytes per ring byte + {fixed / 2**30:.2f} GiB fixed -> {window} of "
+        f"{capacity} steps/env in the ring"
+        + (" (older data pages to the host spill tier)" if spill_needed else ""),
+        flush=True,
+    )
+    if window != probe_window:
+        rb, fused = make(window)
+    if spill_needed:
+        rb.spill = HostSpill(
+            capacity, n_envs, sequential=sequential, memmap=cfg.buffer.memmap, memmap_dir=memmap_dir
+        )
+    return rb, fused
+
+
+def sampled_bytes(
+    leaf_specs: LeafSpecs,
+    batch_size: int,
+    sequence_length: int = 1,
+    derive_next: Sequence[str] = (),
+) -> float:
+    """Raw bytes of ONE update's gathered batch for a ring of ``leaf_specs``
+    rows (``derive_next`` keys are gathered twice: the row and its successor)."""
+    total = 0.0
+    for k, (feat, dtype) in leaf_specs.items():
+        row = int(np.prod(feat)) * np.dtype(dtype).itemsize
+        total += row * int(batch_size) * int(sequence_length) * (2 if k in derive_next else 1)
+    return total
 
 
 def update_chunks(
@@ -390,6 +562,47 @@ class DeviceReplay:
     def keys(self) -> Tuple[str, ...]:
         return tuple(self._buf.keys())
 
+    def abstract_buffers(self, leaf_specs: LeafSpecs) -> Dict[str, Any]:
+        """The ring as ``ShapeDtypeStruct``s (shape, dtype, placement) — what
+        a fused program is lowered against before the ring is allocated."""
+        import jax
+
+        return {
+            k: jax.ShapeDtypeStruct(
+                (self._capacity, self._n_envs) + tuple(int(d) for d in feat),
+                np.dtype(dtype),
+                sharding=self._sharding,
+            )
+            for k, (feat, dtype) in leaf_specs.items()
+        }
+
+    def access_extra_bytes(self, leaf_specs: LeafSpecs) -> Tuple[int, int]:
+        """``(read, write)``: device bytes this ring's gather and its donated
+        scatter need BEYOND the ring itself, summed over its leaves, by XLA's
+        memory analysis of the two programs at this ring's size.  Zero where
+        the device indexes the ring in place; on a TPU both re-lay the whole
+        ring into temporaries, so the figure grows with the ring."""
+        import jax
+
+        scatter, gather, _ = self._ops()
+        index_sharding = None
+        if self._sharding is not None:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
+            index_sharding = NamedSharding(self._mesh, P())
+
+        def spec(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, np.dtype(dtype), sharding=index_sharding)
+
+        n = self._n_envs
+        t, e = spec((1, n), np.int32), spec((n,), np.int32)
+        read = write = 0
+        for k, arr in self.abstract_buffers(leaf_specs).items():
+            read += program_extra_bytes(gather.lower(arr, t, e).compile())
+            rows = spec((1, n) + arr.shape[2:], arr.dtype)
+            write += program_extra_bytes(scatter.lower(arr, rows, t, e).compile())
+        return read, write
+
     @property
     def hbm_bytes(self) -> int:
         """Resident ring bytes (the ``replay_hbm_bytes`` bench column)."""
@@ -404,12 +617,8 @@ class DeviceReplay:
         """HBM bytes one update's gathered batch materializes on device —
         the ``bytes_per_update`` input to :func:`update_chunks`, computed
         exactly from the allocated ring (call after the first ``add``)."""
-        total = 0.0
-        for k, buf in self._buf.items():
-            row = int(np.prod(buf.shape[2:])) * buf.dtype.itemsize
-            copies = 2 if k in derive_next else 1
-            total += row * int(batch_size) * int(sequence_length) * copies
-        return total
+        specs = {k: (buf.shape[2:], buf.dtype) for k, buf in self._buf.items()}
+        return sampled_bytes(specs, batch_size, sequence_length, derive_next)
 
     def can_sample(self, min_steps: int = 1) -> bool:
         return bool((self._filled_h >= max(1, int(min_steps))).any())
@@ -449,14 +658,8 @@ class DeviceReplay:
     def _ensure(self, key: str, feat_shape: Tuple[int, ...], dtype: Any) -> None:
         if key in self._buf:
             return
-        import jax
-        import jax.numpy as jnp
-
         shape = (self._capacity, self._n_envs) + tuple(feat_shape)
-        arr = jnp.zeros(shape, dtype)
-        if self._sharding is not None:
-            arr = jax.device_put(arr, self._sharding)
-        self._buf[key] = arr
+        self._buf[key] = _zeros_program(shape, dtype, self._sharding)()
 
     def _put(self, x: np.ndarray) -> Any:
         """Explicit H2D staging (transfer-guard-legal) of host rows/indices."""
@@ -785,7 +988,7 @@ class DeviceReplay:
             raise ValueError(
                 "checkpoint was written from the replay spill tier but this "
                 "run has no spill armed — keep the same buffer.size / "
-                "buffer.hbm_window / SHEEPRL_REPLAY_BUDGET_BYTES as the saved run"
+                "buffer.hbm_window as the saved run"
             )
         spill_state = {k: v for k, v in state.items() if k != "device_replay"}
         self.spill.load_state_dict(spill_state)

@@ -6,9 +6,9 @@ policy inference produces the whole rollout as device arrays, which the
 algo's existing train phase consumes in the SAME ``fabric.compile``
 executable.  Per update there is ONE dispatch and ZERO host↔device data
 motion — no Python env workers, no observation shipping, no rollout
-staging.  This is the structural answer to the BENCH_TPU.md honest
-negative (classic-control PPO/SAC ran slower on-chip than on host: the
-chip idled while ``AsyncVectorEnv`` stepped CPU gym processes).
+staging.  This is the structural answer to classic-control PPO/SAC running
+slower on-chip than on host, the chip idling while ``AsyncVectorEnv``
+stepped CPU gym processes.
 
 The pieces:
 

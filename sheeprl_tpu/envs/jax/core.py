@@ -2,9 +2,9 @@
 
 The Anakin pattern (Podracer, arXiv:2104.06272) puts the environment INSIDE
 the jitted step so one chip steps thousands of env instances with zero host
-round-trips — the structural fix for the honest negative in BENCH_TPU.md
-(PPO/SAC classic-control ran *slower* on-chip because the chip idled while
-Python gym workers stepped envs and shipped observations).
+round-trips — the structural fix for PPO/SAC classic-control running
+*slower* on-chip because the chip idled while Python gym workers stepped
+envs and shipped observations.
 
 Env authoring contract (docs/jax_envs.md):
 

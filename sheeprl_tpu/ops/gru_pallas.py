@@ -19,7 +19,8 @@ flax cell; validated against it in tests/test_models/test_gru_pallas.py with
 ``interpret=True`` (no TPU needed).  Enable inside models with
 ``LayerNormGRUCell(use_pallas=True)``.
 
-HARDWARE STATUS (2026-07-31, v5e, honest scan-based timing — BENCH_TPU.md):
+HARDWARE STATUS (v5e, scan-based timing — 2026-07-31 capture, deleted in PR 23, see git history;
+not measured on the current code):
 Mosaic-compiles and matches the flax cell to <3e-6, but LOSES to XLA's
 fused scan body at every shape (speedup 0.38-0.56x; H=512/B=16: 11.3 µs vs
 XLA 4.5 µs per step) — XLA already keeps the scan working set VMEM-resident.

@@ -37,7 +37,8 @@ Validated against the flax modules in tests/test_models/test_rssm_pallas.py
 with ``interpret=True`` (no TPU needed).  Enable inside the world model with
 ``algo.world_model.recurrent_model.fused_pallas=True`` once on TPU hardware.
 
-HARDWARE STATUS (2026-07-31, v5e, honest scan-based timing — BENCH_TPU.md):
+HARDWARE STATUS (v5e, scan-based timing — 2026-07-31 capture, deleted in PR 23, see git history;
+not measured on the current code):
 Mosaic-compiles and matches the XLA path to <1e-4 at every preset shape,
 but LOSES to XLA's fused scan body on all of them (speedup 0.18-0.47x;
 e.g. D=512/H=512/B=16: 13.2 µs vs XLA 4.4 µs per step).  XLA already keeps

@@ -43,7 +43,8 @@ def _canon_placement(sharding: Any) -> Any:
     committed ``SingleDeviceSharding``, an uncommitted array on the default
     device, a replicated ``NamedSharding`` over a 1-device mesh — collapses
     to the same ``("dev", platform, id)`` key.  A compiled executable
-    accepts all of them interchangeably (verified on jax 0.4.37), and NOT
+    accepts all of them interchangeably (chip_smoke.py's runs feed one
+    executable a committed initial key and its own returned one), and NOT
     collapsing them burns a duplicate multi-minute compile the first time a
     program's inputs ping-pong between e.g. the host-committed initial key
     and the executable-returned one.  Multi-device shardings stay distinct

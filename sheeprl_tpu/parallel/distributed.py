@@ -63,13 +63,14 @@ ENV_PROCESS_ID = "SHEEPRL_DCN_PROCESS_ID"
 ENV_NUM_PROCESSES = "SHEEPRL_DCN_NUM_PROCESSES"
 ENV_COORD = "SHEEPRL_DCN_COORD"
 
-# env vars whose presence marks a real multi-host TPU pod environment
-# (worth an argument-less jax.distributed.initialize())
-_TPU_POD_ENV_VARS = (
-    "MEGASCALE_COORDINATOR_ADDRESS",
-    "TPU_WORKER_HOSTNAMES",
-    "CLOUD_TPU_TASK_ID",
-)
+def _tpu_pod_env() -> bool:
+    """True when the environment describes a MULTI-host TPU pod (worth an
+    argument-less ``jax.distributed.initialize()``).  A one-host TPU VM also
+    sets ``TPU_WORKER_HOSTNAMES`` (to ``localhost``) and a worker id; there
+    the argument-less call has no cluster to find and may wait on a metadata
+    server that does not exist."""
+    hosts = [h for h in os.environ.get("TPU_WORKER_HOSTNAMES", "").split(",") if h.strip()]
+    return len(hosts) > 1 or "MEGASCALE_COORDINATOR_ADDRESS" in os.environ
 
 
 class PeerLost(RuntimeError):
@@ -210,7 +211,7 @@ def ensure_distributed(cfg: Any) -> str:
 
     enabled = dcfg.get("enabled", "auto")
     if enabled is True or (
-        str(enabled) == "auto" and any(v in os.environ for v in _TPU_POD_ENV_VARS)
+        str(enabled) == "auto" and _tpu_pod_env()
     ):
         try:
             jax.distributed.initialize()
@@ -302,7 +303,8 @@ _KV_LOCK = threading.RLock()
 class _SafeKV:
     """Thread-safe face of jax's coordination-service client.
 
-    Two hazards observed under the gloo CPU backend (jaxlib 0.4.x):
+    Two hazards observed under the gloo CPU backend (not re-checked on
+    jaxlib 0.9; the serialization below is cheap and stays):
     concurrent client calls from two threads can segfault the process,
     and ``blocking_key_value_get_bytes`` segfaults whenever it SUCCEEDS
     off the main thread (the bytes-return binding) — exactly the

@@ -1,9 +1,8 @@
 """MPMD-style pipeline parallelism for ≥5B world models (ROADMAP item 3).
 
 The PR 7 rules engine shards the big matmuls over a ``model`` mesh axis, but
-the RSSM's sequential scan leaves that axis idle between layers — DV3-XL
-measured 8.8% MFU data-parallel-only (BENCH_TPU round 5), far from the ≥25%
-target.  "Scaling Deep Learning Training with MPMD Pipeline Parallelism"
+the RSSM's sequential scan leaves that axis idle between layers (DV3-XL
+read 8.8% MFU data-parallel-only in the 2026-07-31 capture, deleted in PR 23, see git history).  "Scaling Deep Learning Training with MPMD Pipeline Parallelism"
 (arXiv:2412.14374) recovers exactly this idle time by splitting the model
 into stages and streaming microbatches through them; the Podracer line
 (arXiv:2104.06272) is the same keep-the-chips-busy discipline this repo
@@ -45,7 +44,7 @@ noise out of the stages (draw at full batch shape with the baseline's exact
 keys, slice per microbatch — ``OneHotCategorical.rsample_from_noise``),
 which is what makes DP-vs-pipelined parity hold at reassociation level
 (tests/test_parallel/test_pipeline.py; tolerance tiers in
-tests/test_regression/DRIFT.md).
+tests/test_parallel/test_tensor_parallel.py).
 
 Telemetry: the schedule's bubble fraction ``(S-1)/(M+S-1)`` is a
 first-class metric (``Pipeline/bubble_frac`` through the hub;

@@ -41,12 +41,10 @@ from sheeprl_tpu.algos.sac.sac import make_sac_train_fns
 from sheeprl_tpu.algos.sac.utils import prepare_obs, test
 from sheeprl_tpu.data.buffers import ReplayBuffer
 from sheeprl_tpu.data.device_replay import (
-    DeviceReplay,
-    HostSpill,
-    estimate_step_bytes,
-    fit_hbm_window,
+    build_device_replay,
     fused_uniform_train,
     resolve_device_replay,
+    sampled_bytes,
     update_chunks,
 )
 from sheeprl_tpu.parallel.topology import DeviceTopology, ParamBroadcast, topology_cfg
@@ -214,26 +212,6 @@ def run_sebulba(fabric: Any, cfg: Any) -> Dict[str, Any]:
     capacity = int(cfg.buffer.size) // num_envs
     memmap_dir = os.path.join(log_dir, "memmap_buffer", "rank_0") if cfg.buffer.memmap else None
     use_device_replay = resolve_device_replay(cfg, fabric.accelerator)
-    if use_device_replay:
-        step_bytes = estimate_step_bytes(
-            obs_space, mlp_keys, extra_bytes=4 * (act_dim + 2), copies_per_key=2
-        )
-        hbm_window, spill_needed = fit_hbm_window(
-            capacity, num_envs, step_bytes, cfg.buffer.get("hbm_window")
-        )
-        spill = (
-            HostSpill(capacity, num_envs, memmap=cfg.buffer.memmap, memmap_dir=memmap_dir)
-            if spill_needed
-            else None
-        )
-        rb: Any = DeviceReplay(
-            hbm_window, num_envs, mesh=learner_fab.mesh, data_axis=learner_fab.data_axis, spill=spill
-        )
-    else:
-        rb = ReplayBuffer(capacity, num_envs, memmap=cfg.buffer.memmap, memmap_dir=memmap_dir)
-    if state and cfg.buffer.checkpoint and "rb" in state:
-        rb.load_state_dict(state["rb"])
-
     batch_size = int(cfg.algo.per_rank_batch_size) * learner_fab.local_world_size
     train_phase_dev = None
     if use_device_replay:
@@ -246,15 +224,42 @@ def run_sebulba(fabric: Any, cfg: Any) -> Dict[str, Any]:
                 "terminated": b["terminated"][..., 0],
             }
 
-        train_phase_dev = fused_uniform_train(
-            learner_fab,
-            train_phase,
-            rb,
-            batch_size,
-            _prep_batch,
-            name=f"{cfg.algo.name}.sebulba_train_phase_device",
-            max_recompiles=cfg.algo.get("max_recompiles"),
+        def _make_fused(ring):
+            return fused_uniform_train(
+                learner_fab,
+                train_phase,
+                ring,
+                batch_size,
+                _prep_batch,
+                name=f"{cfg.algo.name}.sebulba_train_phase_device",
+                max_recompiles=cfg.algo.get("max_recompiles"),
+            )
+
+        # the ring's rows, exactly as the env workers' segments carry them
+        leaf_specs = {
+            "obs": ((obs_dim,), np.float32),
+            "next_obs": ((obs_dim,), np.float32),
+            "actions": ((act_dim,), np.float32),
+            "rewards": ((1,), np.float32),
+            "terminated": ((1,), np.float32),
+        }
+        # what Ratio will owe at the first train window: every step collected
+        # up to learning_starts, in whole rounds
+        steps_per_round = num_envs * max(1, int(topo_cfg.get("segment_steps", 16)))
+        first_steps = 0 if cfg.dry_run else int(cfg.algo.learning_starts)
+        first_steps = max(-(-first_steps // steps_per_round), 1) * steps_per_round
+        burst = Ratio(cfg.algo.replay_ratio, pretrain_steps=cfg.algo.per_rank_pretrain_steps)(
+            first_steps / learner_fab.world_size
         )
+        rb, train_phase_dev = build_device_replay(
+            learner_fab, cfg, capacity, num_envs, leaf_specs, _make_fused,
+            train_state=(params, opt_state), first_window=burst,
+            batch_bytes=sampled_bytes(leaf_specs, batch_size), memmap_dir=memmap_dir,
+        )
+    else:
+        rb = ReplayBuffer(capacity, num_envs, memmap=cfg.buffer.memmap, memmap_dir=memmap_dir)
+    if state and cfg.buffer.checkpoint and "rb" in state:
+        rb.load_state_dict(state["rb"])
 
     # ---------------- broadcast + queues + actors ----------------------------
     broadcast = ParamBroadcast(

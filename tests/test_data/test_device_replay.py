@@ -27,8 +27,10 @@ from sheeprl_tpu.data.buffers import ReplayBuffer
 from sheeprl_tpu.data.device_replay import (
     DeviceReplay,
     HostSpill,
+    device_memory,
     fit_hbm_window,
     fused_uniform_train,
+    ring_device_bytes,
     steady_guard,
     update_chunks,
 )
@@ -300,12 +302,53 @@ class TestSpillTier:
         )
         rb2.spill.close(); spill.close()
 
-    def test_fit_hbm_window_arms_spill_under_budget(self, monkeypatch):
-        monkeypatch.setenv("SHEEPRL_REPLAY_BUDGET_BYTES", str(1000 * 4))
-        window, spill_needed = fit_hbm_window(10_000, 2, step_bytes=4)
-        assert window == 500 and spill_needed
-        window, spill_needed = fit_hbm_window(100, 2, step_bytes=4)
-        assert window == 100 and not spill_needed
+    @pytest.mark.parametrize(
+        "capacity,budget,requested,want",
+        [
+            (10_000, 1000 * 4, None, (500, True)),  # over budget: shrink + spill
+            (100, 1000 * 4, None, (100, False)),  # fits whole
+            (10_000, 1000 * 4, 200, (200, True)),  # explicit window honored
+            (10_000, 1000 * 4, 800, (500, True)),  # ...but still budget-capped
+        ],
+    )
+    def test_fit_hbm_window(self, capacity, budget, requested, want):
+        """The window is the longest ring whose COMPILED allocation fits the
+        byte budget (on CPU the layout does not pad: 2 envs x 1 f32 = 8 B/step)."""
+        specs = {"x": ((1,), np.float32)}
+        assert fit_hbm_window(capacity, 2, specs, budget, requested=requested) == want
+
+    def test_fit_hbm_window_raises_when_min_window_cannot_fit(self):
+        with pytest.raises(RuntimeError, match="replay ring needs"):
+            fit_hbm_window(10_000, 2, {"x": ((1,), np.float32)}, 64, min_window=16)
+
+    def test_ring_device_bytes_asks_the_compiled_allocation(self):
+        from sheeprl_tpu.parallel.fabric import Fabric
+        from sheeprl_tpu.parallel.sharding import replay_sharding
+
+        specs = {"rgb": ((8, 8, 3), np.uint8), "r": ((1,), np.float32)}
+        raw = 32 * 4 * (8 * 8 * 3 + 4)
+        assert ring_device_bytes(specs, 32, 4) == raw
+        # sharded over the env axis: each device holds its share only
+        fabric = Fabric(devices=4, accelerator="cpu")
+        sh = replay_sharding(fabric.mesh, 4, fabric.data_axis)
+        assert ring_device_bytes(specs, 32, 4, sh) == raw // 4
+
+    def test_device_memory_is_what_the_device_reports(self):
+        class Dev:
+            def __init__(self, platform, stats):
+                self.platform, self._stats = platform, stats
+
+            def memory_stats(self):
+                return self._stats
+
+        assert device_memory(Dev("tpu", {"bytes_limit": 100, "bytes_in_use": 7})) == (100, 7)
+        # a CPU device reports nothing: its memory is the host's
+        limit, in_use = device_memory(jax.devices("cpu")[0])
+        assert limit > 2**28 and in_use == 0
+        # any other device without a limit is an error, never an assumed size
+        for stats in (None, {}, {"bytes_in_use": 5}):
+            with pytest.raises(RuntimeError, match="reports no memory limit"):
+                device_memory(Dev("tpu", stats))
 
     def _plan(self, spec):
         from sheeprl_tpu.resilience.faults import FaultPlan, install_plan

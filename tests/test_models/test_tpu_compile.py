@@ -1,0 +1,134 @@
+"""Compile the TPU-only code for a DESCRIBED v5e chip (no chip attached).
+
+The TPU compiler is installed with libtpu and compiles for a topology that is
+described rather than attached, so these cases guard — at no chip time — what
+interpret mode and the CPU backend cannot see: Mosaic lowering of the Pallas
+kernels at real DreamerV3 widths (tiling, VMEM budget), and the TPU lowering
+of the DV3-S world-model forward+backward.  Nothing runs; a compile that
+passes is not a chip run.
+
+All cases live in THIS file and the topology is described inside a
+module-scoped, non-autouse fixture: only the xdist worker that is handed this
+file loads libtpu (one process at a time may hold it).
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A ``SingleDeviceSharding`` on chip 0 of a described ``v5e:2x2`` host,
+    with the persistent compilation cache off around the module's compiles (a
+    described-chip entry can be written but never read back without a chip)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever libtpu raises here means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _spec(sharding, *shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compiled_text(fn, *specs):
+    return jax.jit(fn).lower(*specs).compile().as_text()
+
+
+@pytest.mark.parametrize("batch", [16, 1024])
+def test_fused_gru_compiles_for_v5e_at_dv3_s_widths(one_chip, batch):
+    """LayerNorm-GRU cell, DV3-S: dense 512 -> recurrent 512."""
+    from sheeprl_tpu.ops.gru_pallas import fused_layernorm_gru
+
+    D = H = 512
+    s = lambda *shape: _spec(one_chip, *shape)  # noqa: E731
+    text = _compiled_text(
+        lambda x, h, w, sc, b: fused_layernorm_gru(x, h, w, sc, b, interpret=False),
+        s(batch, D), s(batch, H), s(D + H, 3 * H), s(3 * H), s(3 * H),
+    )
+    assert "tpu_custom_call" in text
+
+
+def _rssm_specs(one_chip, batch, ZA, D, H):
+    s = lambda *shape: _spec(one_chip, *shape)  # noqa: E731
+    return (
+        s(batch, ZA), s(batch, H), s(ZA, D), s(D), s(D), s(D),
+        s(D + H, 3 * H), s(3 * H), s(3 * H),
+    )
+
+
+@pytest.mark.parametrize("batch", [16, 1024])
+def test_fused_rssm_compiles_for_v5e_at_dv3_s_widths(one_chip, batch):
+    """Whole recurrent path resident in VMEM, DV3-S: z(32x32)+a(5) = 1029 in."""
+    from sheeprl_tpu.ops.rssm_pallas import fused_rssm_recurrent
+
+    text = _compiled_text(
+        lambda *a: fused_rssm_recurrent(*a, interpret=False),
+        *_rssm_specs(one_chip, batch, ZA=1029, D=512, H=512),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_tiled_rssm_compiles_for_v5e_at_dv3_xl_widths(one_chip):
+    """XL (dense 1024, recurrent 4096) exceeds the resident kernel's VMEM
+    budget: the planner must pick the column-tiled kernel and Mosaic accept it."""
+    from sheeprl_tpu.ops.rssm_pallas import fused_rssm_recurrent
+
+    text = _compiled_text(
+        lambda *a: fused_rssm_recurrent(*a, interpret=False),
+        *_rssm_specs(one_chip, 16, ZA=1029, D=1024, H=4096),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_dv3_s_world_model_fwd_bwd_compiles_for_v5e(one_chip):
+    """The DV3-S world model's loss and gradients at B=16, L=64 on 64x64x3
+    uint8 pixels, bf16-mixed — the shapes ``chip_smoke.py`` trains at."""
+    from gymnasium import spaces
+
+    from sheeprl_tpu.algos.dreamer_v3.agent import build_agent
+    from sheeprl_tpu.algos.dreamer_v3.dreamer_v3 import make_wm_stages
+    from sheeprl_tpu.config.compose import compose
+    from sheeprl_tpu.parallel.fabric import Fabric
+
+    cfg = compose(["exp=dreamer_v3", "algo=dreamer_v3_S", "env=jax_forage"])
+    fabric = Fabric(devices=1, accelerator="cpu", precision="bf16-mixed")
+    obs_space = spaces.Dict({"rgb": spaces.Box(0, 255, (64, 64, 3), np.uint8)})
+    world_model, _, _, params = build_agent(fabric, (5,), False, cfg, obs_space)
+    wm_forward, _, _ = make_wm_stages(cfg, world_model, ("rgb",), ())
+
+    def loss_and_grads(wm_params, data, key):
+        return jax.value_and_grad(lambda p: wm_forward(p, data, key)[0])(wm_params)
+
+    L, B = 64, 16
+    on_chip = lambda x: _spec(one_chip, *x.shape, dtype=x.dtype)  # noqa: E731
+    data = {
+        "rgb": _spec(one_chip, L, B, 64, 64, 3, dtype=jnp.uint8),
+        "actions": _spec(one_chip, L, B, 5),
+        "rewards": _spec(one_chip, L, B),
+        "terminated": _spec(one_chip, L, B),
+        "is_first": _spec(one_chip, L, B),
+    }
+    key = on_chip(jax.random.PRNGKey(0))
+    compiled = (
+        jax.jit(loss_and_grads)
+        .lower(jax.tree.map(on_chip, params["world_model"]), data, key)
+        .compile()
+    )
+    memory = compiled.memory_analysis()
+    # fits the 16 GB chip with room for the ring (it needs about 1 GB)
+    assert 0 < memory.temp_size_in_bytes < 4 * 2**30
+    assert "convolution" in compiled.as_text()

@@ -334,6 +334,27 @@ class TestSharedRoot:
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.parametrize(
+    "env,is_pod",
+    [
+        ({}, False),
+        # a one-host TPU VM (what a v5e host sets): NOT a pod — an argument-less
+        # jax.distributed.initialize() there has no cluster to find
+        ({"TPU_WORKER_HOSTNAMES": "localhost", "TPU_WORKER_ID": "0"}, False),
+        ({"TPU_WORKER_HOSTNAMES": "10.0.0.2,10.0.0.3"}, True),
+        ({"MEGASCALE_COORDINATOR_ADDRESS": "10.0.0.2:8080"}, True),
+    ],
+)
+def test_pod_autodetect_needs_more_than_one_host(monkeypatch, env, is_pod):
+    from sheeprl_tpu.parallel.distributed import _tpu_pod_env
+
+    for name in ("TPU_WORKER_HOSTNAMES", "TPU_WORKER_ID", "MEGASCALE_COORDINATOR_ADDRESS"):
+        monkeypatch.delenv(name, raising=False)
+    for name, value in env.items():
+        monkeypatch.setenv(name, value)
+    assert _tpu_pod_env() is is_pod
+
+
 class TestRankZeroWarn:
     def test_rank_zero_warns_once_per_key(self, monkeypatch):
         monkeypatch.setenv(ENV_PROCESS_ID, "0")

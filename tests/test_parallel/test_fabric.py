@@ -223,35 +223,72 @@ def test_env_sharding_plan():
             fab.env_sharding_plan(3, "PPO")
 
 
-def test_compilation_cache_dir_config(tmp_path):
-    """fabric.compilation_cache_dir wires the persistent XLA compilation
-    cache; entries appear for newly compiled programs."""
-    import glob
+_CACHE_PROBE = """
+import glob, jax, jax.numpy as jnp
+from sheeprl_tpu.config.compose import compose
+from sheeprl_tpu.parallel.fabric import build_fabric
+build_fabric(compose([
+    "env=dummy", "env.id=discrete_dummy", "algo=ppo", "algo.total_steps=1",
+    "algo.per_rank_batch_size=1", "fabric=tpu", "fabric.accelerator=cpu", "fabric.devices=1",
+]))
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.jit(lambda x: (x @ x.T).sum() + 41)(jnp.ones((64, 64))).block_until_ready()
+d = jax.config.jax_compilation_cache_dir
+print("CACHE_DIR=" + str(d), "ENTRIES=" + str(len(glob.glob(str(d) + "/*"))))
+"""
 
-    import jax
-    import jax.numpy as jnp
 
-    from sheeprl_tpu.config.compose import compose
-    from sheeprl_tpu.parallel.fabric import build_fabric
+def _cache_probe(env_dir):
+    """Build a ``fabric=tpu`` runtime in a FRESH process (the cache location
+    is decided once per process) and report where its compile cache went."""
+    import os
+    import subprocess
+    import sys
 
-    cfg = compose(
-        [
-            "env=dummy", "env.id=discrete_dummy", "algo=ppo",
-            "algo.total_steps=1", "algo.per_rank_batch_size=1",
-            f"fabric.compilation_cache_dir={tmp_path}", "fabric.accelerator=cpu",
-        ]
+    from sheeprl_tpu.parallel.fabric import COMPILE_CACHE_DIR
+
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["JAX_PLATFORMS"] = "cpu"
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+    out = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE], env=env, capture_output=True, text=True,
+        timeout=300, cwd=os.path.dirname(COMPILE_CACHE_DIR),
     )
-    orig_dir = jax.config.jax_compilation_cache_dir
-    orig_min = jax.config.jax_persistent_cache_min_compile_time_secs
-    try:
-        build_fabric(cfg)
-        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.jit(lambda x: (x @ x.T).sum() + 41)(jnp.ones((64, 64))).block_until_ready()
-        assert glob.glob(str(tmp_path) + "/*"), "no cache entries written"
-    finally:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", orig_min)
-        jax.config.update("jax_compilation_cache_dir", orig_dir)
+    assert out.returncode == 0, out.stderr[-2000:]
+    line = next(l for l in out.stdout.splitlines() if l.startswith("CACHE_DIR="))
+    cache_dir, entries = line.split(" ENTRIES=")
+    return cache_dir[len("CACHE_DIR="):], int(entries)
+
+
+def test_compile_cache_env_var_wins_over_fabric_tpu(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set the program sets no cache directory
+    in code: a ``fabric=tpu`` run keeps JAX's own setting and writes there."""
+    cache_dir, entries = _cache_probe(tmp_path)
+    assert cache_dir == str(tmp_path)
+    assert entries > 0, "no cache entries written where the environment said"
+
+
+def test_compile_cache_default_is_fixed_in_checkout():
+    """Unset, every process uses the same directory inside the checkout — no
+    temp dir, uid, pid or time enters the path."""
+    from sheeprl_tpu.parallel.fabric import COMPILE_CACHE_DIR
+
+    first, _ = _cache_probe(None)
+    second, _ = _cache_probe(None)
+    import os
+
+    import sheeprl_tpu
+
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(sheeprl_tpu.__file__)))
+    assert first == second == COMPILE_CACHE_DIR == os.path.join(checkout, ".jax_cache")
+
+
+def test_explicit_accelerator_without_device_raises():
+    """``fabric.accelerator=tpu`` on a process that sees no chip is an error
+    naming what JAX does see — never a silent run on the CPU."""
+    with pytest.raises(RuntimeError, match=r"no 'tpu' device.*platforms visible: \['cpu'\]"):
+        Fabric(devices=1, accelerator="tpu")
 
 
 def test_packed_copy_bit_identical():

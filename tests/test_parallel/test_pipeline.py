@@ -12,7 +12,7 @@ The load-bearing claims, in dependency order:
    a synthetic chain (pure reassociation, tight tolerance);
 4. the ISSUE 16 acceptance cell: pipelined dreamer_v3 on a fake pipeline
    mesh matches the data-parallel baseline's losses/params within the
-   DRIFT.md tiers, compile-once across ≥50 windows under the armed
+   tensor-parallel drift tiers (test_tensor_parallel.py), compile-once across ≥50 windows under the armed
    transfer guard;
 5. an indivisible microbatch split errors with the shard_batch-style
    message (the divisibility law), not an opaque XLA reshape error.
@@ -285,7 +285,7 @@ PIPE_2STAGE = [
 
 @pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 virtual devices")
 def test_dv3_pipelined_matches_dp_baseline():
-    """DP-vs-pipelined parity within the DRIFT.md tensor-parallel tiers
+    """DP-vs-pipelined parity within the tensor-parallel drift tiers
     (same cell shape as test_mesh_e2e's DP-vs-TP): the 2-stage 1F1B pipeline
     on a {data: 2, pipeline: 4} mesh trains the same XS model to the same
     losses/params as the pure-data 8-device baseline."""

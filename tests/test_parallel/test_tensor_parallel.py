@@ -105,8 +105,7 @@ def test_tp_noop_without_model_axis():
 def test_tp_train_step_matches_single_device():
     """2×2 data×model mesh vs 1 device: seeded DV3 train step equivalence.
 
-    Tolerance policy (measured on the jax 0.4.37 pin; derivation in
-    tests/test_regression/DRIFT.md "Tensor-parallel drift"):
+    Tolerance policy (measured drift, tier by tier):
 
     * data-parallel-only (4-device ``data`` mesh, no model axis) is pure
       batch-reduction regrouping and must stay ~bit-exact (< 1e-5 measured)

@@ -40,8 +40,8 @@ ATOL = 1e-5
 # RTOL without any code change (ADVICE r3): widen instead of flaking.
 RTOL_FOREIGN = 5e-2
 # Cancellation-prone metrics: a difference of O(k) constituents can show a
-# large RELATIVE drift from ordinary platform numerics (DRIFT.md measured
-# every sac_ae constituent at 3-5% on the real TPU; policy_loss = alpha*logp
+# large RELATIVE drift from ordinary platform numerics (the 2026-07-31 chip
+# capture read every sac_ae constituent at 3-5%; policy_loss = alpha*logp
 # - min(Q) lands near zero, so that 3.5% becomes 62% relative).  A narrow,
 # data-backed ABSOLUTE allowance per metric — never a blanket widening.
 ATOL_FOREIGN = {
@@ -57,7 +57,7 @@ def _env_stamp() -> dict:
         "machine": platform.machine(),
         "system": platform.system(),
         # the backend IS part of the platform: TPU-vs-CPU drift is exactly
-        # what RTOL_FOREIGN exists for (DRIFT.md second-platform table)
+        # what RTOL_FOREIGN exists for
         "backend": jax.default_backend(),
     }
 

@@ -6,7 +6,7 @@ devices, conftest.py) vs the same step on a pure-data 8-device mesh:
 
 * losses/params agree within the measured tensor-parallel drift tiers of
   tests/test_parallel/test_tensor_parallel.py (derivation in
-  tests/test_regression/DRIFT.md "Tensor-parallel drift" — GSPMD collective
+  tests/test_parallel/test_tensor_parallel.py's tiers — GSPMD collective
   reassociation noise amplified through near-tie discrete latent samples);
 * optimizer-state kernels are sharded exactly like their params (the
   state_io_shardings pin + the shared rule table);
@@ -130,7 +130,7 @@ def test_dv3_2x4_mesh_loss_parity_and_opt_sharding():
     assert train_phase.cache_size() == 1
 
     # loss parity vs the pure-data mesh, within the measured TP drift tiers
-    # (tests/test_parallel/test_tensor_parallel.py, DRIFT.md)
+    # (tests/test_parallel/test_tensor_parallel.py)
     _, _, p_dp, _, m_dp = _one_step(None, repeats=2)
     for a, b in zip(jax.tree_util.tree_leaves(m_tp), jax.tree_util.tree_leaves(m_dp)):
         b_arr = np.asarray(b)

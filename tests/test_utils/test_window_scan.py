@@ -1,6 +1,6 @@
 """window_scan: trace-time unrolling must be semantically identical to scan.
 
-Background (BENCH_CPU.md round 5): XLA-CPU runs convolution-bearing update
+Background: XLA-CPU runs convolution-bearing update
 bodies ~5x slower inside ``lax.scan``'s outlined call, and ``unroll=True``
 does not remove the penalty — only true trace-time inlining does.  The
 helper must therefore agree with ``lax.scan`` exactly, on every path.
