@@ -69,6 +69,7 @@ from sheeprl_tpu.parallel.pipeline import (
     split_microbatches,
     stage_batch_constraint,
 )
+from sheeprl_tpu.telemetry.spans import SPANS
 from sheeprl_tpu.utils.distribution import (
     Bernoulli,
     MSEDistribution,
@@ -200,20 +201,21 @@ def dreamer_family_loop(
         the advanced key (advancing it in-program saves two host dispatches
         per env step)."""
         h, z, prev_a = carry
-        k_repr, k_act, k_next = jax.random.split(k, 3)
-        embed = world_model.apply(p["world_model"], obs, method=WM.encode)
-        is_first = jnp.zeros((h.shape[0], 1))
-        h, z, _, _ = world_model.apply(
-            p["world_model"], h, z, prev_a, embed, is_first, k_repr, method=WM.dynamic
-        )
-        latent = jnp.concatenate([z, h], -1)
-        head = actor.apply(p["actor"], latent)
-        if use_action_masks:
-            action = actor.sample_masked(
-                head, k_act, {mk: obs[mk] for mk in mask_keys}, greedy=greedy
+        with jax.named_scope("player.step"):
+            k_repr, k_act, k_next = jax.random.split(k, 3)
+            embed = world_model.apply(p["world_model"], obs, method=WM.encode)
+            is_first = jnp.zeros((h.shape[0], 1))
+            h, z, _, _ = world_model.apply(
+                p["world_model"], h, z, prev_a, embed, is_first, k_repr, method=WM.dynamic
             )
-        else:
-            action = actor.sample(head, k_act, greedy=greedy)
+            latent = jnp.concatenate([z, h], -1)
+            head = actor.apply(p["actor"], latent)
+            if use_action_masks:
+                action = actor.sample_masked(
+                    head, k_act, {mk: obs[mk] for mk in mask_keys}, greedy=greedy
+                )
+            else:
+                action = actor.sample(head, k_act, greedy=greedy)
         return (h, z, action), action, k_next
 
     # compile-once routing: the player executable is AOT-compiled per
@@ -437,6 +439,7 @@ def dreamer_family_loop(
     profiler = ProfilerGate(cfg, log_dir)
     for update in range(start_iter, total_iters + 1):
         profiler.step(update)
+        SPANS.iteration(update)  # the `iter` span: closes the one before
         policy_step += policy_steps_per_iter
         with timer("Time/env_interaction_time"):
             if update <= learning_starts and not state:
@@ -709,6 +712,7 @@ def dreamer_family_loop(
             fabric.print(f"Preemption: committed checkpoint at step {policy_step}, exiting")
             break
 
+    SPANS.end_iteration()
     profiler.close()
     envs.close()
     if sentinel is not None:
@@ -765,10 +769,11 @@ def make_wm_stages(cfg, world_model, cnn_keys, mlp_keys):
     def _encode(wm_params, data):
         """Stage 1 — normalize + encode: → (obs, embed (L, B, E))."""
         L, B = data["rewards"].shape
-        obs = normalize_obs_block(data, cnn_keys, obs_keys)
-        flat_obs = {kk: v.reshape((L * B,) + v.shape[2:]) for kk, v in obs.items()}
-        embed = world_model.apply(wm_params, flat_obs, method=WorldModel.encode)
-        return obs, embed.reshape(L, B, -1)
+        with jax.named_scope("wm.encoder"):
+            obs = normalize_obs_block(data, cnn_keys, obs_keys)
+            flat_obs = {kk: v.reshape((L * B,) + v.shape[2:]) for kk, v in obs.items()}
+            embed = world_model.apply(wm_params, flat_obs, method=WorldModel.encode)
+            return obs, embed.reshape(L, B, -1)
 
     def _rssm_inputs(data):
         # shifted actions: h_t consumes a_{t-1} (reference: dreamer_v3.py:105)
@@ -778,6 +783,7 @@ def make_wm_stages(cfg, world_model, cnn_keys, mlp_keys):
         is_first = data["is_first"].at[0].set(1.0)[..., None]
         return actions, is_first
 
+    @jax.named_scope("wm.heads")
     def _heads_losses(wm_params, data, obs, latents, post_logits, prior_logits):
         """Stage 3 — decoder/reward/continue heads + world-model loss."""
         L, B = data["rewards"].shape
@@ -814,43 +820,44 @@ def make_wm_stages(cfg, world_model, cnn_keys, mlp_keys):
         obs, embed = _encode(wm_params, data)
         actions, is_first = _rssm_inputs(data)
 
-        h0 = jnp.zeros((B, rec_size))
-        z0 = jnp.zeros((B, stoch_flat))
+        with jax.named_scope("wm.rssm"):
+            h0 = jnp.zeros((B, rec_size))
+            z0 = jnp.zeros((B, stoch_flat))
 
-        keys = jax.random.split(k, L)
-        if world_model.decoupled_rssm:
-            # DecoupledRSSM: ALL posteriors computed and sampled in one
-            # batched pass (no h dependence); only the GRU+prior stay in the
-            # scan — a much lighter sequential step on TPU
-            post_logits = world_model.apply(
-                wm_params, embed.reshape(L * B, -1), method=WorldModel.posterior_decoupled
-            ).reshape(L, B, world_model.stochastic_size, world_model.discrete_size)
-            zs = jax.vmap(
-                lambda lg, kk: OneHotCategorical(lg, unimix=world_model.unimix).rsample(kk)
-            )(post_logits, keys).reshape(L, B, stoch_flat)
-            prev_zs = jnp.concatenate([jnp.zeros_like(zs[:1]), zs[:-1]], 0)
+            keys = jax.random.split(k, L)
+            if world_model.decoupled_rssm:
+                # DecoupledRSSM: ALL posteriors computed and sampled in one
+                # batched pass (no h dependence); only the GRU+prior stay in the
+                # scan — a much lighter sequential step on TPU
+                post_logits = world_model.apply(
+                    wm_params, embed.reshape(L * B, -1), method=WorldModel.posterior_decoupled
+                ).reshape(L, B, world_model.stochastic_size, world_model.discrete_size)
+                zs = jax.vmap(
+                    lambda lg, kk: OneHotCategorical(lg, unimix=world_model.unimix).rsample(kk)
+                )(post_logits, keys).reshape(L, B, stoch_flat)
+                prev_zs = jnp.concatenate([jnp.zeros_like(zs[:1]), zs[:-1]], 0)
 
-            def step(h, xs):
-                prev_z, act_t, first_t = xs
-                h, prior_logits = world_model.apply(
-                    wm_params, h, prev_z, act_t, first_t, method=WorldModel.recurrent_prior
+                def step(h, xs):
+                    prev_z, act_t, first_t = xs
+                    h, prior_logits = world_model.apply(
+                        wm_params, h, prev_z, act_t, first_t, method=WorldModel.recurrent_prior
+                    )
+                    return h, (h, prior_logits)
+
+                _, (hs, prior_logits) = jax.lax.scan(maybe_remat(step), h0, (prev_zs, actions, is_first))
+            else:
+                def step(carry, xs):
+                    h, z = carry
+                    embed_t, act_t, first_t, k_t = xs
+                    h, z, post_logits, prior_logits = world_model.apply(
+                        wm_params, h, z, act_t, embed_t, first_t, k_t, method=WorldModel.dynamic
+                    )
+                    return (h, z), (h, z, post_logits, prior_logits)
+
+                _, (hs, zs, post_logits, prior_logits) = jax.lax.scan(
+                    maybe_remat(step), (h0, z0), (embed, actions, is_first, keys)
                 )
-                return h, (h, prior_logits)
-
-            _, (hs, prior_logits) = jax.lax.scan(maybe_remat(step), h0, (prev_zs, actions, is_first))
-        else:
-            def step(carry, xs):
-                h, z = carry
-                embed_t, act_t, first_t, k_t = xs
-                h, z, post_logits, prior_logits = world_model.apply(
-                    wm_params, h, z, act_t, embed_t, first_t, k_t, method=WorldModel.dynamic
-                )
-                return (h, z), (h, z, post_logits, prior_logits)
-
-            _, (hs, zs, post_logits, prior_logits) = jax.lax.scan(
-                maybe_remat(step), (h0, z0), (embed, actions, is_first, keys)
-            )
-        latents = jnp.concatenate([zs, hs], -1)  # (L, B, stoch+rec)
+            latents = jnp.concatenate([zs, hs], -1)  # (L, B, stoch+rec)
         return _heads_losses(wm_params, data, obs, latents, post_logits, prior_logits)
 
     # ---- pipeline stage functions (parallel/pipeline.py chain shapes) ----
@@ -860,6 +867,7 @@ def make_wm_stages(cfg, world_model, cnn_keys, mlp_keys):
         _, embed = _encode(wm_params, const["data"])
         return embed
 
+    @jax.named_scope("wm.rssm")
     def _rssm_stage(wm_params, embed, const):
         data, noise = const["data"], const["noise"]
         L, B = data["rewards"].shape
@@ -1018,6 +1026,7 @@ def make_train_phase(
         n = L * B
         start_latents = jax.lax.stop_gradient(latents.reshape(1, n, -1))[0]
 
+        @jax.named_scope("actor.loss")
         def actor_loss_fn(actor_params):
             def img_step(carry, k_t):
                 h, z = carry
@@ -1035,7 +1044,8 @@ def make_train_phase(
             keys = jax.random.split(k, horizon + 1)
             # H+1 scan steps emit the pre-action latent each time → traj holds
             # states z0, z'1, ..., z'H (reference diagram, dreamer_v3.py:222-232)
-            _, (traj, actions_seq) = jax.lax.scan(maybe_remat(img_step), (h0, z0), keys)
+            with jax.named_scope("behavior.imagine"):
+                _, (traj, actions_seq) = jax.lax.scan(maybe_remat(img_step), (h0, z0), keys)
             # predictions over the whole imagined trajectory
             # the imagination batch's wide head evals, row-chunked under
             # pipeline.imagination_microbatches (chunked_rows is fn(x)
@@ -1096,8 +1106,9 @@ def make_train_phase(
         (pl, (traj, lambda_values, discount)), a_grads = jax.value_and_grad(
             actor_loss_fn, has_aux=True
         )(p["actor"])
-        a_updates, new_a_opt = actor_opt.update(a_grads, o_state["actor"], p["actor"])
-        p = {**p, "actor": optax.apply_updates(p["actor"], a_updates)}
+        with jax.named_scope("actor.optim"):
+            a_updates, new_a_opt = actor_opt.update(a_grads, o_state["actor"], p["actor"])
+            p = {**p, "actor": optax.apply_updates(p["actor"], a_updates)}
 
         # recompute moments state outside the grad fn (pure duplicate, cheap)
         new_moments, _, _ = moments_update(
@@ -1109,13 +1120,15 @@ def make_train_phase(
         # ---- critic (Eq. 10): two-hot NLL of λ-returns + target regularizer
         traj_sg = jax.lax.stop_gradient(traj[:-1])
         flat_sg = traj_sg.reshape(horizon * traj_sg.shape[1], -1)
-        target_mean = TwoHotEncodingDistribution(
-            chunked_rows(
-                lambda x: critic.apply(p["target_critic"], x), flat_sg, imag_chunks
-            ).reshape(horizon, -1, cfg.algo.critic.bins),
-            dims=1,
-        ).mean
+        with jax.named_scope("critic.loss"):
+            target_mean = TwoHotEncodingDistribution(
+                chunked_rows(
+                    lambda x: critic.apply(p["target_critic"], x), flat_sg, imag_chunks
+                ).reshape(horizon, -1, cfg.algo.critic.bins),
+                dims=1,
+            ).mean
 
+        @jax.named_scope("critic.loss")
         def critic_loss_fn(critic_params):
             qv = TwoHotEncodingDistribution(
                 chunked_rows(
@@ -1128,8 +1141,9 @@ def make_train_phase(
             return jnp.mean(vl * discount[:-1])
 
         vl, c_grads = jax.value_and_grad(critic_loss_fn)(p["critic"])
-        c_updates, new_c_opt = critic_opt.update(c_grads, o_state["critic"], p["critic"])
-        p = {**p, "critic": optax.apply_updates(p["critic"], c_updates)}
+        with jax.named_scope("critic.optim"):
+            c_updates, new_c_opt = critic_opt.update(c_grads, o_state["critic"], p["critic"])
+            p = {**p, "critic": optax.apply_updates(p["critic"], c_updates)}
         o_state = {**o_state, "actor": new_a_opt, "critic": new_c_opt}
         return p, o_state, new_moments, pl, vl
 
@@ -1139,8 +1153,9 @@ def make_train_phase(
         k_wm, k_beh = jax.random.split(k)
 
         (wm_l, aux), wm_grads = wm_value_and_grad(p["world_model"], data, k_wm)
-        wm_updates, new_wm_opt = wm_opt.update(wm_grads, o_state["world_model"], p["world_model"])
-        p = {**p, "world_model": optax.apply_updates(p["world_model"], wm_updates)}
+        with jax.named_scope("wm.optim"):
+            wm_updates, new_wm_opt = wm_opt.update(wm_grads, o_state["world_model"], p["world_model"])
+            p = {**p, "world_model": optax.apply_updates(p["world_model"], wm_updates)}
         o_state = {**o_state, "world_model": new_wm_opt}
 
         p, o_state, new_moments, pl, vl = behavior_update(
@@ -1149,16 +1164,17 @@ def make_train_phase(
         p = {**p, "moments": new_moments}
 
         # target critic EMA (reference: dreamer_v3.py:674-680)
-        do_ema = (counter % target_freq) == 0
-        new_target = jax.tree.map(
-            lambda t, o: (1 - tau) * t + tau * o, p["target_critic"], p["critic"]
-        )
-        p = {
-            **p,
-            "target_critic": jax.tree.map(
-                lambda n_, o_: jnp.where(do_ema, n_, o_), new_target, p["target_critic"]
-            ),
-        }
+        with jax.named_scope("critic.optim"):
+            do_ema = (counter % target_freq) == 0
+            new_target = jax.tree.map(
+                lambda t, o: (1 - tau) * t + tau * o, p["target_critic"], p["critic"]
+            )
+            p = {
+                **p,
+                "target_critic": jax.tree.map(
+                    lambda n_, o_: jnp.where(do_ema, n_, o_), new_target, p["target_critic"]
+                ),
+            }
 
         post_ent = OneHotCategorical(jax.lax.stop_gradient(aux["post_logits"])).entropy().sum(-1).mean()
         prior_ent = OneHotCategorical(jax.lax.stop_gradient(aux["prior_logits"])).entropy().sum(-1).mean()

@@ -49,6 +49,7 @@ from sheeprl_tpu.algos.ppo.utils import (
 from sheeprl_tpu.data.buffers import ReplayBuffer
 from sheeprl_tpu.data.device_replay import stage_rollout, stage_scalar, steady_guard
 from sheeprl_tpu.envs.jax.registry import anakin_enabled
+from sheeprl_tpu.telemetry.spans import SPANS
 from sheeprl_tpu.utils.env import episode_stats, final_obs_rows, make_env, vectorize
 from sheeprl_tpu.utils.logger import get_log_dir, get_logger
 from sheeprl_tpu.utils.metric import MetricAggregator, flush_metrics
@@ -211,6 +212,7 @@ def main(fabric: Any, cfg: Any) -> None:
     gae_lambda = float(cfg.algo.gae_lambda)
     update_epochs = int(cfg.algo.update_epochs)
 
+    @jax.named_scope("update.loss")
     def loss_fn(p, batch, clip_coef, ent_coef):
         out, new_values = agent.apply(p, {k: batch[k] for k in obs_keys})
         new_logprobs, entropy = evaluate_actions(out, batch["actions"], actions_dim, is_continuous, dist_type=dist_type)
@@ -251,12 +253,13 @@ def main(fabric: Any, cfg: Any) -> None:
         # --- GAE (values recomputed in one batched forward) ---
         T, B = rollout["rewards"].shape
         flat_obs = {key_: rollout[key_].reshape((T * B,) + rollout[key_].shape[2:]) for key_ in obs_keys}
-        _, values = agent.apply(p, flat_obs)
-        values = values[..., 0].reshape(T, B)
-        next_value = values_fn(p, last_obs)
-        returns, advantages = gae(
-            rollout["rewards"], values, rollout["dones"], next_value, gamma, gae_lambda
-        )
+        with jax.named_scope("gae"):  # the value pass over the whole rollout included
+            _, values = agent.apply(p, flat_obs)
+            values = values[..., 0].reshape(T, B)
+            next_value = values_fn(p, last_obs)
+            returns, advantages = gae(
+                rollout["rewards"], values, rollout["dones"], next_value, gamma, gae_lambda
+            )
 
         flat = dict(flat_obs)
         flat["actions"] = rollout["actions"].reshape(T * B, -1)
@@ -278,13 +281,15 @@ def main(fabric: Any, cfg: Any) -> None:
 
             def mb_body(i, carry2):
                 p, o_state, losses = carry2
-                idx = jax.lax.dynamic_slice(perm, (i * batch_size,), (batch_size,))
-                batch = {kk: jnp.take(vv, idx, axis=0) for kk, vv in flat.items()}
+                with jax.named_scope("update.gather"):
+                    idx = jax.lax.dynamic_slice(perm, (i * batch_size,), (batch_size,))
+                    batch = {kk: jnp.take(vv, idx, axis=0) for kk, vv in flat.items()}
                 (_, (pg, vl, ent)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                     p, batch, clip_coef, ent_coef
                 )
-                updates, o_state = optimizer.update(grads, o_state, p)
-                p = optax.apply_updates(p, updates)
+                with jax.named_scope("update.optim"):
+                    updates, o_state = optimizer.update(grads, o_state, p)
+                    p = optax.apply_updates(p, updates)
                 return p, o_state, (pg, vl, ent)
 
             carry2 = (p, o_state, (jnp.zeros(()), jnp.zeros(()), jnp.zeros(())))
@@ -556,6 +561,7 @@ def main(fabric: Any, cfg: Any) -> None:
     profiler = ProfilerGate(cfg, log_dir)
     for update in range(start_iter, total_iters + 1):
         profiler.step(update)
+        SPANS.iteration(update)  # the `iter` span: closes the one before
         if use_anakin:
             # -------- fused rollout+train: ONE dispatch per update ---------
             with timer("Time/train_time"):
@@ -578,7 +584,10 @@ def main(fabric: Any, cfg: Any) -> None:
                 # the H2D-scoped steady guard)
                 from sheeprl_tpu.envs.jax.anakin import episode_stats_from_device
 
-                rets, lens = episode_stats_from_device(ep_stats)
+                # the loop's first wait for the fused dispatch: its host time
+                # is the device's, so it gets a span of its own
+                with SPANS.span("stats.pull", phase=False):
+                    rets, lens = episode_stats_from_device(ep_stats)
                 for ep_ret, ep_len in zip(rets, lens):
                     aggregator.update("Rewards/rew_avg", float(ep_ret))
                     aggregator.update("Game/ep_len_avg", int(ep_len))
@@ -733,6 +742,7 @@ def main(fabric: Any, cfg: Any) -> None:
             fabric.print(f"Preemption: committed checkpoint at step {policy_step}, exiting")
             break
 
+    SPANS.end_iteration()
     profiler.close()
     if envs is not None:
         envs.close()

@@ -45,6 +45,7 @@ from sheeprl_tpu.checkpoint.rollback import rollback_state
 from sheeprl_tpu.parallel.compile import compile_once
 from sheeprl_tpu.parallel.fabric import PlayerSync
 from sheeprl_tpu.resilience.health import DivergenceError, HealthSentinel
+from sheeprl_tpu.telemetry.spans import SPANS
 from sheeprl_tpu.utils.env import episode_stats, final_obs_rows, make_env, vectorize
 from sheeprl_tpu.utils.logger import get_log_dir, get_logger
 from sheeprl_tpu.utils.metric import MetricAggregator, flush_metrics
@@ -352,6 +353,7 @@ def sac_loop(fabric: Any, cfg: Any, build_agent_fn: Any, critic_apply: Any) -> N
     profiler = ProfilerGate(cfg, log_dir)
     for update in range(start_iter, total_iters + 1):
         profiler.step(update)
+        SPANS.iteration(update)  # the `iter` span: closes the one before
         policy_step += num_envs * fabric.num_processes
         with timer("Time/env_interaction_time"):
             if update <= learning_starts and not state:
@@ -557,6 +559,7 @@ def sac_loop(fabric: Any, cfg: Any, build_agent_fn: Any, critic_apply: Any) -> N
             fabric.print(f"Preemption: committed checkpoint at step {policy_step}, exiting")
             break
 
+    SPANS.end_iteration()
     profiler.close()
     envs.close()
     if sentinel is not None:

@@ -43,8 +43,9 @@ import queue
 import threading
 import time
 import warnings
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Optional, Tuple
 
+from sheeprl_tpu.telemetry.spans import SPANS
 from sheeprl_tpu.utils.profiler import CHECKPOINT_MONITOR
 
 
@@ -75,7 +76,8 @@ class AsyncCheckpointWriter:
         io_retry_base_s: float = 0.5,
         hang_warn_s: float = 120.0,
     ):
-        self._queue: "queue.Queue[Optional[Callable[[], Any]]]" = queue.Queue(
+        # (job, the span open on the submitting thread), or None to stop
+        self._queue: "queue.Queue[Optional[Tuple[Callable[[], Any], Any]]]" = queue.Queue(
             maxsize=max(1, int(queue_size))
         )
         self._error: Optional[BaseException] = None
@@ -114,21 +116,25 @@ class AsyncCheckpointWriter:
 
     def _loop(self) -> None:
         while True:
-            job = self._queue.get()
-            if job is None:
+            item = self._queue.get()
+            if item is None:
                 self._queue.task_done()
                 return
+            job, cause = item
             t0 = time.perf_counter()
             if self._watchdog is not None:
                 self._watchdog.arm()
             try:
                 # writer-thread span: snapshot cost shows up in the phase
                 # breakdown as concurrent ckpt.snapshot time, distinct from
-                # the learner's critical path (telemetry/spans.py)
-                from sheeprl_tpu.telemetry.spans import span
-
-                with span("ckpt.snapshot"):
+                # the learner's critical path (telemetry/spans.py); its
+                # parent and iteration are those of the span that queued it
+                # (the caller's ckpt.save)
+                token = SPANS.push("ckpt.snapshot", cause=cause)
+                try:
                     nbytes = self._run_job(job)
+                finally:
+                    SPANS.pop(token)
                 CHECKPOINT_MONITOR.record_save(
                     seconds=time.perf_counter() - t0,
                     nbytes=int(nbytes or 0),
@@ -165,7 +171,7 @@ class AsyncCheckpointWriter:
         with self._pending_lock:
             self._pending += 1
             self._idle.clear()
-        self._queue.put(job)
+        self._queue.put((job, SPANS.current()))
         CHECKPOINT_MONITOR.record_depth(self.in_flight)
 
     def flush(self, timeout_s: Optional[float] = None) -> bool:

@@ -635,8 +635,14 @@ class DeviceReplay:
             # donate the ring: updates are in-place, no 2x HBM spike; pin the
             # output back onto the replay sharding so a multi-device scatter
             # cannot drift the layout update-over-update
+            def scoped_set(arr, rows, t, e):
+                with jax.named_scope("replay.write"):
+                    return arr.at[t, e[None, :]].set(rows)
+
+            # a lambda still: the jitted function's name is the module's name, and
+            # that is part of the compile cache's key where the scope is not
             self._scatter = jax.jit(
-                lambda arr, rows, t, e: arr.at[t, e[None, :]].set(rows),
+                lambda arr, rows, t, e: scoped_set(arr, rows, t, e),
                 donate_argnums=0,
                 out_shardings=self._sharding,
             )
@@ -799,20 +805,24 @@ class DeviceReplay:
         empty, draws never exclude the write-head predecessor — exactly the
         host law.  Call INSIDE a jitted train program: the index generation
         and gather compile into the update step."""
+        import jax
+
         total = int(batch_size) * int(n_samples)
-        step, env = self.uniform_indices(cursor, key, total, sample_next_obs=bool(derive_next))
+        with jax.named_scope("replay.sample_index"):
+            step, env = self.uniform_indices(cursor, key, total, sample_next_obs=bool(derive_next))
         out: Dict[str, Any] = {}
-        for k, buf in buffers.items():
-            if keys is not None and k not in keys:
-                continue
-            out[k] = buf[step, env].reshape(n_samples, batch_size, *buf.shape[2:])
-        for k in derive_next:
-            if k in buffers:
-                nxt = (step + 1) % self._capacity
-                out[f"next_{k}"] = buffers[k][nxt, env].reshape(
-                    n_samples, batch_size, *buffers[k].shape[2:]
-                )
-        return self._constrain(out, batch_axis=1) if constrain else out
+        with jax.named_scope("replay.gather"):
+            for k, buf in buffers.items():
+                if keys is not None and k not in keys:
+                    continue
+                out[k] = buf[step, env].reshape(n_samples, batch_size, *buf.shape[2:])
+            for k in derive_next:
+                if k in buffers:
+                    nxt = (step + 1) % self._capacity
+                    out[f"next_{k}"] = buffers[k][nxt, env].reshape(
+                        n_samples, batch_size, *buffers[k].shape[2:]
+                    )
+            return self._constrain(out, batch_axis=1) if constrain else out
 
     def sequence_indices(self, cursor: Dict[str, Any], key: Any, total: int, sequence_length: int):
         """``(t_idx (total, L), env (total,))`` for contiguous sequence draws
@@ -850,17 +860,21 @@ class DeviceReplay:
     ) -> Dict[str, Any]:
         """Contiguous ``(n_samples, L, batch_size, *)`` sequence batches
         gathered on device — the Dreamer-family sampling layout."""
+        import jax
+
         total = int(batch_size) * int(n_samples)
         L = int(sequence_length)
-        t_idx, env = self.sequence_indices(cursor, key, total, L)
+        with jax.named_scope("replay.sample_index"):
+            t_idx, env = self.sequence_indices(cursor, key, total, L)
         out: Dict[str, Any] = {}
-        for k, buf in buffers.items():
-            if keys is not None and k not in keys:
-                continue
-            g = buf[t_idx, env[:, None]]  # (total, L, *feat)
-            g = g.reshape(n_samples, batch_size, L, *buf.shape[2:])
-            out[k] = g.swapaxes(1, 2)  # (n_samples, L, batch, *feat)
-        return self._constrain(out, batch_axis=2) if constrain else out
+        with jax.named_scope("replay.gather"):
+            for k, buf in buffers.items():
+                if keys is not None and k not in keys:
+                    continue
+                g = buf[t_idx, env[:, None]]  # (total, L, *feat)
+                g = g.reshape(n_samples, batch_size, L, *buf.shape[2:])
+                out[k] = g.swapaxes(1, 2)  # (n_samples, L, batch, *feat)
+            return self._constrain(out, batch_axis=2) if constrain else out
 
     def _constrain(self, tree: Dict[str, Any], batch_axis: int) -> Dict[str, Any]:
         """Re-lay sampled batches over the mesh ``data`` axis (the

@@ -145,14 +145,20 @@ def make_rollout_fn(
     def rollout(p: Any, actor: Dict[str, Any], key: jax.Array):
         def body(carry, k_step):
             env_state, ep_ret, ep_len = carry
-            pobs = prep(venv.observe(env_state))
-            out, value = agent_apply(p, pobs)
-            actions, logprob, _ = sample_fn(out, k_step)
-            env_state, _, reward, term, trunc, final_obs = venv.step(env_state, to_env(actions))
+            # named scopes (docs/telemetry.md): where a device trace puts
+            # the rollout's time — the render, the policy, the env
+            with jax.named_scope("rollout.observe"):
+                pobs = prep(venv.observe(env_state))
+            with jax.named_scope("rollout.policy"):
+                out, value = agent_apply(p, pobs)
+                actions, logprob, _ = sample_fn(out, k_step)
+            with jax.named_scope("rollout.env_step"):
+                env_state, _, reward, term, trunc, final_obs = venv.step(env_state, to_env(actions))
             # truncation bootstrap with the CURRENT params (the host loops'
             # `rewards[truncated] += gamma * V(final_obs)` — here final_obs
             # is always available, no padded re-dispatch needed)
-            _, v_final = agent_apply(p, prep(final_obs))
+            with jax.named_scope("rollout.policy"):
+                _, v_final = agent_apply(p, prep(final_obs))
             trunc_f = trunc.astype(jnp.float32)
             boot_reward = reward + gamma * v_final[..., 0] * trunc_f
             done = jnp.logical_or(term, trunc)
@@ -180,7 +186,8 @@ def make_rollout_fn(
         stats = {k: traj.pop(k) for k in ("ep_done", "ep_ret", "ep_len")}
         if not store_logprobs:
             traj.pop("logprobs")
-        last_obs = prep(venv.observe(env_state))
+        with jax.named_scope("rollout.observe"):
+            last_obs = prep(venv.observe(env_state))
         new_actor = {
             "env": env_state,
             "ep_ret": ep_ret,

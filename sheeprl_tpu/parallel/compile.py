@@ -33,6 +33,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import numpy as np
 
+from sheeprl_tpu.telemetry.spans import SPANS
 from sheeprl_tpu.utils.profiler import COMPILE_MONITOR, RecompileLimitExceeded  # noqa: F401
 
 _FALLBACK = object()  # cache sentinel: route this signature through plain jit
@@ -120,6 +121,7 @@ class AOTFunction:
         self._fn = fn
         self.name = name or getattr(fn, "__name__", "<anonymous>")
         self.__name__ = self.name
+        self._span_name = f"exec.{self.name}"
         self._static_argnums = tuple(static_argnums)
         self._static_argnames = tuple(static_argnames)
         # a static argument is static to jax.jit however it is passed —
@@ -321,34 +323,46 @@ class AOTFunction:
         sig, dyn_args, dyn_kwargs = self._signature_and_split(args, kwargs)
         if sig is None:  # traced inside another program: inline like plain jit
             return self._jitted(*args, **kwargs)
-        exe = self._lookup(sig, args, kwargs)
-        if exe is _FALLBACK:
-            return self._jitted(*args, **kwargs)
+        # the one place every fabric.compile program of every loop is
+        # dispatched from: its host span (a first call's compile included).
+        # No helper frame under the span: a first call lowers the program
+        # below this frame, and one more frame there moved JAX's recursive
+        # lowering onto a block boundary of CPython's frame stack, where
+        # every call pays an mmap and a munmap (3.5 s to lower the Anakin
+        # phase instead of 1.7; PERF.md section 6, PR 28)
+        token = SPANS.push(self._span_name, phase=False)
         try:
-            return exe(*dyn_args, **dyn_kwargs)
-        except (TypeError, ValueError):
-            # argument/executable mismatch our coarse signature missed
-            # (argument-validation errors fire before execution, so donated
-            # buffers are still intact) — plain jit is always correct; pin
-            # this signature to the fallback so the cost is paid once.
-            # The implicit-jit call re-traces for the TRUE signature: count
-            # that compile (and hold it to the budget) so retraces stay
-            # visible exactly where the coarse scheme failed — but only
-            # once the call SUCCEEDS: genuinely bad arguments raise the
-            # same error from plain jit without compiling anything, and
-            # must not leave a phantom executable in the audit.  Only LATER
-            # drift inside this pinned bucket escapes the audit.
-            fb_sig = ("jit-fallback",) + sig[1:]
-            self._check_budget(fb_sig)
+            exe = self._lookup(sig, args, kwargs)
+            if exe is _FALLBACK:
+                return self._jitted(*args, **kwargs)
             try:
-                out = self._jitted(*args, **kwargs)
-            except BaseException:
-                self._rollback_budget(fb_sig)
-                raise
-            self._monitor.begin(self.name, fb_sig)
-            with self._lock:
-                self._cache[sig] = _FALLBACK
-            return out
+                return exe(*dyn_args, **dyn_kwargs)
+            except (TypeError, ValueError):
+                # argument/executable mismatch our coarse signature missed
+                # (argument-validation errors fire before execution, so
+                # donated buffers are still intact) — plain jit is always
+                # correct; pin this signature to the fallback so the cost is
+                # paid once.  The implicit-jit call re-traces for the TRUE
+                # signature: count that compile (and hold it to the budget)
+                # so retraces stay visible exactly where the coarse scheme
+                # failed — but only once the call SUCCEEDS: genuinely bad
+                # arguments raise the same error from plain jit without
+                # compiling anything, and must not leave a phantom
+                # executable in the audit.  Only LATER drift inside this
+                # pinned bucket escapes the audit.
+                fb_sig = ("jit-fallback",) + sig[1:]
+                self._check_budget(fb_sig)
+                try:
+                    out = self._jitted(*args, **kwargs)
+                except BaseException:
+                    self._rollback_budget(fb_sig)
+                    raise
+                self._monitor.begin(self.name, fb_sig)
+                with self._lock:
+                    self._cache[sig] = _FALLBACK
+                return out
+        finally:
+            SPANS.pop(token)
 
     def cache_size(self) -> int:
         with self._lock:

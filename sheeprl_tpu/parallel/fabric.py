@@ -32,6 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from sheeprl_tpu.telemetry.spans import span
+
 
 @dataclass(frozen=True)
 class Precision:
@@ -844,17 +846,29 @@ class PlayerSync:
             "Player/param_staleness_max": float(self.staleness_max),
         }
 
+    def _pull(self, token: Any, params: Any) -> Any:
+        """The weight pull itself, its bytes counted on the open ``player.sync`` span."""
+        tree = self.extract(params)
+        if token is not None:
+            token.count(bytes=sum(int(x.nbytes) for x in jax.tree.leaves(tree)))
+        return self.fabric.copy_to(tree, self.device)
+
     def before_dispatch(self, player_params: Any) -> Any:
         """Pull the previous window's (long since finished) train output."""
-        if self._pending is not None:
-            pending, self._pending = self._pending, None
-            self._player_version = self._pending_version
+        with span("player.sync", phase=False) as token:
+            if self._pending is not None:
+                pending, self._pending = self._pending, None
+                self._player_version = self._pending_version
+                self._observe_staleness()
+                return self._pull(token, pending)
             self._observe_staleness()
-            return self.fabric.copy_to(self.extract(pending), self.device)
-        self._observe_staleness()
-        return player_params
+            return player_params
 
     def after_dispatch(self, params: Any, player_params: Any) -> Any:
+        with span("player.sync", phase=False) as token:
+            return self._after_dispatch(token, params, player_params)
+
+    def _after_dispatch(self, token: Any, params: Any, player_params: Any) -> Any:
         # Gate on COMPLETED TRAINING WINDOWS, not the env-loop update counter:
         # with a fractional replay_ratio the Ratio governor fires training on
         # a fixed update parity, and an `update % sync_every` gate can then
@@ -871,7 +885,7 @@ class PlayerSync:
             return player_params
         self._player_version = self._windows
         self._observe_staleness()
-        return self.fabric.copy_to(self.extract(params), self.device)
+        return self._pull(token, params)
 
     # -- checkpointing ------------------------------------------------------
     def state_dict(self) -> Dict[str, int]:

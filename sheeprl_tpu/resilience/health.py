@@ -370,7 +370,14 @@ class HealthSentinel:
     def poll(self, h: HealthState, policy_step: int) -> str:
         """Fetch the device state (tiny, once per poll interval), publish
         metrics/events, and return the pending action: ``"none"`` or
-        ``"rollback"``."""
+        ``"rollback"``.  The fetch waits for the dispatch that produced
+        ``h``: the loop's ``health.poll`` span."""
+        from sheeprl_tpu.telemetry.spans import span
+
+        with span("health.poll", phase=False):
+            return self._poll(h, policy_step)
+
+    def _poll(self, h: HealthState, policy_step: int) -> str:
         import jax
 
         vals = jax.device_get(h)

@@ -8,7 +8,8 @@ One place for everything a production RL run needs to be observable:
   resilience monitors (the old ``utils.profiler`` globals are thin shims
   over these)
 * :mod:`~sheeprl_tpu.telemetry.spans`     — ``SPANS``: nestable step-phase
-  spans → per-window ``Phase/*`` breakdown fractions
+  spans → per-window ``Phase/*`` breakdown fractions, one record per closed
+  span (``SPANS.records()``), and the same spans as profiler annotations
 * :mod:`~sheeprl_tpu.telemetry.tracer`    — ``TRACER``: on-demand XLA
   profiler windows (``telemetry.trace_at`` / ``SHEEPRL_TRACE_AT`` /
   ``SIGUSR1``)
@@ -44,7 +45,7 @@ from sheeprl_tpu.telemetry.monitors import (  # noqa: F401
     ResilienceMonitor,
 )
 from sheeprl_tpu.telemetry.recorder import RECORDER, FlightRecorder  # noqa: F401
-from sheeprl_tpu.telemetry.spans import SPANS, SpanTracker, span  # noqa: F401
+from sheeprl_tpu.telemetry.spans import SPANS, SpanRecord, SpanTracker, span  # noqa: F401
 from sheeprl_tpu.telemetry.tracer import TRACER, TraceScheduler  # noqa: F401
 
 _SERVER: Optional[IntrospectionServer] = None
@@ -67,6 +68,7 @@ def setup_run(cfg: Any, log_dir: Optional[str], rank: int = 0) -> None:
     introspection server restarts only when a port is configured."""
     tcfg = (cfg.get("telemetry") or {}) if hasattr(cfg, "get") else {}
     SPANS.configure(tcfg.get("spans") or {})
+    COMPILE_MONITOR.install()  # JAX's own compile event (the jax.jit programs fabric.compile never sees)
     RECORDER.configure(tcfg.get("recorder") or {}, run_dir=log_dir)
     TRACER.configure(tcfg, log_dir)
     TRACER.install_signal()  # SIGUSR1 → one trace window (main thread only)
@@ -93,8 +95,11 @@ def setup_run(cfg: Any, log_dir: Optional[str], rank: int = 0) -> None:
 
 
 def shutdown_run() -> None:
-    """End-of-run teardown: stop an open trace window and the introspection
-    server.  Called from the ``finally`` path of ``cli.run``."""
+    """End-of-run teardown: close the iteration span a raising loop left open,
+    stop an open trace window and the introspection server.  Called from the
+    ``finally`` path of ``cli.run``; the span log stays (``SPANS.records()`` is
+    read after the run)."""
+    SPANS.end_iteration()
     TRACER.close()
     global _SERVER
     with _SERVER_LOCK:

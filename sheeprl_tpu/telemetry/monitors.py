@@ -27,6 +27,10 @@ class RecompileLimitExceeded(RuntimeError):
     """A compile-once function exceeded its allowed recompile budget."""
 
 
+#: JAX's own event around every backend compile (or persistent-cache read)
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
 class CompileMonitor:
     """Process-global per-function compile counter + abstract-signature log.
 
@@ -36,11 +40,54 @@ class CompileMonitor:
     enforced per-``AOTFunction`` instance (see ``parallel/compile.py``),
     which raises :class:`RecompileLimitExceeded`; this monitor is the
     process-wide aggregate view (metrics, dryrun stage summaries).
+
+    ``fabric.compile`` programs are not all a run compiles: the replay
+    ring's ``jax.jit`` scatter builds a program for every count of envs
+    that finish at once.  :meth:`install` therefore also listens to JAX's
+    own compile event, counts it (``Compile/backend_compiles``) and writes
+    a ``compile.backend`` recorder event naming the span open on the
+    compiling thread and its loop iteration — which is how an operator
+    learns which step recompiled.
     """
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._stats: Dict[str, Dict[str, Any]] = {}
+        self._backend = [0, 0.0]  # count, seconds
+        self._backend_flushed = 0  # the count the last rolling flush logged
+        self._installed = False
+
+    # -- JAX's own compile event ---------------------------------------------
+    def install(self) -> None:
+        """Listen to JAX's compile event (idempotent; ``telemetry.setup_run``)."""
+        with self._lock:
+            if self._installed:
+                return
+            self._installed = True
+        import jax
+
+        jax.monitoring.register_event_duration_secs_listener(self._on_jax_event)
+
+    def _on_jax_event(self, event: str, duration: float, **_: Any) -> None:
+        if event != BACKEND_COMPILE_EVENT:
+            return
+        from sheeprl_tpu.telemetry.spans import SPANS
+
+        with self._lock:
+            self._backend[0] += 1
+            self._backend[1] += float(duration)
+        under = SPANS.current()
+        RECORDER.record(
+            "compile.backend",
+            seconds=round(float(duration), 3),
+            span=under.name if under is not None else None,
+            iteration=under.iteration if under is not None else None,
+        )
+
+    def backend_totals(self) -> Tuple[int, float]:
+        """(compile events seen, their seconds): every program, jitted or AOT."""
+        with self._lock:
+            return int(self._backend[0]), float(self._backend[1])
 
     # -- recording (called by parallel.compile.AOTFunction) -----------------
     def begin(self, name: str, signature: Any) -> None:
@@ -131,12 +178,21 @@ class CompileMonitor:
     def compile_metrics(self) -> Dict[str, float]:
         """Aggregate counters for the hub flush (see metric.flush_metrics)."""
         count, seconds = self.totals()
-        if count == 0:
-            return {}
-        return {
-            "Compile/executables": float(count),
-            "Compile/compile_time_s": round(seconds, 3),
-        }
+        backend, backend_s = self.backend_totals()
+        out: Dict[str, float] = {}
+        if count:
+            out["Compile/executables"] = float(count)
+            out["Compile/compile_time_s"] = round(seconds, 3)
+        if backend != self._backend_flushed:
+            # only while it moves: a steady run pays no scalar for it
+            out["Compile/backend_compiles"] = float(backend)
+            out["Compile/backend_compile_time_s"] = round(backend_s, 3)
+        return out
+
+    def roll(self) -> None:
+        """The hub's roll hook: the backend count now logged."""
+        with self._lock:
+            self._backend_flushed = self._backend[0]
 
     # hub-source alias: the hub polls ``metrics()`` on registered objects
     metrics = compile_metrics
@@ -144,6 +200,8 @@ class CompileMonitor:
     def reset(self) -> None:
         with self._lock:
             self._stats.clear()
+            self._backend = [0, 0.0]
+            self._backend_flushed = 0
 
 
 #: The process-global monitor every AOTFunction reports into.
@@ -334,6 +392,6 @@ RESILIENCE_MONITOR = ResilienceMonitor()
 
 
 # absorbed behind the hub's one registration API / one flush contract
-HUB.register("compile", COMPILE_MONITOR.compile_metrics)
+HUB.register("compile", COMPILE_MONITOR.compile_metrics, on_roll=COMPILE_MONITOR.roll)
 HUB.register("checkpoint", CHECKPOINT_MONITOR.metrics)
 HUB.register("resilience", RESILIENCE_MONITOR.metrics)

@@ -1,23 +1,51 @@
-"""Step-phase spans: nestable host-side timing with per-window breakdowns.
+"""Step-phase spans: nestable host-side timing, one record per boundary.
 
 The named timers (``utils/timer.py``) answer "how long did phase X take";
 they cannot answer "what FRACTION of the window went where" — the number
 that decides whether to tune ``traj_queue_slots`` (queue waits dominate)
 or shard the model further (update dispatch dominates).  The span tracker
-keeps a per-thread stack of open spans, attributes each span its
-EXCLUSIVE time (children subtracted), and aggregates a rolling window
-into phase-breakdown fractions that sum to ~1.0 (an ``other`` bucket
-absorbs untracked host time).
+keeps a per-thread stack of open spans and does three things with every
+push/pop pair:
 
-Span taxonomy (docs/telemetry.md):
+* attributes the span its EXCLUSIVE time (children subtracted) and
+  aggregates a rolling window into phase-breakdown fractions that sum to
+  ~1.0 (an ``other`` bucket absorbs untracked host time): ``Phase/*``,
+  ``/v1/phase``, the postmortem's ``phase_breakdown``;
+* appends one :class:`SpanRecord` to a bounded in-memory ring
+  (:data:`RECORD_CAPACITY` records, untouched by ``roll_window``):
+  ``name, start, end`` on ``time.perf_counter``, its own ``id``, its
+  ``parent`` (the span open under it on the same thread, or the span that
+  caused it across threads), the loop ``iteration`` it belongs to, the
+  thread's name and an optional small dict of counts.
+  ``SPANS.records()`` hands them to whoever wants the run's own account
+  of an iteration (``chipbench/metrics/``);
+* enters/exits a ``jax.profiler.TraceAnnotation`` of the span's name (the
+  iteration span a ``StepTraceAnnotation`` with ``step_num=update``) that
+  carries the record's ``id`` as its ``span`` stat, which
+  costs next to nothing while no profiler session records and puts every
+  host span on the device trace's clock while one does — a
+  ``ProfilerGate`` window, a ``TRACER`` window or anybody's
+  ``jax.profiler.start_trace`` alike.
 
+Span taxonomy (docs/telemetry.md has the table with counts and parents):
+
+* ``iter``             — one loop iteration (:meth:`SpanTracker.iteration`,
+  called beside ``profiler.step(update)``); every span below that the
+  loop's thread opens is its descendant and carries its ``iteration``
 * ``rollout``          — env interaction / segment collection
+* ``env.step``         — the vector env's ``step`` (``utils/env.vectorize``)
+* ``exec.<program>``   — one dispatch of a ``fabric.compile`` program
+  (``parallel/compile.py AOTFunction.__call__``)
 * ``queue.wait``       — the learner blocked on the trajectory queue
 * ``replay.write``     — host→ring staging of new rows
 * ``update.dispatch``  — the train-phase device dispatch (fused on-device
   sampling included — it is part of the same executable)
+* ``player.sync``      — ``PlayerSync.before_dispatch``/``after_dispatch``
 * ``param.broadcast``  — learner→actor param publication
-* ``ckpt.snapshot``    — checkpoint serialize+write (writer thread)
+* ``log.flush`` / ``health.poll`` / ``ckpt.save`` — the loop's stalls:
+  the metric flush, the sentinel's poll, the caller-thread part of a save
+* ``ckpt.snapshot``    — checkpoint serialize+write (writer thread; its
+  ``parent`` and ``iteration`` are those of the ``ckpt.save`` that queued it)
 * ``pipeline.stage.<name>.fwd`` / ``.bwd`` — per-stage forward/backward
   wall time of the pipelined world-model update, measured by
   ``bench.py --mode pipeline``'s standalone stage programs
@@ -28,25 +56,42 @@ Span taxonomy (docs/telemetry.md):
   schedule's idle fraction ``(S-1)/(M+S-1)`` (docs/pipeline.md).
 
 Wiring is centralized: ``utils.timer`` bridges the two phase timers every
-loop already has (:data:`TIMER_PHASES`), and the sebulba runner /
-topology / checkpoint / replay layers open their own spans — no per-loop
-copies.  Opening a top-level ``update.dispatch`` span also ticks the
-trace scheduler (``tracer.py``), which is how trace windows count
-updates without the loops knowing.
+loop already has (:data:`TIMER_PHASES`), and the compile / env / replay /
+player-sync / checkpoint / metric / health layers open their own spans —
+no per-loop copies beyond the one ``SPANS.iteration(update)`` line.
+
+Two kinds of span, chosen where the span is opened.  A PHASE (the default:
+``rollout``, ``update.dispatch``, ``replay.write``, ``queue.wait``,
+``param.broadcast``, ``ckpt.snapshot``, ``pipeline.stage.*``) is what
+``Phase/*`` is made of, and a phase with no phase open under it is
+TOP-LEVEL: opening a top-level ``update.dispatch`` ticks the trace
+scheduler (``tracer.py``), closing one feeds ``/healthz`` liveness, and
+top-level phase edges are flight-recorder events.  A BOUNDARY
+(``phase=False``: ``iter``, ``exec.*``, ``env.step``, ``player.sync``,
+``stats.pull``, ``log.flush``, ``health.poll``, ``ckpt.save``) is a record
+and a profiler annotation and nothing else: its time stays with the phase
+it runs under (else ``Phase/other``), it makes no phase less top-level and
+writes no recorder event — so ``Phase/*``, ``/v1/phase``, ``/healthz`` and
+the postmortem read as they did before the boundaries existed.
+
 
 Device attribution: dispatch is asynchronous, so a span's host time is
-not its device time.  While a trace window is armed (``TRACER.active``)
-or ``telemetry.spans.sync`` is set, span edges drain the device
-(``utils.device_sync``), making phases attributable exactly when someone
-is looking; steady-state runs never pay the fence.
+not its device time, and no span edge ever waits for the device — a trace
+window records the pipeline as it runs.  The train phases carry
+``jax.named_scope``s (docs/telemetry.md), which is how device time is
+attributed (``python -m chipbench.scopes <trace dir>``, which keeps their
+list); ``metric.sync_timers`` is the one fence knob left, on the bridged
+phases.
 """
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
+from collections import deque
 from contextlib import contextmanager
-from typing import Any, Dict, Optional
+from typing import Any, Deque, Dict, List, NamedTuple, Optional, Tuple
 
 from sheeprl_tpu.telemetry.hub import HUB
 from sheeprl_tpu.telemetry.recorder import RECORDER
@@ -59,28 +104,81 @@ TIMER_PHASES: Dict[str, str] = {
     "Time/train_time": "update.dispatch",
 }
 
+#: the iteration span: the frame every other span of a loop thread hangs under
+ITER = "iter"
+
+#: closed spans kept in memory (a DV3 iteration closes about 20)
+RECORD_CAPACITY = 32768
+
 _now = time.perf_counter
+_ids = itertools.count(1)
+
+
+class SpanRecord(NamedTuple):
+    """One closed span.  ``start``/``end`` are ``time.perf_counter`` seconds."""
+
+    name: str
+    start: float
+    end: float
+    id: int
+    parent: Optional[int]
+    iteration: Optional[int]
+    thread: str
+    counts: Optional[Dict[str, int]]
+
+
+_marks: Optional[Tuple[Any, Any]] = None  # jax.profiler's two annotation classes, looked up once
+
+
+def _annotate(name: str, span_id: int, step: Optional[int] = None) -> Any:
+    """The profiler's own host annotation for one span (entered by the caller).
+    Its ``span`` stat is the record's ``id``: what tells the program's spans
+    from JAX's own marks on the same thread's line of a trace, and joins a
+    trace to ``SPANS.records()``."""
+    global _marks
+    if _marks is None:
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+        _marks = (TraceAnnotation, StepTraceAnnotation)
+    if step is None:
+        return _marks[0](name, span=span_id)
+    return _marks[1](name, step_num=step, span=span_id)
 
 
 class _Span:
-    __slots__ = ("name", "start", "child_s")
+    __slots__ = ("name", "start", "child_s", "id", "parent", "iteration", "counts", "phase", "up", "mark")
 
-    def __init__(self, name: str, start: float) -> None:
+    def __init__(self, name, span_id, start, parent, iteration, counts, phase, up, mark) -> None:
         self.name = name
         self.start = start
         self.child_s = 0.0
+        self.id = span_id
+        self.parent = parent
+        self.iteration = iteration
+        self.counts = counts
+        self.phase = phase  # False: a boundary (record and annotation only)
+        self.up = up  # the innermost phase open under it on its thread (None: top-level)
+        self.mark = mark
+
+    def count(self, **counts: int) -> None:
+        """Add to this span's counts (what it moved is often known only inside it)."""
+        if self.counts is None:
+            self.counts = {}
+        for k, v in counts.items():
+            self.counts[k] = self.counts.get(k, 0) + int(v)
 
 
 class SpanTracker:
-    """Process-global span stack (per-thread) + windowed phase aggregator."""
+    """Process-global span stack (per-thread) + windowed phase aggregator
+    + the bounded log of closed spans."""
 
     def __init__(self) -> None:
         self.enabled = True
-        self.sync = False
         self._lock = threading.Lock()
         self._local = threading.local()
         self._excl: Dict[str, float] = {}
         self._counts: Dict[str, int] = {}
+        self._records: Deque[SpanRecord] = deque(maxlen=RECORD_CAPACITY)
         self._window_start = _now()
         # liveness signal for /healthz (introspect.py): wall time of the
         # newest COMPLETED top-level update.dispatch span + total count —
@@ -94,7 +192,6 @@ class SpanTracker:
         """Apply the ``telemetry.spans`` config group."""
         cfg = cfg or {}
         self.enabled = bool(cfg.get("enabled", True))
-        self.sync = bool(cfg.get("sync", False))
 
     # -- the span stack ------------------------------------------------------
     def _stack(self) -> list:
@@ -103,27 +200,39 @@ class SpanTracker:
             stack = self._local.stack = []
         return stack
 
-    @staticmethod
-    def _fence() -> None:
-        try:
-            from sheeprl_tpu.utils.utils import device_sync
-
-            device_sync()
-        except Exception:
-            pass  # attribution is best-effort; never take down the run
-
-    def push(self, name: str) -> Optional[_Span]:
+    def push(
+        self,
+        name: str,
+        counts: Optional[Dict[str, int]] = None,
+        cause: Optional[_Span] = None,
+        iteration: Optional[int] = None,
+        phase: bool = True,
+    ) -> Optional[_Span]:
         """Open a span; returns the token :meth:`pop` needs (None when
-        disabled — pop of None is a no-op, so call sites stay branch-free)."""
+        disabled — pop of None is a no-op, so call sites stay branch-free).
+
+        ``phase=False`` opens a boundary: a record and an annotation, no part
+        of ``Phase/*``.  ``cause`` is what :meth:`current` returned on the
+        thread that asked for this work: where nothing is open under the new
+        span on its own thread, that span is its parent and gives it its
+        iteration."""
         if not self.enabled:
             return None
         stack = self._stack()
-        if name == "update.dispatch" and not stack:
-            # the update tick stream the trace scheduler counts on
+        under = stack[-1] if stack else None
+        origin = under if under is not None else cause
+        parent, inherited = (origin.id, origin.iteration) if origin is not None else (None, None)
+        up = under if under is None or under.phase else under.up
+        if phase and up is None and name == "update.dispatch":
+            # the update tick stream the trace scheduler counts on; before the
+            # annotation, so a window this tick opens holds this dispatch
             TRACER.tick()
-        if self.sync or TRACER.active:
-            self._fence()
-        span = _Span(name, _now())
+        if iteration is None:
+            iteration = inherited
+        span_id = next(_ids)
+        mark = _annotate(name, span_id, iteration if name == ITER else None)
+        mark.__enter__()
+        span = _Span(name, span_id, _now(), parent, iteration, counts, phase, up, mark)
         stack.append(span)
         return span
 
@@ -132,33 +241,37 @@ class SpanTracker:
         raise between push and pop unwinds with the parent)."""
         if token is None:
             return
-        if self.sync or TRACER.active:
-            self._fence()
         stack = self._stack()
         end = _now()
+        thread = threading.current_thread().name
         while stack:
             span = stack.pop()
+            span.mark.__exit__(None, None, None)
             dur = max(0.0, end - span.start)
-            excl = max(0.0, dur - span.child_s)
-            if stack:
-                stack[-1].child_s += dur
+            top = span.phase and span.up is None
+            if span.phase and span.up is not None:
+                span.up.child_s += dur
+            record = SpanRecord(
+                span.name, span.start, end, span.id, span.parent, span.iteration, thread, span.counts
+            )
             with self._lock:
-                self._excl[span.name] = self._excl.get(span.name, 0.0) + excl
-                self._counts[span.name] = self._counts.get(span.name, 0) + 1
-            if not stack:
-                # top-level span edges are flight-recorder events (bounded
+                self._records.append(record)
+                if span.phase:
+                    self._excl[span.name] = self._excl.get(span.name, 0.0) + max(0.0, dur - span.child_s)
+                    self._counts[span.name] = self._counts.get(span.name, 0) + 1
+                if top and span.name == "update.dispatch":
+                    self._last_update_done = time.time()
+                    self._updates_done += 1
+            if top:
+                # top-level phase edges are flight-recorder events (bounded
                 # ring — per-update cadence, not per-env-step)
                 RECORDER.record("span", name=span.name, seconds=round(dur, 6))
-                if span.name == "update.dispatch":
-                    with self._lock:
-                        self._last_update_done = time.time()
-                        self._updates_done += 1
             if span is token:
                 return
 
     @contextmanager
-    def span(self, name: str):
-        token = self.push(name)
+    def span(self, name: str, phase: bool = True, **counts: int):
+        token = self.push(name, counts or None, phase=phase)
         try:
             yield token
         finally:
@@ -166,6 +279,35 @@ class SpanTracker:
 
     def depth(self) -> int:
         return len(self._stack())
+
+    def current(self) -> Optional[_Span]:
+        """The innermost span open on this thread (``name``, ``id``,
+        ``iteration``): what a worker thread passes as ``cause`` for the work
+        this span queued, and what a compile event is put down to."""
+        stack = self._stack()
+        return stack[-1] if stack else None
+
+    # -- the iteration frame -------------------------------------------------
+    def iteration(self, update: int) -> None:
+        """Top of a loop iteration: close the iteration span open on this
+        thread (and whatever leaked under it) and open the one for ``update``."""
+        self.end_iteration()
+        self._local.iter = self.push(ITER, iteration=int(update), phase=False)
+
+    def end_iteration(self) -> None:
+        """After the loop (and from ``shutdown_run``, for a loop that raised)."""
+        token = getattr(self._local, "iter", None)
+        if token is not None:
+            self._local.iter = None
+            if token in self._stack():
+                self.pop(token)
+
+    # -- the span log --------------------------------------------------------
+    def records(self) -> List[SpanRecord]:
+        """Closed spans, oldest first.  Open spans are not in the log: a span
+        that never closes (the loop left by an exception) never shows up."""
+        with self._lock:
+            return list(self._records)
 
     # -- liveness ------------------------------------------------------------
     def last_update_age_s(self) -> Optional[float]:
@@ -223,19 +365,24 @@ class SpanTracker:
 
     def roll_window(self) -> None:
         """Start a fresh aggregation window (fired by the per-interval
-        metric flush via the hub's ``on_roll`` hook)."""
+        metric flush via the hub's ``on_roll`` hook).  The span log stays."""
         with self._lock:
             self._excl.clear()
             self._counts.clear()
             self._window_start = _now()
 
     def reset(self) -> None:
-        """Tests: fresh window + default knobs (per-thread stacks drain
-        naturally as their context managers exit)."""
+        """Tests: fresh window, empty log, default knobs, and this thread's
+        stack closed (other threads' stacks drain as their context managers
+        exit)."""
+        stack = self._stack()
+        while stack:
+            stack.pop().mark.__exit__(None, None, None)
+        self._local.iter = None
         self.roll_window()
         self.enabled = True
-        self.sync = False
         with self._lock:
+            self._records.clear()
             self._last_update_done = None
             self._updates_done = 0
 

@@ -17,9 +17,10 @@ Update numbering is the train-dispatch count: the span layer calls
 ``Time/train_time`` phase every loop already wraps), so no per-loop wiring
 exists.  Each window captures ``telemetry.trace_updates`` dispatches into
 ``<log_dir>/trace/update_<n>`` (viewable with TensorBoard's profile
-plugin / xprof).  While a window is open the span layer fences device
-dispatch boundaries, so the trace's host markers line up with device
-streams; when no window is armed the fence — and its cost — is absent.
+plugin / xprof, or reduce it with ``python -m chipbench.scopes``).  A
+window records the run as it is: the span layer fences nothing while one
+is open; its spans are in the trace as profiler annotations, and the
+train phases carry named scopes (``telemetry/spans.py``).
 """
 
 from __future__ import annotations
@@ -63,8 +64,7 @@ class TraceScheduler:
         self._stop_at = 0
         self._signal_armed = False
         self._signal_installed = False
-        #: a window is open right now — the span layer reads this to decide
-        #: whether span edges fence the device
+        #: a window is open right now
         self.active = False
         self.windows_captured = 0
 

@@ -21,6 +21,7 @@ from typing import Any, Dict, Optional
 import numpy as np
 
 from sheeprl_tpu.data.buffers import EnvIndependentReplayBuffer, EpisodeBuffer, ReplayBuffer
+from sheeprl_tpu.telemetry.spans import span
 from sheeprl_tpu.utils.checkpoint import prune_checkpoints
 
 
@@ -36,22 +37,27 @@ class CheckpointCallback:
         state: Dict[str, Any],
         replay_buffer: Any = None,
     ) -> None:
-        if replay_buffer is not None:
-            with _consistent_tail(replay_buffer):
-                state = dict(state)
-                state["rb"] = _buffer_state(replay_buffer)
-                # with an async manager the state SNAPSHOT (host memcpys)
-                # happens inside save() on this thread, i.e. still under the
-                # tail patch — only serialization/IO runs in the background
+        # ckpt.save: the part of a checkpoint that holds the calling loop (the
+        # buffer's state, the on-device copies, the host memcpys); the writer
+        # thread's ckpt.snapshot takes this span as its parent
+        with span("ckpt.save", phase=False):
+            if replay_buffer is not None:
+                with _consistent_tail(replay_buffer):
+                    state = dict(state)
+                    state["rb"] = _buffer_state(replay_buffer)
+                    # with an async manager the state SNAPSHOT (host memcpys)
+                    # happens inside save() on this thread, i.e. still under the
+                    # tail patch — only serialization/IO runs in the background
+                    self._save(fabric, ckpt_path, state)
+            else:
                 self._save(fabric, ckpt_path, state)
-        else:
-            self._save(fabric, ckpt_path, state)
 
     def on_checkpoint_player(self, fabric: Any, ckpt_path: str, state: Dict[str, Any], replay_buffer: Any = None) -> None:
         self.on_checkpoint_coupled(fabric, ckpt_path, state, replay_buffer)
 
     def on_checkpoint_trainer(self, fabric: Any, ckpt_path: str, state: Dict[str, Any]) -> None:
-        self._save(fabric, ckpt_path, state)
+        with span("ckpt.save", phase=False):
+            self._save(fabric, ckpt_path, state)
 
     # -- save routing --------------------------------------------------------
     def _save(self, fabric: Any, ckpt_path: str, state: Dict[str, Any]) -> None:

@@ -445,6 +445,26 @@ class StepDeadlineVectorEnv:
         self._env.close(**kwargs)
 
 
+def _span_step(envs: Any) -> Any:
+    """Put the ``env.step`` span (telemetry/spans.py) around ``envs.step`` as
+    the loops call it: one span per loop step over all envs, which is the
+    env layer's boundary whatever steps under it (gym processes, the
+    ``JaxToGymAdapter``s of a jax env).  The vector env keeps its type."""
+    from sheeprl_tpu.telemetry.spans import SPANS
+
+    step = envs.step
+
+    def spanned_step(actions: Any):
+        token = SPANS.push("env.step", phase=False)
+        try:
+            return step(actions)
+        finally:
+            SPANS.pop(token)
+
+    envs.step = spanned_step
+    return envs
+
+
 def vectorize(cfg: Any, thunks: list) -> gym.vector.VectorEnv:
     """Vectorize with SAME_STEP autoreset so rollout loops observe the
     pre-1.0 gymnasium semantics the algorithms are written against
@@ -457,18 +477,19 @@ def vectorize(cfg: Any, thunks: list) -> gym.vector.VectorEnv:
     nothing to watchdog from inside the process."""
     from gymnasium.vector import AutoresetMode
 
-    if cfg.env.sync_env:
-        return gym.vector.SyncVectorEnv(thunks, autoreset_mode=AutoresetMode.SAME_STEP)
-
     def make() -> gym.vector.VectorEnv:
         return gym.vector.AsyncVectorEnv(thunks, autoreset_mode=AutoresetMode.SAME_STEP)
 
     deadline = float(cfg.env.get("step_deadline_s", 0) or 0)
-    if deadline > 0:
-        return StepDeadlineVectorEnv(
+    if cfg.env.sync_env:
+        envs = gym.vector.SyncVectorEnv(thunks, autoreset_mode=AutoresetMode.SAME_STEP)
+    elif deadline > 0:
+        envs = StepDeadlineVectorEnv(
             make,
             deadline,
             max_restarts=int(cfg.env.get("max_vecenv_restarts", 3) or 3),
             window_s=float(cfg.env.get("vecenv_restart_window_s", 600.0) or 600.0),
         )
-    return make()
+    else:
+        envs = make()
+    return _span_step(envs)

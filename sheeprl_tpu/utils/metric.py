@@ -148,7 +148,16 @@ def flush_metrics(
     dreamer_v3.py:715-730), merge ``extra_times`` (e.g. trainer-side times
     shipped over DCN in the dedicated decoupled topology) and
     ``extra_metrics`` (e.g. ``Params/replay_ratio``), log, and return the new
-    ``last_log``."""
+    ``last_log``.  The whole flush is the loop's ``log.flush`` span."""
+    from sheeprl_tpu.telemetry.spans import span
+
+    with span("log.flush", phase=False):
+        return _flush_metrics(
+            aggregator, timer_obj, logger, policy_step, last_log, extra_times, extra_metrics
+        )
+
+
+def _flush_metrics(aggregator, timer_obj, logger, policy_step, last_log, extra_times, extra_metrics) -> int:
     metrics = aggregator.compute()
     aggregator.reset()
     times = timer_obj.to_dict(reset=True)

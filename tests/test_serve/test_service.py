@@ -110,6 +110,8 @@ def test_steady_state_never_recompiles(ppo_service):
 
 
 def test_service_stats_shape(ppo_service):
+    # a latency needs a served request: under xdist this test can be the first of its worker
+    ppo_service.act(_zero_obs(ppo_service.player), timeout=60.0)
     stats = ppo_service.stats()
     for field in (
         "served", "batches", "errors", "avg_batch", "padded_frac",

@@ -1,4 +1,5 @@
-"""Span nesting / aggregation math, the timer bridge, and fencing rules."""
+"""Span nesting / aggregation math, the timer bridge, and the profiler annotations
+(no span edge fences the device any more)."""
 
 import pytest
 
@@ -138,19 +139,72 @@ class TestTimerBridge:
             timer.disabled = False
 
 
+class _Mark:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs its own enter/exit."""
+
+    def __init__(self, log, name, step):
+        self.log, self.name, self.step = log, name, step
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, self.step))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, self.step))
+        return False
+
+
 class TestFencing:
     def test_fence_called_only_when_armed(self, monkeypatch):
-        calls = []
-        monkeypatch.setattr(SPANS, "_fence", lambda: calls.append(1))
+        """The fence is gone: every push/pop enters and exits one profiler
+        annotation of the span's name and never drains the device — with a
+        trace window open (``TRACER.active``) as without."""
+        from sheeprl_tpu.utils import utils as utils_mod
+
+        syncs, marks = [], []
+        monkeypatch.setattr(utils_mod, "device_sync", lambda *a, **k: syncs.append(1))
+        monkeypatch.setattr(
+            spans_mod, "_annotate", lambda name, span_id, step=None: _Mark(marks, name, step)
+        )
+        with SPANS.span("rollout"):
+            with SPANS.span("env.step", phase=False):
+                pass
+        assert marks == [
+            ("enter", "rollout", None), ("enter", "env.step", None),
+            ("exit", "env.step", None), ("exit", "rollout", None),
+        ]
+        del marks[:]
+        monkeypatch.setattr(TRACER, "active", True)  # a trace window is open
+        SPANS.iteration(7)
+        with SPANS.span("update.dispatch"):
+            pass
+        SPANS.end_iteration()
+        # the iteration is the profiler's step annotation, numbered by the loop
+        assert marks == [
+            ("enter", "iter", 7), ("enter", "update.dispatch", None),
+            ("exit", "update.dispatch", None), ("exit", "iter", 7),
+        ]
+        assert not syncs
+        assert not hasattr(SPANS, "sync") and not hasattr(SPANS, "_fence")
+
+    def test_leaked_children_exit_their_annotations_innermost_first(self, monkeypatch):
+        marks = []
+        monkeypatch.setattr(
+            spans_mod, "_annotate", lambda name, span_id, step=None: _Mark(marks, name, step)
+        )
+        outer = SPANS.push("rollout")
+        SPANS.push("queue.wait")  # leaks (a raise between push and pop)
+        SPANS.pop(outer)
+        assert [m[:2] for m in marks[2:]] == [("exit", "queue.wait"), ("exit", "rollout")]
+
+    def test_real_annotations_cost_nothing_without_a_profiler_session(self):
+        """No session records: the default annotations are jax.profiler's own
+        and a span is still just a span."""
+        import jax
+
+        mark = spans_mod._annotate("rollout", 1)
+        assert isinstance(mark, jax.profiler.TraceAnnotation)
+        assert isinstance(spans_mod._annotate("iter", 2, 3), jax.profiler.StepTraceAnnotation)
         with SPANS.span("rollout"):
             pass
-        assert not calls  # sync off, no trace window: no fence
-        SPANS.sync = True
-        with SPANS.span("rollout"):
-            pass
-        assert len(calls) == 2  # entry + exit
-        SPANS.sync = False
-        monkeypatch.setattr(TRACER, "active", True)
-        with SPANS.span("rollout"):
-            pass
-        assert len(calls) == 4  # trace window armed → fenced again
+        assert SPANS.breakdown()["phases"]["rollout"]["count"] == 1
