@@ -438,8 +438,7 @@ def _check_dv3(phase, cfg, checks, info, programs, rings, ring_alloc, want_platf
 
     # the ring: what it cost on the device against its raw bytes
     raw = ring.hbm_bytes
-    specs = {k: (b.shape[2:], b.dtype) for k, b in ring.buffers.items()}
-    compiled_says = ring_device_bytes(specs, ring.capacity, ring.n_envs, ring._sharding)
+    compiled_says = ring_device_bytes(ring.leaf_specs, ring.capacity, ring.n_envs, ring._sharding)
     info["ring"] = {
         "steps_per_env": ring.capacity,
         "n_envs": ring.n_envs,

@@ -11,6 +11,10 @@ makes HBM the home of replay:
   sharded over the mesh ``data`` axis along the env dimension
   (:func:`sheeprl_tpu.parallel.sharding.replay_sharding`) so the ring's
   layout matches what ``fabric.shard_batch`` would give a shipped batch.
+  A feature of more than one axis (pixels) is stored lane-dense, as ONE
+  axis padded to a multiple of 128 (:func:`stored_feature`): the device then
+  indexes the ring in place, where a last axis of 3 made it re-lay the whole
+  ring around every write and every gather.  Callers never see that shape.
 * **Writes are donated in-place**: the actor path appends host rows with one
   explicit ``device_put`` per key plus a jitted ``buffer.at[slots].set(rows)``
   whose ring argument is donated — no HBM reallocation, no 2x spike.
@@ -65,6 +69,51 @@ def resolve_device_replay(cfg: Any, fabric_accelerator: str) -> bool:
 #: ring row layout: key -> (per-step feature shape, dtype)
 LeafSpecs = Dict[str, Tuple[Tuple[int, ...], Any]]
 
+#: elements in a TPU lane row: a stored pixel feature is a multiple of it
+LANES = 128
+
+
+def stored_feature(feat: Sequence[int]) -> Tuple[int, ...]:
+    """The shape the ring stores a per-step feature in.
+
+    One axis (vectors, actions, flags): as it is.  More (pixels): flattened
+    to one axis and padded to the next multiple of :data:`LANES`.  A TPU lays
+    ``u8[W, E, 64, 64, 3]`` out W-minor, so an index on the leading axes needs
+    the whole array in another layout (two ring-sized copies a write, one a
+    gather); ``u8[W, E, 12288]`` it lays out row-major and indexes in place
+    (XLA's memory analysis for a v5e: no temporaries, 1.00 device bytes per
+    raw byte).  A flat axis that is no lane multiple (84*84*4 = 28224) falls
+    back to the W-minor layout, hence the padding (28288, 0.2%)."""
+    feat = tuple(int(d) for d in feat)
+    if len(feat) < 2:
+        return feat
+    return (-(-math.prod(feat) // LANES) * LANES,)
+
+
+def to_stored(rows: np.ndarray) -> np.ndarray:
+    """Host rows ``(T, K, *feat)`` as the ring stores them, zero-padded."""
+    feat = rows.shape[2:]
+    stored = stored_feature(feat)
+    if stored == feat:
+        return rows
+    flat = rows.reshape(rows.shape[:2] + (-1,))
+    pad = stored[0] - flat.shape[-1]
+    return np.pad(flat, ((0, 0), (0, 0), (0, pad))) if pad else flat
+
+
+def from_stored(x: Any, feat: Sequence[int]) -> Any:
+    """``(..., *stored_feature(feat))`` back to ``(..., *feat)``: the inverse of
+    :func:`to_stored` on any leading axes, for a numpy array (a checkpoint) or
+    a traced one (a gathered batch: the reshape touches the batch, never the
+    ring)."""
+    feat = tuple(int(d) for d in feat)
+    if len(feat) < 2:
+        return x
+    size = math.prod(feat)
+    if size != x.shape[-1]:
+        x = x[..., :size]
+    return x.reshape(x.shape[:-1] + feat)
+
 
 def _zeros_program(shape: Tuple[int, ...], dtype: Any, sharding: Any) -> Any:
     """THE ring allocation program: zeros born directly in their final
@@ -79,15 +128,16 @@ def _zeros_program(shape: Tuple[int, ...], dtype: Any, sharding: Any) -> Any:
 def ring_device_bytes(
     leaf_specs: LeafSpecs, window: int, n_envs: int, sharding: Any = None
 ) -> float:
-    """REAL per-device bytes of a ``(window, n_envs, *feat)`` ring: the output
-    size of the compiled allocation.  ``nbytes`` of the abstract shape is not
-    enough — the device layout may pad, and by how much depends on the whole
-    shape, so the question is asked at the size that will be allocated."""
+    """REAL per-device bytes of a ``(window, n_envs, *stored_feature(feat))``
+    ring: the output size of the compiled allocation.  ``nbytes`` of the
+    abstract shape is not enough — the device layout may pad, and by how much
+    depends on the whole shape, so the question is asked at the size that
+    will be allocated."""
     import jax
 
     total = 0
     for feat, dtype in leaf_specs.values():
-        shape = (int(window), int(n_envs)) + tuple(int(d) for d in feat)
+        shape = (int(window), int(n_envs)) + stored_feature(feat)
         try:
             compiled = _zeros_program(shape, dtype, sharding).lower().compile()
         except jax.errors.JaxRuntimeError as e:
@@ -142,8 +192,8 @@ def fit_hbm_window(
     the requested window (``buffer.hbm_window``, default the full capacity),
     whose REAL device bytes (:func:`ring_device_bytes`) fit ``budget_bytes``
     per device.  Anything beyond the window pages to the host spill tier.
-    The storage layout is whatever the device gives the ring's shape — this
-    only measures it."""
+    The storage layout is whatever the device gives the ring's stored shape
+    (:func:`stored_feature`) — this only measures it."""
     window = int(capacity) if requested is None else min(int(requested), int(capacity))
     need = ring_device_bytes(leaf_specs, window, n_envs, sharding)
     while need > budget_bytes and window > min_window:
@@ -193,10 +243,13 @@ def build_device_replay(
     * the fused program is AOT-compiled at that largest dispatch against a
       probe ring that takes an eighth of the free memory;
     * the ring's own gather and donated-scatter programs are compiled at the
-      same size (:meth:`DeviceReplay.access_extra_bytes`).  On a TPU both
-      re-lay the WHOLE ring into temporaries, so their need — and the train
-      program's share that is the gather's — grows with the ring; the write
-      may be in flight beside a train dispatch, so both count.
+      same size (:meth:`DeviceReplay.access_extra_bytes`).  Where the device
+      cannot index a leaf in place it re-lays the WHOLE leaf into temporaries
+      (a v5e did, for pixel leaves, until they were stored lane-dense:
+      :func:`stored_feature`), and that need — with the train program's share
+      that is the gather's — grows with the ring; the write may be in flight
+      beside a train dispatch, so both count.  With every leaf indexed in
+      place both read near zero and the line below prints about 1.0.
 
     ``need(ring) = ring * (1 + (read + write) / probe_ring) + (train - read)``
     then gives the longest window that fits.  When that is the probe's own
@@ -472,7 +525,11 @@ class HostSpill:
 class DeviceReplay:
     """Mesh-sharded device-resident replay ring over ``Dict[str, (W, E, *)]``.
 
-    ``W`` is the HBM window (steps per env), ``E`` the env count.  Arrays are
+    ``W`` is the HBM window (steps per env), ``E`` the env count.  A leaf whose
+    feature has more than one axis is held as ``(W, E, F_pad)``
+    (:func:`stored_feature`); ``add``/``write_at`` flatten host rows on the way
+    in, the samplers and ``gather_at`` restore the feature shape of the
+    *gathered batch*, and a checkpoint keeps ``(W, E, *feat)``.  Arrays are
     placed with ``PartitionSpec(None, 'data', ...)`` when the env axis
     divides the mesh ``data`` axis (else replicated) — the same layout
     ``fabric.shard_batch`` gives shipped batches, so gathers stay mostly
@@ -508,6 +565,7 @@ class DeviceReplay:
         self._data_axis = data_axis
         self.spill = spill
         self._buf: Dict[str, Any] = {}
+        self._feat: Dict[str, Tuple[int, ...]] = {}  # key -> feature shape as callers see it
         self._sharding = None
         if mesh is not None:
             from sheeprl_tpu.parallel.sharding import replay_sharding
@@ -541,9 +599,15 @@ class DeviceReplay:
 
     @property
     def buffers(self) -> Dict[str, Any]:
-        """The device pytree — pass it (with :attr:`cursor`) into a fused
-        train program; never copied, never donated."""
+        """The device pytree, in its stored shapes — pass it (with
+        :attr:`cursor`) into a fused train program; never copied, never
+        donated.  Read rows through the samplers or :meth:`gather_at`."""
         return self._buf
+
+    @property
+    def leaf_specs(self) -> LeafSpecs:
+        """The allocated ring's rows as callers see them: feature shape, dtype."""
+        return {k: (self._feat[k], buf.dtype) for k, buf in self._buf.items()}
 
     @property
     def full(self) -> bool:
@@ -563,15 +627,14 @@ class DeviceReplay:
         return tuple(self._buf.keys())
 
     def abstract_buffers(self, leaf_specs: LeafSpecs) -> Dict[str, Any]:
-        """The ring as ``ShapeDtypeStruct``s (shape, dtype, placement) — what
-        a fused program is lowered against before the ring is allocated."""
+        """The ring as ``ShapeDtypeStruct``s (stored shape, dtype, placement)
+        — what a fused program is lowered against before the ring is
+        allocated."""
         import jax
 
         return {
             k: jax.ShapeDtypeStruct(
-                (self._capacity, self._n_envs) + tuple(int(d) for d in feat),
-                np.dtype(dtype),
-                sharding=self._sharding,
+                self._declare(k, feat), np.dtype(dtype), sharding=self._sharding
             )
             for k, (feat, dtype) in leaf_specs.items()
         }
@@ -579,9 +642,10 @@ class DeviceReplay:
     def access_extra_bytes(self, leaf_specs: LeafSpecs) -> Tuple[int, int]:
         """``(read, write)``: device bytes this ring's gather and its donated
         scatter need BEYOND the ring itself, summed over its leaves, by XLA's
-        memory analysis of the two programs at this ring's size.  Zero where
-        the device indexes the ring in place; on a TPU both re-lay the whole
-        ring into temporaries, so the figure grows with the ring."""
+        memory analysis of the two programs at this ring's size.  Near zero
+        where the device indexes the ring in place (every leaf in its stored
+        shape, on a v5e); where it re-lays a whole leaf into temporaries the
+        figure grows with the ring."""
         import jax
 
         scatter, gather, _ = self._ops()
@@ -598,7 +662,7 @@ class DeviceReplay:
         t, e = spec((1, n), np.int32), spec((n,), np.int32)
         read = write = 0
         for k, arr in self.abstract_buffers(leaf_specs).items():
-            read += program_extra_bytes(gather.lower(arr, t, e).compile())
+            read += program_extra_bytes(gather.lower(arr, t, e, self._feat[k]).compile())
             rows = spec((1, n) + arr.shape[2:], arr.dtype)
             write += program_extra_bytes(scatter.lower(arr, rows, t, e).compile())
         return read, write
@@ -617,8 +681,7 @@ class DeviceReplay:
         """HBM bytes one update's gathered batch materializes on device —
         the ``bytes_per_update`` input to :func:`update_chunks`, computed
         exactly from the allocated ring (call after the first ``add``)."""
-        specs = {k: (buf.shape[2:], buf.dtype) for k, buf in self._buf.items()}
-        return sampled_bytes(specs, batch_size, sequence_length, derive_next)
+        return sampled_bytes(self.leaf_specs, batch_size, sequence_length, derive_next)
 
     def can_sample(self, min_steps: int = 1) -> bool:
         return bool((self._filled_h >= max(1, int(min_steps))).any())
@@ -646,7 +709,9 @@ class DeviceReplay:
                 donate_argnums=0,
                 out_shardings=self._sharding,
             )
-            self._gather = jax.jit(lambda arr, t, e: arr[t, e])
+            self._gather = jax.jit(
+                lambda arr, t, e, feat: from_stored(arr[t, e], feat), static_argnums=3
+            )
 
             def advance(pos, filled, steps, mask):
                 new_pos = (pos + steps) % self._capacity
@@ -661,11 +726,19 @@ class DeviceReplay:
             self._advance = jax.jit(advance)
         return self._scatter, self._gather, self._advance
 
-    def _ensure(self, key: str, feat_shape: Tuple[int, ...], dtype: Any) -> None:
-        if key in self._buf:
-            return
-        shape = (self._capacity, self._n_envs) + tuple(feat_shape)
-        self._buf[key] = _zeros_program(shape, dtype, self._sharding)()
+    def _declare(self, key: str, feat_shape: Sequence[int]) -> Tuple[int, ...]:
+        """Record ``key``'s feature shape; the shape its leaf is stored in."""
+        feat = tuple(int(d) for d in feat_shape)
+        if self._feat.setdefault(key, feat) != feat:
+            raise ValueError(
+                f"replay key {key!r} holds rows of shape {self._feat[key]}, got {feat}"
+            )
+        return (self._capacity, self._n_envs) + stored_feature(feat)
+
+    def _ensure(self, key: str, feat_shape: Sequence[int], dtype: Any) -> None:
+        shape = self._declare(key, feat_shape)
+        if key not in self._buf:
+            self._buf[key] = _zeros_program(shape, dtype, self._sharding)()
 
     def _put(self, x: np.ndarray) -> Any:
         """Explicit H2D staging (transfer-guard-legal) of host rows/indices."""
@@ -716,7 +789,7 @@ class DeviceReplay:
             t_dev = self._put(t_idx)
             e_dev = self._put(env_sel.astype(np.int32))
             for k, v in data.items():
-                rows = self._put(np.asarray(v)[-steps:])
+                rows = self._put(to_stored(np.asarray(v)[-steps:]))
                 self._buf[k] = scatter(self._buf[k], rows, t_dev, e_dev)
             mask = np.zeros(self._n_envs, bool)
             mask[env_sel] = True
@@ -737,8 +810,7 @@ class DeviceReplay:
         tail = int((self._pos_h[env] - 1) % self._capacity)
         for key, value in (("truncated", 1.0), ("terminated", 0.0), ("is_first", 0.0)):
             if key in self._buf:
-                feat = self._buf[key].shape[2:]
-                row = np.full((1, 1) + tuple(feat), value, dtype=np.dtype(self._buf[key].dtype))
+                row = np.full((1, 1) + self._feat[key], value, dtype=np.dtype(self._buf[key].dtype))
                 self.write_at(key, row, np.asarray([[tail]], np.int32), [env])
 
     # -- mirror-compatible primitives (the attach_mirror shim rides these) ---
@@ -750,7 +822,7 @@ class DeviceReplay:
         scatter, _, _ = self._ops()
         self._buf[key] = scatter(
             self._buf[key],
-            self._put(rows),
+            self._put(to_stored(rows)),
             self._put(np.asarray(time_pos, np.int32)),
             self._put(np.asarray(env_cols, np.int32)),
         )
@@ -762,6 +834,7 @@ class DeviceReplay:
             self._buf[key],
             self._put(np.asarray(time_idx, np.int32)),
             self._put(np.asarray(env_idx, np.int32)),
+            self._feat[key],
         )
 
     # -- on-device sampling (jit-traceable over buffers/cursor/key) ----------
@@ -815,12 +888,14 @@ class DeviceReplay:
             for k, buf in buffers.items():
                 if keys is not None and k not in keys:
                     continue
-                out[k] = buf[step, env].reshape(n_samples, batch_size, *buf.shape[2:])
+                out[k] = from_stored(buf[step, env], self._feat[k]).reshape(
+                    n_samples, batch_size, *self._feat[k]
+                )
             for k in derive_next:
                 if k in buffers:
                     nxt = (step + 1) % self._capacity
-                    out[f"next_{k}"] = buffers[k][nxt, env].reshape(
-                        n_samples, batch_size, *buffers[k].shape[2:]
+                    out[f"next_{k}"] = from_stored(buffers[k][nxt, env], self._feat[k]).reshape(
+                        n_samples, batch_size, *self._feat[k]
                     )
             return self._constrain(out, batch_axis=1) if constrain else out
 
@@ -871,8 +946,8 @@ class DeviceReplay:
             for k, buf in buffers.items():
                 if keys is not None and k not in keys:
                     continue
-                g = buf[t_idx, env[:, None]]  # (total, L, *feat)
-                g = g.reshape(n_samples, batch_size, L, *buf.shape[2:])
+                g = from_stored(buf[t_idx, env[:, None]], self._feat[k])  # (total, L, *feat)
+                g = g.reshape(n_samples, batch_size, L, *self._feat[k])
                 out[k] = g.swapaxes(1, 2)  # (n_samples, L, batch, *feat)
             return self._constrain(out, batch_axis=2) if constrain else out
 
@@ -909,7 +984,8 @@ class DeviceReplay:
         to the host COPY (the callback's ``_consistent_tail`` contract: the
         step at each env's write head must not look continuable on resume —
         only ``truncated``/``dones`` are forced, NEVER ``terminated``, which
-        is a value-semantics bootstrap-killing flag)."""
+        is a value-semantics bootstrap-killing flag).  Either way the arrays
+        are ``(W, E, *feat)``: the stored shape never reaches a checkpoint."""
         if self.spill is not None and not self.spill.degraded:
             if self.spill.flush(self._spill_flush_timeout_s):
                 state = self.spill.state_dict()
@@ -925,7 +1001,7 @@ class DeviceReplay:
                 "device ring (HBM window) instead of the full spill history",
                 RuntimeWarning,
             )
-        buf = {k: np.asarray(v) for k, v in self._buf.items()}
+        buf = {k: from_stored(np.asarray(v), self._feat[k]) for k, v in self._buf.items()}
         if buf and not any(k.startswith("next_") for k in buf):
             # writable copies for just the patched flag keys (np.asarray of a
             # device array is a read-only view)
@@ -978,7 +1054,6 @@ class DeviceReplay:
             )
         for k, v in buf.items():
             v = np.asarray(v)
-            self._ensure(k, v.shape[2:], v.dtype)
             self.write_at(k, v, np.tile(np.arange(saved_cap)[:, None], (1, self._n_envs)), list(range(self._n_envs)))
         self._pos_h = pos.astype(np.int64).copy()
         self._filled_h = np.minimum(filled.astype(np.int64), self._capacity).copy()

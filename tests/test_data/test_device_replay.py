@@ -29,9 +29,11 @@ from sheeprl_tpu.data.device_replay import (
     HostSpill,
     device_memory,
     fit_hbm_window,
+    from_stored,
     fused_uniform_train,
     ring_device_bytes,
     steady_guard,
+    stored_feature,
     update_chunks,
 )
 
@@ -135,6 +137,102 @@ class TestSeededParity:
 
 
 # --------------------------------------------------------------------------
+# pixel leaves: stored lane-dense, seen by every caller in their own shape
+# --------------------------------------------------------------------------
+
+#: a feature that is a lane multiple as it is, and one that needs padding
+PIXEL_FEATS = [pytest.param((8, 8, 2), id="lane_multiple"), pytest.param((5, 5, 3), id="padded")]
+
+
+def _fill_pixels(feat, cap=8, n_envs=3, steps=5, seed=0):
+    """(DeviceReplay, host ReplayBuffer) given the same pixel and flag rows."""
+    rng = np.random.default_rng(seed)
+    dev = DeviceReplay(cap, n_envs)
+    host = ReplayBuffer(cap, n_envs, obs_keys=("rgb",))
+    for _ in range(steps):
+        data = {
+            "rgb": rng.integers(0, 256, size=(1, n_envs) + feat, dtype=np.uint8),
+            "rewards": rng.normal(size=(1, n_envs, 1)).astype(np.float32),
+        }
+        dev.add(data)
+        host.add(data)
+    return dev, host
+
+
+@pytest.mark.parametrize("feat", PIXEL_FEATS)
+@pytest.mark.parametrize("steps", [5, 21], ids=["partial", "wrapped"])
+class TestPixelLeafParity:
+    def test_store_is_one_padded_axis_and_vectors_are_untouched(self, feat, steps):
+        dev, host = _fill_pixels(feat, steps=steps)
+        flat = int(np.prod(feat))
+        assert stored_feature(feat) == (-(-flat // 128) * 128,)
+        assert dev.buffers["rgb"].shape == (8, 3) + stored_feature(feat)
+        assert dev.buffers["rewards"].shape == (8, 3, 1)
+        assert dev.leaf_specs["rgb"] == (feat, np.uint8)
+        stored = np.asarray(dev.buffers["rgb"])
+        np.testing.assert_array_equal(from_stored(stored, feat), host.buffer["rgb"])
+        assert not stored[..., flat:].any()  # the padding stays zero
+        assert dev.sampled_bytes_per_update(4, 2) == 4 * 2 * (flat + 4)
+
+    def test_gather_at_matches_host_rows(self, feat, steps):
+        dev, host = _fill_pixels(feat, steps=steps)
+        rng = np.random.default_rng(1)
+        t_idx = rng.integers(0, min(steps, 8), size=(6, 2))
+        e_idx = rng.integers(0, 3, size=(6, 2))
+        got = np.asarray(dev.gather_at("rgb", t_idx, e_idx))
+        assert got.shape == (6, 2) + feat
+        np.testing.assert_array_equal(got, host.buffer["rgb"][t_idx, e_idx])
+
+    def test_sample_sequences_matches_host_rows(self, feat, steps):
+        dev, host = _fill_pixels(feat, steps=steps)
+        key = jax.random.PRNGKey(5)
+        t_idx, env = (np.asarray(x) for x in dev.sequence_indices(dev.cursor, key, 6, 3))
+        batch = jax.jit(
+            lambda b, c, k: dev.sample_sequences(b, c, k, batch_size=2, sequence_length=3, n_samples=3)
+        )(dev.buffers, dev.cursor, key)
+        assert batch["rgb"].shape == (3, 3, 2) + feat and batch["rgb"].dtype == jnp.uint8
+        for k in ("rgb", "rewards"):
+            got = np.asarray(batch[k]).swapaxes(1, 2).reshape(6, 3, *host.buffer[k].shape[2:])
+            np.testing.assert_array_equal(got, host.buffer[k][t_idx, env[:, None]])
+
+    def test_sample_uniform_with_derived_next_matches_host_rows(self, feat, steps):
+        dev, host = _fill_pixels(feat, steps=steps)
+        key = jax.random.PRNGKey(3)
+        batch = jax.jit(
+            lambda b, c, k: dev.sample_uniform(b, c, k, batch_size=3, n_samples=2, derive_next=("rgb",))
+        )(dev.buffers, dev.cursor, key)
+        step, env = (np.asarray(x) for x in dev.uniform_indices(dev.cursor, key, 6, sample_next_obs=True))
+        expected = host._gather(step, env, sample_next_obs=True)
+        for k in ("rgb", "next_rgb"):
+            assert batch[k].shape == (2, 3) + feat
+            np.testing.assert_array_equal(np.asarray(batch[k]).reshape(6, *feat), expected[k].reshape(6, *feat))
+
+    def test_checkpoint_holds_feature_shapes_and_the_parents_layout_loads(self, feat, steps):
+        dev, host = _fill_pixels(feat, steps=steps)
+        state = dev.state_dict()
+        assert state["buffer"]["rgb"].shape == (8, 3) + feat
+        np.testing.assert_array_equal(state["buffer"]["rgb"], host.buffer["rgb"])
+        # what the (W, E, *feat) store wrote before pixel leaves were flattened: the host ring's own arrays
+        parents = {
+            "buffer": {k: np.array(host.buffer[k]) for k in ("rgb", "rewards")},
+            "pos": np.array(dev._pos_h), "filled": np.array(dev._filled_h),
+            "buffer_size": 8, "n_envs": 3, "device_replay": {"from_spill": False},
+        }
+        for saved in (state, parents):
+            fresh = DeviceReplay(8, 3).load_state_dict(saved)
+            assert fresh.buffers["rgb"].shape == dev.buffers["rgb"].shape
+            np.testing.assert_array_equal(np.asarray(fresh.buffers["rgb"]), np.asarray(dev.buffers["rgb"]))
+            np.testing.assert_array_equal(np.asarray(fresh.cursor["pos"]), np.asarray(dev.cursor["pos"]))
+
+
+def test_a_key_keeps_the_feature_shape_it_was_declared_with():
+    dev = DeviceReplay(8, 2)
+    dev.add({"rgb": np.zeros((1, 2, 8, 8, 3), np.uint8)})
+    with pytest.raises(ValueError, match="holds rows of shape"):
+        dev.add({"rgb": np.zeros((1, 2, 8, 24), np.uint8)})  # the same 192 bytes, another feature
+
+
+# --------------------------------------------------------------------------
 # compile-once: no signature churn from cursors
 # --------------------------------------------------------------------------
 
@@ -220,6 +318,23 @@ class TestMeshSharding:
         assert b["obs"].shape == (2, 4, 6)
         s = rb.sample_sequences(rb.buffers, rb.cursor, key, 4, 3, n_samples=2)
         assert s["obs"].shape == (2, 3, 4, 6)
+
+    @pytest.mark.parametrize("feat", PIXEL_FEATS)
+    def test_pixel_leaf_keeps_the_env_axis_on_data_and_samples_its_own_shape(self, mesh_fabric, feat):
+        rb = DeviceReplay(16, 4, mesh=mesh_fabric.mesh, data_axis=mesh_fabric.data_axis)
+        rng = np.random.default_rng(0)
+        rows = [rng.integers(0, 256, size=(1, 4) + feat, dtype=np.uint8) for _ in range(8)]
+        for r in rows:
+            rb.add({"rgb": r})
+        assert rb.buffers["rgb"].shape == (16, 4) + stored_feature(feat)
+        assert rb.buffers["rgb"].sharding.spec == P(None, "data")  # one env column a data shard
+        key = jax.random.PRNGKey(0)
+        s = jax.jit(lambda b, c, k: rb.sample_sequences(b, c, k, 4, 3, n_samples=2))(rb.buffers, rb.cursor, key)
+        assert s["rgb"].shape == (2, 3, 4) + feat
+        assert s["rgb"].sharding.spec == P(None, None, "data")
+        t_idx, env = (np.asarray(x) for x in rb.sequence_indices(rb.cursor, key, 8, 3))
+        got = np.asarray(s["rgb"]).swapaxes(1, 2).reshape(8, 3, *feat)
+        np.testing.assert_array_equal(got, np.concatenate(rows)[t_idx, env[:, None]])
 
 
 # --------------------------------------------------------------------------
@@ -326,7 +441,9 @@ class TestSpillTier:
         from sheeprl_tpu.parallel.sharding import replay_sharding
 
         specs = {"rgb": ((8, 8, 3), np.uint8), "r": ((1,), np.float32)}
-        raw = 32 * 4 * (8 * 8 * 3 + 4)
+        # the pixel leaf as it is stored: one axis, 192 padded to 256 lanes
+        raw = 32 * 4 * (stored_feature((8, 8, 3))[0] + 4)
+        assert raw == 32 * 4 * (256 + 4)
         assert ring_device_bytes(specs, 32, 4) == raw
         # sharded over the env axis: each device holds its share only
         fabric = Fabric(devices=4, accelerator="cpu")

@@ -132,3 +132,69 @@ def test_dv3_s_world_model_fwd_bwd_compiles_for_v5e(one_chip):
     # fits the 16 GB chip with room for the ring (it needs about 1 GB)
     assert 0 < memory.temp_size_in_bytes < 4 * 2**30
     assert "convolution" in compiled.as_text()
+
+
+# --------------------------------------------------------------------------
+# the device replay ring: pixel leaves are stored lane-dense, so the chip
+# indexes them in place (data/device_replay.stored_feature)
+# --------------------------------------------------------------------------
+
+def _described_ring(one_chip, window, n_envs):
+    """A ``DeviceReplay`` whose programs lower for the described chip.  Built
+    without a mesh (nothing can be put on a described device), then handed
+    the one-chip mesh its ``_ops``/``access_extra_bytes`` read."""
+    from jax.sharding import Mesh
+
+    from sheeprl_tpu.data.device_replay import DeviceReplay
+    from sheeprl_tpu.parallel.sharding import replay_sharding
+
+    ring = DeviceReplay(window, n_envs)
+    ring._mesh = Mesh(np.array([one_chip._device]), ("data",))
+    ring._sharding = replay_sharding(ring._mesh, n_envs, "data")
+    return ring
+
+
+@pytest.mark.parametrize("n_envs", [1, 4])
+@pytest.mark.parametrize("feat", [(64, 64, 3), (84, 84, 4)], ids=["64x64x3", "84x84x4_padded"])
+def test_ring_write_and_gather_index_a_pixel_leaf_in_place_on_v5e(one_chip, feat, n_envs):
+    """The ring's real donated scatter and its gather at W=8192: temporaries
+    under 1% of the ring (they were 2.0x each while the leaf was stored
+    ``(W, E, H, W, C)``), and the stored leaf dense on the device."""
+    from sheeprl_tpu.data.device_replay import ring_device_bytes
+
+    window = 8192
+    specs = {"rgb": (feat, np.uint8)}
+    ring = _described_ring(one_chip, window, n_envs)
+    raw = window * n_envs * int(np.prod(feat))
+    assert ring_device_bytes(specs, window, n_envs, ring._sharding) <= 1.01 * raw
+    read, write = ring.access_extra_bytes(specs)
+    assert read < 0.01 * raw and write < 0.01 * raw, (read / raw, write / raw)
+
+
+def test_fused_dv3_gather_holds_no_copy_of_the_ring_on_v5e(one_chip):
+    """``sample_sequences`` as the fused DV3-S train program runs it (U=4,
+    L=64, B=16 over the five DV3 leaves, pixels normalised as the encoder's
+    first op does): no ``copy`` of the ring's shape in the compiled text, no
+    temporary of its size."""
+    import re
+
+    window, n_envs = 65536, 4
+    specs = {
+        "rgb": ((64, 64, 3), np.uint8), "actions": ((5,), np.float32),
+        "rewards": ((1,), np.float32), "terminated": ((1,), np.float32), "is_first": ((1,), np.float32),
+    }
+    ring = _described_ring(one_chip, window, n_envs)
+
+    def gather(buffers, cursor, key):
+        blocks = ring.sample_sequences(buffers, cursor, key, 16, 64, 4)
+        assert blocks["rgb"].shape == (4, 64, 16, 64, 64, 3)
+        return dict(blocks, rgb=blocks["rgb"].astype(jnp.bfloat16) / 255.0 - 0.5)
+
+    cursor = {k: _spec(one_chip, n_envs, dtype=jnp.int32) for k in ("pos", "filled")}
+    key = _spec(one_chip, 2, dtype=jnp.uint32)
+    compiled = jax.jit(gather).lower(ring.abstract_buffers(specs), cursor, key).compile()
+    ring_shaped = re.findall(rf"= u8\[{window},{n_envs},\d+\]\S* copy\(", compiled.as_text())
+    assert not ring_shaped, ring_shaped
+    raw = window * n_envs * 64 * 64 * 3
+    # the gathered block (0.2 GiB as the chip pads it), nothing of the ring's size (3 GiB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1 * raw

@@ -194,7 +194,7 @@ class TestRingWrite:
         ring.add({"rgb": np.zeros((1, 2, 8, 8, 3), np.uint8), "rewards": np.zeros((1, 2, 1), np.float32)})
         scatter, _, _ = ring._ops()
         arr = ring.buffers["rgb"]
-        rows = jax.ShapeDtypeStruct((1, 2, 8, 8, 3), np.uint8)
+        rows = jax.ShapeDtypeStruct((1, 2) + arr.shape[2:], np.uint8)  # a pixel leaf is stored flat
         idx = jax.ShapeDtypeStruct((1, 2), np.int32)
         env = jax.ShapeDtypeStruct((2,), np.int32)
         text = scatter.lower(jax.ShapeDtypeStruct(arr.shape, arr.dtype), rows, idx, env).as_text(debug_info=True)
