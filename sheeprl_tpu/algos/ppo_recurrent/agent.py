@@ -20,7 +20,9 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from sheeprl_tpu.models import decoder
 from sheeprl_tpu.models.models import MLP
 
 
@@ -159,6 +161,135 @@ def one_hot_actions(actions: jax.Array, actions_dim: Sequence[int], is_continuou
     return jnp.concatenate(parts, axis=-1)
 
 
+class LSTMCore:
+    """What the recurrent PPO loop asks of its core (``algo.core``), answered for the LSTM agent.
+
+    The loop drives a core through these calls alone and does not know which one it holds:
+    ``policy_step`` (one step on the carry), ``policy_segment`` (a rollout's segment from the carry at its
+    start; a third result where the core has a router: its counts), ``encode_prev`` (the action as the next
+    step's input), ``acting_params`` (the weights as the rollout reads them), ``initial_state``,
+    ``stores_values`` (the rollout keeps its values: no second pass over every token), and for a core with
+    more to tell or to keep: ``init_aux``/``after_update`` (state that moves with every update),
+    ``rollout_stats`` (what a dispatch gives out beside its losses), ``host_counts`` (what the loop counts on
+    its ``stats.pull`` span), ``prefill`` (a carry filled from an episode so far)."""
+
+    stores_values = False
+
+    def __init__(self, agent: RecurrentPPOAgent):
+        self.agent = agent
+        self.prev_action_width = int(sum(agent.actions_dim))
+
+    def policy_step(self, p, carry, obs, prev_actions, is_first):
+        return self.agent.apply(
+            p, method=RecurrentPPOAgent.step, carry=carry, obs=obs,
+            prev_actions=prev_actions, is_first=is_first,
+        )
+
+    def policy_segment(self, p, obs_seq, prev_actions_seq, is_first_seq, carry):
+        return self.agent.apply(p, obs_seq, prev_actions_seq, is_first_seq, carry) + (None,)
+
+    def encode_prev(self, actions):
+        return one_hot_actions(actions, self.agent.actions_dim, self.agent.is_continuous)
+
+    def acting_params(self, p):
+        return p
+
+    def initial_state(self, batch: int):
+        size = self.agent.lstm_size
+        return jnp.zeros((batch, size), jnp.float32), jnp.zeros((batch, size), jnp.float32)
+
+    def init_aux(self):
+        return None
+
+    def rollout_stats(self, rollout, init_carry, venv, actor) -> Dict[str, Any]:
+        return {}
+
+    def host_counts(self, stats) -> Dict[str, Any]:
+        return {}
+
+
+class DecoderPPOAgent:
+    """The decoder core (``algo.core: decoder``): a token-level policy over ``models/decoder.py``.
+
+    It answers the calls of :class:`LSTMCore` itself.  The observation is the one integer key
+    ``mlp_keys[0]``; the recurrent carry is the decoder's caches and positions; the previous action is not
+    read (the env's observation is the token emitted last)."""
+
+    stores_values = True
+    prev_action_width = 1  # the action itself: no one-hot of the vocabulary
+
+    def __init__(self, config: Any, mlp_keys: Tuple[str, ...], dtype: Any = jnp.float32):
+        if len(mlp_keys) != 1:
+            raise ValueError(f"the decoder core reads one integer observation, got mlp_keys={mlp_keys}")
+        self.config, self.key, self.dtype = config, mlp_keys[0], dtype
+        self.prefill_chunk = config.sliding_window  # a longer prefill segment would write a ring's slot twice
+
+    def init(self, rng: jax.Array) -> Dict[str, Any]:
+        return {"params": decoder.init_params(self.config, rng)}
+
+    def initial_state(self, batch: int) -> Dict[str, Any]:
+        return decoder.init_carry(self.config, batch, jnp.float32 if self.dtype == jnp.float32 else jnp.bfloat16)
+
+    def policy_step(self, p, carry, obs, prev_actions, is_first):
+        carry, logits, value = decoder.step(
+            p["params"], self.config, carry, obs[self.key][..., 0], is_first[..., 0], self.dtype
+        )
+        return carry, (logits, value)
+
+    def policy_segment(self, p, obs_seq, prev_actions_seq, is_first_seq, carry):
+        return decoder.segment(
+            p["params"], self.config, carry, obs_seq[self.key][..., 0], is_first_seq[..., 0], self.dtype
+        )
+
+    def prefill(self, p, carry, tokens, valid):
+        """``carry`` with the first ``valid`` (B,) of the ``(T, B)`` ``tokens`` written into it."""
+        first = jnp.zeros(tokens.shape, jnp.float32)
+        return decoder.segment(p["params"], self.config, carry, tokens, first, self.dtype, extend=True, valid=valid)[3]
+
+    def encode_prev(self, actions):
+        return actions
+
+    def acting_params(self, p):
+        """The weights in the compute dtype once, not at every one of the rollout's steps."""
+        return jax.tree.map(lambda x: x.astype(self.dtype), p)
+
+    def init_aux(self) -> Dict[str, Any]:
+        """What a dispatch's updates tell of the expert layers: the router's counts, summed and of the first."""
+        counts = jnp.zeros((len(self.config.moe_layers()), self.config.num_experts), jnp.int32)
+        return {"updates": jnp.zeros((), jnp.int32), "load": counts, "first_load": counts,
+                "first_losses": jnp.zeros((3,), jnp.float32)}
+
+    def after_update(self, p, load):
+        """The experts' selection bias follows the router's counts of the update."""
+        return {**p, "params": decoder.update_router_bias(p["params"], load, self.config)}
+
+    def rollout_stats(self, rollout, init_carry, venv, actor) -> Dict[str, Any]:
+        """The rollout as the caches produced it, with the observation that follows it (what a check against a
+        full forward needs), and how many of its steps lay past the window."""
+        pos, _ = decoder.segment_positions(rollout["is_first"][..., 0], init_carry["pos"])
+        kept = ("actions", "logprobs", "values", "rewards", "dones", "is_first", "mask")
+        return {
+            **{k: rollout[k] for k in kept},
+            "tokens": rollout[self.key], "next_tokens": venv.observe(actor["env"])[self.key],
+            "next_is_first": actor["is_first"],
+            "beyond_window": jnp.sum(pos >= self.config.sliding_window), "steps": jnp.asarray(pos.size, jnp.int32),
+        }
+
+    def host_counts(self, stats) -> Dict[str, Any]:
+        first, held = self.config.experts_held
+        load = np.asarray(stats["load"])[:, first:first + held]  # tokens per held expert of the dispatch's updates
+        return {"moe_load_max": load.max(), "moe_load_mean": load.mean(),
+                "beyond_window": np.asarray(stats["beyond_window"]), "steps": np.asarray(stats["steps"])}
+
+
+def build_decoder_agent(fabric: Any, cfg: Any, action_space: Any, max_len: int, agent_state: Optional[Any] = None):
+    config = decoder.DecoderConfig.from_dict(dict(cfg.algo.decoder), vocab_size=int(action_space.n), max_len=max_len)
+    agent = DecoderPPOAgent(config, tuple(cfg.algo.mlp_keys.encoder), fabric.precision.compute_dtype)
+    if agent_state is not None:
+        return agent, fabric.replicate(agent_state)
+    return agent, jax.jit(agent.init, out_shardings=fabric.replicated)(jax.random.PRNGKey(cfg.seed))
+
+
 def build_agent(
     fabric: Any,
     actions_dim: Sequence[int],
@@ -185,8 +316,6 @@ def build_agent(
     )
     if agent_state is not None:
         return agent, fabric.replicate(agent_state)
-    import numpy as np
-
     act_width = sum(actions_dim) if not is_continuous else int(sum(actions_dim))
     dummy_obs = {k: jnp.zeros((1, int(np.prod(obs_space[k].shape))), jnp.float32) for k in mlp_keys}
     params = agent.init(

@@ -1,4 +1,4 @@
-"""Recurrent PPO (LSTM) — coupled topology.
+"""Recurrent PPO (LSTM core, or a decoder core over tokens) — coupled topology.
 
 Capability parity with the reference
 (reference: sheeprl/algos/ppo_recurrent/ppo_recurrent.py:119-524): LSTM
@@ -12,7 +12,11 @@ TPU-native differences:
   consumes fixed ``(T, B)`` blocks with fully static shapes — minibatches
   are subsets of the env axis;
 * the whole optimization phase (forward scan, GAE, epochs × env-minibatch
-  updates) is one jitted dispatch, as in the other algorithms here.
+  updates) is one jitted dispatch, as in the other algorithms here;
+* the recurrent carry is a pytree: ``(c, h)`` for the LSTM core, window and
+  full attention caches with each env's position for the decoder core
+  (``algo.core: decoder``, ``exp=ppo_tokens``, howto/ppo_tokens.md).  The
+  train phase re-runs a segment from the carry at its start for both.
 """
 
 from __future__ import annotations
@@ -28,13 +32,10 @@ import optax
 
 from sheeprl_tpu.algos.ppo.loss import entropy_loss, policy_loss, value_loss
 from sheeprl_tpu.algos.ppo.utils import actions_for_env, normalize_obs_keys, spaces_to_dims
-from sheeprl_tpu.algos.ppo_recurrent.agent import (
-    RecurrentPPOAgent,
-    build_agent,
-    one_hot_actions,
-)
+from sheeprl_tpu.algos.ppo_recurrent.agent import LSTMCore, build_agent, build_decoder_agent, one_hot_actions
 from sheeprl_tpu.data.buffers import ReplayBuffer
 from sheeprl_tpu.data.device_replay import stage_rollout, stage_scalar, steady_guard
+from sheeprl_tpu.telemetry.spans import SPANS
 from sheeprl_tpu.utils.distribution import Categorical, Normal
 from sheeprl_tpu.utils.env import episode_stats, final_obs_rows, make_env, vectorize
 from sheeprl_tpu.utils.logger import get_log_dir, get_logger
@@ -75,6 +76,24 @@ def _sample(actor_out, actions_dim, is_continuous, key, greedy=False):
         lp = lp + dist.log_prob(a)
         start += d
     return jnp.stack(acts, axis=-1).astype(jnp.float32), lp
+
+
+def _warm_start(fabric: Any, cfg: Any, venv: Any, core: Any, params: Any, actor: Dict[str, Any], key: jax.Array):
+    """A run that starts where a long run finds its envs (an env with ``warm_start`` and ``history``): every env
+    moved to a drawn step of an episode, and the core's carry filled from the episode so far, a chunk of tokens
+    at a time through the segment pass."""
+    n_envs = venv.num_envs
+    env_state = jax.jit(jax.vmap(venv.env.warm_start))(
+        actor["env"], jax.random.split(key, n_envs)
+    )
+    tokens, n = jax.jit(jax.vmap(venv.env.history))(env_state)
+    chunk = min(int(cfg.algo.rollout_steps), core.prefill_chunk)
+    prefill = fabric.compile(core.prefill, name=f"{cfg.algo.name}.prefill", donate_argnums=(1,))
+    carry = actor["carry"]
+    for start in range(0, int(np.asarray(n).max()), chunk):
+        tok = jax.lax.dynamic_slice_in_dim(tokens, start, chunk, axis=1).T
+        carry = prefill(params, carry, tok, jnp.clip(n - start, 0, chunk))
+    return {**actor, "env": env_state, "carry": carry, "is_first": (n == 0).astype(jnp.float32)[:, None]}
 
 
 @register_algorithm()
@@ -123,7 +142,6 @@ def main(fabric: Any, cfg: Any) -> None:
     normalize_obs_keys(cfg, obs_space)
     actions_dim, is_continuous = spaces_to_dims(act_space)
     mlp_keys = tuple(cfg.algo.mlp_keys.encoder)
-    act_width = int(sum(actions_dim))
 
     state: Dict[str, Any] = {}
     if cfg.checkpoint.resume_from:
@@ -131,9 +149,31 @@ def main(fabric: Any, cfg: Any) -> None:
     if state and state.get("key") is not None:
         # resume the train-dispatch RNG stream bit-exactly (rank-identical)
         key = jnp.asarray(state["key"])
-    agent, params = build_agent(fabric, actions_dim, is_continuous, cfg, obs_space, state.get("agent"))
+    # the one place that knows the cores apart: `algo.core` selects a model as `algo=` does, and from here on
+    # the loop drives `core` through the calls `agent.LSTMCore` lists
+    kind = str(cfg.algo.get("core", "lstm"))
+    if kind == "decoder":
+        if not use_anakin or cfg.algo.run_test:
+            raise ValueError(
+                "algo.core=decoder runs on the fused path: it needs a pure-JAX env (env=jax_*) and "
+                "algo.run_test=False (the test plays a host episode)"
+            )
+        agent, params = build_decoder_agent(
+            fabric, cfg, act_space, int(venv.env.max_episode_steps), state.get("agent")
+        )
+        core = agent
+    elif kind == "lstm":
+        agent, params = build_agent(fabric, actions_dim, is_continuous, cfg, obs_space, state.get("agent"))
+        core = LSTMCore(agent)
+    else:
+        raise ValueError(f"Unknown algo.core '{kind}'; options: lstm, decoder")
+    Agent = type(agent)
+    act_width = core.prev_action_width
     optimizer = build_optimizer(cfg.algo.optimizer, cfg.algo.max_grad_norm)
-    opt_state = fabric.replicate(state.get("opt_state") or optimizer.init(params))
+    # made in place: a copy of Adam's state beside its source does not fit beside a model that fills the chip
+    opt_state = fabric.replicate(state["opt_state"]) if state.get("opt_state") else jax.jit(
+        optimizer.init, out_shardings=fabric.replicated
+    )(params)
 
     aggregator = MetricAggregator(cfg.metric.aggregator.metrics if cfg.metric.log_level > 0 else {})
     timer.configure(cfg.metric)
@@ -157,10 +197,7 @@ def main(fabric: Any, cfg: Any) -> None:
     def policy_step_fn(p, carry, obs, prev_actions, is_first, k):
         # key advances INSIDE the jitted step (one host dispatch per env step)
         k_sample, k_next = jax.random.split(k)
-        carry, (actor_out, value) = agent.apply(
-            p, method=RecurrentPPOAgent.step, carry=carry, obs=obs,
-            prev_actions=prev_actions, is_first=is_first,
-        )
+        carry, (actor_out, value) = core.policy_step(p, carry, obs, prev_actions, is_first)
         actions, logprob = _sample(actor_out, actions_dim, is_continuous, k_sample)
         return carry, actions, logprob, value[..., 0], k_next
 
@@ -175,67 +212,88 @@ def main(fabric: Any, cfg: Any) -> None:
     def train_phase(p, o_state, rollout, init_carry, last_values, k, ent_coef, env_bs, num_minibatches):
         """Forward scan + GAE + epochs of env-axis minibatch updates."""
         T, B = rollout["rewards"].shape
+        mask = rollout.get("mask")  # 1 where a step counts in the losses; an env without one: every step
 
         def fwd(p, env_idx):
-            obs = {kk: jnp.take(rollout[kk], env_idx, axis=1) for kk in mlp_keys}
-            prev_a = jnp.take(rollout["prev_actions"], env_idx, axis=1)
-            first = jnp.take(rollout["is_first"], env_idx, axis=1)
-            carry = (
-                jnp.take(init_carry[0], env_idx, axis=0),
-                jnp.take(init_carry[1], env_idx, axis=0),
-            )
-            return agent.apply(p, obs, prev_a, first, carry)
+            with jax.named_scope("update.gather"):
+                obs = {kk: jnp.take(rollout[kk], env_idx, axis=1) for kk in mlp_keys}
+                prev_a = jnp.take(rollout["prev_actions"], env_idx, axis=1)
+                first = jnp.take(rollout["is_first"], env_idx, axis=1)
+                carry = jax.tree.map(lambda x: jnp.take(x, env_idx, axis=0), init_carry)
+            return core.policy_segment(p, obs, prev_a, first, carry)
 
-        all_idx = jnp.arange(B)
-        actor_out, values = fwd(p, all_idx)
-        values = values[..., 0]
-        returns, advantages = gae(
-            rollout["rewards"], values, rollout["dones"], last_values, gamma, gae_lambda
-        )
+        with jax.named_scope("gae"):  # the value pass over the whole rollout included
+            if "values" in rollout:  # graftlint: disable=trace-python-branch  (a key of the dict, not a value: the rollout kept its own values, so no second pass over every token)
+                values = rollout["values"]
+            else:
+                _, values, _ = fwd(p, jnp.arange(B))
+                values = values[..., 0]
+            returns, advantages = gae(
+                rollout["rewards"], values, rollout["dones"], last_values, gamma, gae_lambda
+            )
 
         def epoch_body(carry, key_e):
-            p, o_state = carry
+            p, o_state, aux = carry
             perm = jax.random.permutation(key_e, B)
             pad = num_minibatches * env_bs - B
             perm = jnp.concatenate([perm, perm[: max(pad, 0)]]) if pad > 0 else perm
 
             def mb_body(i, carry2):
-                p, o_state, _ = carry2
+                p, o_state, _, aux = carry2
                 env_idx = jax.lax.dynamic_slice(perm, (i * env_bs,), (env_bs,))
 
+                @jax.named_scope("update.loss")
                 def loss_of(p_):
-                    a_out, new_values = fwd(p_, env_idx)
+                    a_out, new_values, load = fwd(p_, env_idx)
                     acts = jnp.take(rollout["actions"], env_idx, axis=1)
                     lp, ent = _dist_stats(a_out, acts, actions_dim, is_continuous)
                     adv = jnp.take(advantages, env_idx, axis=1)
-                    if normalize_adv:
-                        adv = normalize_tensor(adv)
                     old_lp = jnp.take(rollout["logprobs"], env_idx, axis=1)
                     ret = jnp.take(returns, env_idx, axis=1)
                     old_v = jnp.take(values, env_idx, axis=1)
-                    pg = policy_loss(lp, old_lp, adv, clip_coef, reduction)
-                    vl = value_loss(new_values[..., 0], old_v, ret, clip_coef, clip_vloss, reduction)
-                    el = entropy_loss(ent, reduction)
-                    return pg + vf_coef * vl + ent_coef * el, (pg, vl, el)
+                    if mask is None:
+                        if normalize_adv:
+                            adv = normalize_tensor(adv)
+                        pg = policy_loss(lp, old_lp, adv, clip_coef, reduction)
+                        vl = value_loss(new_values[..., 0], old_v, ret, clip_coef, clip_vloss, reduction)
+                        el = entropy_loss(ent, reduction)
+                    else:
+                        mk = jnp.take(mask, env_idx, axis=1)
+                        if normalize_adv:
+                            adv = normalize_tensor(adv, mask=mk)
+                        pg = policy_loss(lp, old_lp, adv, clip_coef, reduction, mk)
+                        vl = value_loss(new_values[..., 0], old_v, ret, clip_coef, clip_vloss, reduction, mk)
+                        el = entropy_loss(ent, reduction, mk)
+                    return pg + vf_coef * vl + ent_coef * el, ((pg, vl, el), load)
 
-                (_, (pg, vl, el)), grads = jax.value_and_grad(loss_of, has_aux=True)(p)
-                updates, o_state = optimizer.update(grads, o_state, p)
-                p = optax.apply_updates(p, updates)
-                return p, o_state, (pg, vl, el)
+                (_, ((pg, vl, el), load)), grads = jax.value_and_grad(loss_of, has_aux=True)(p)
+                with jax.named_scope("update.optim"):
+                    updates, o_state = optimizer.update(grads, o_state, p)
+                    p = optax.apply_updates(p, updates)
+                    if load is not None:
+                        p = core.after_update(p, load)
+                        first = aux["updates"] == 0
+                        aux = {
+                            "updates": aux["updates"] + 1,
+                            "load": aux["load"] + load,
+                            "first_load": jnp.where(first, load, aux["first_load"]),
+                            "first_losses": jnp.where(first, jnp.stack([pg, vl, el]), aux["first_losses"]),
+                        }
+                return p, o_state, (pg, vl, el), aux
 
-            p, o_state, losses = jax.lax.fori_loop(
+            p, o_state, losses, aux = jax.lax.fori_loop(
                 0, num_minibatches, mb_body,
-                (p, o_state, (jnp.zeros(()), jnp.zeros(()), jnp.zeros(()))),
+                (p, o_state, (jnp.zeros(()), jnp.zeros(()), jnp.zeros(())), aux),
             )
-            return (p, o_state), losses
+            return (p, o_state, aux), losses
 
         # recurrent PPO is MLP-only (no conv trunk): the XLA-CPU
         # outlined-loop penalty is conv-specific (utils.window_scan), so the
         # compact scan/fori lowering stays unconditionally
-        (p, o_state), losses = jax.lax.scan(
-            epoch_body, (p, o_state), jax.random.split(k, update_epochs)
+        (p, o_state, aux), losses = jax.lax.scan(
+            epoch_body, (p, o_state, core.init_aux()), jax.random.split(k, update_epochs)
         )
-        return p, o_state, jax.tree.map(lambda x: x[-1], losses)
+        return p, o_state, jax.tree.map(lambda x: x[-1], losses), aux
 
     # the staged rollout is donated too (argnum 2): one dispatch consumes it
     # exactly once (see ppo.py)
@@ -274,7 +332,7 @@ def main(fabric: Any, cfg: Any) -> None:
             np.zeros((num_envs, hidden_size), np.float32),
             np.zeros((num_envs, hidden_size), np.float32),
         )
-    player_params = fabric.to_host(params)
+    player_params = None if use_anakin else fabric.to_host(params)  # the fused path has no host player
     last_losses = None
     # per-rank player key stream, advanced inside policy_step_fn; the main
     # `key` stays rank-identical for train dispatches
@@ -307,22 +365,13 @@ def main(fabric: Any, cfg: Any) -> None:
             traced_polynomial_decay,
         )
 
-        def step_apply(p, carry, obs_d, prev_a, first):
-            return agent.apply(
-                p, method=RecurrentPPOAgent.step, carry=carry, obs=obs_d,
-                prev_actions=prev_a, is_first=first,
-            )
-
         def _sample_fn(actor_out, k):
             return _sample(actor_out, actions_dim, is_continuous, k)
 
-        def _encode(a):
-            return one_hot_actions(a, actions_dim, is_continuous)
-
         rollout_fn = make_recurrent_rollout_fn(
-            venv, step_apply, _sample_fn, _encode,
+            venv, core.policy_step, _sample_fn, core.encode_prev,
             mlp_keys=mlp_keys, action_space=act_space, gamma=gamma,
-            rollout_steps=rollout_steps,
+            rollout_steps=rollout_steps, store_values=core.stores_values,
         )
 
         def anakin_phase(p, o_state, actor, k):
@@ -342,11 +391,14 @@ def main(fabric: Any, cfg: Any) -> None:
                     o_state,
                     traced_polynomial_decay(step0, initial=base_lr, max_decay_steps=total_iters),
                 )
-            actor, rollout, init_carry, last_values, stats = rollout_fn(p, actor, k_roll)
-            p, o_state, losses = train_phase_fn(
+            actor, rollout, init_carry, last_values, stats = rollout_fn(core.acting_params(p), actor, k_roll)
+            stats = {**stats, **core.rollout_stats(rollout, init_carry, venv, actor)}
+            p, o_state, losses, aux = train_phase_fn(
                 p, o_state, rollout, init_carry, last_values, k_train, ent,
                 env_bs=env_bs, num_minibatches=num_minibatches,
             )
+            if aux is not None:
+                stats = {**stats, **{kk: aux[kk] for kk in ("load", "first_load", "first_losses")}}
             return p, o_state, actor, k_next, losses, stats
 
         anakin_step = fabric.compile(
@@ -360,17 +412,21 @@ def main(fabric: Any, cfg: Any) -> None:
             start_iter - 1,
             sharded=num_envs % fabric.local_world_size == 0,
             extra={
-                "carry": (
-                    jnp.zeros((num_envs, hidden_size), jnp.float32),
-                    jnp.zeros((num_envs, hidden_size), jnp.float32),
-                ),
+                "carry": core.initial_state(num_envs),
                 "prev_actions": jnp.zeros((num_envs, act_width), jnp.float32),
                 "is_first": jnp.ones((num_envs, 1), jnp.float32),
             },
         )
+        if hasattr(venv.env, "warm_start") and hasattr(core, "prefill"):
+            actor_state = _warm_start(fabric, cfg, venv, core, params, actor_state, jax.random.fold_in(key, 7))
     guard_anakin = bool(cfg.buffer.get("transfer_guard", False))
 
+    from sheeprl_tpu.utils.profiler import ProfilerGate
+
+    profiler = ProfilerGate(cfg, log_dir)
     for update in range(start_iter, total_iters + 1):
+        profiler.step(update)
+        SPANS.iteration(update)  # the `iter` span: closes the one before
         if use_anakin:
             # -------- fused rollout+train: ONE dispatch per update ---------
             with timer("Time/train_time"):
@@ -384,7 +440,13 @@ def main(fabric: Any, cfg: Any) -> None:
                 # the H2D-scoped steady guard)
                 from sheeprl_tpu.envs.jax.anakin import episode_stats_from_device
 
-                rets, lens = episode_stats_from_device(ep_stats)
+                # the loop's first wait for the fused dispatch: its host time
+                # is the device's, so it gets a span of its own
+                with SPANS.span("stats.pull", phase=False) as pull:
+                    rets, lens = episode_stats_from_device(ep_stats)
+                    counts = core.host_counts(ep_stats) if pull is not None else {}
+                    if counts:
+                        pull.count(**counts)
                 for ep_ret, ep_len in zip(rets, lens):
                     aggregator.update("Rewards/rew_avg", float(ep_ret))
                     aggregator.update("Game/ep_len_avg", int(ep_len))
@@ -431,7 +493,7 @@ def main(fabric: Any, cfg: Any) -> None:
                                     one_hot_actions(jnp.asarray(actions_np), actions_dim, is_continuous)
                                 )
                                 _, (_, v_boot) = agent.apply(
-                                    player_params, method=RecurrentPPOAgent.step,
+                                    player_params, method=Agent.step,
                                     carry=(jnp.asarray(carry_np[0]), jnp.asarray(carry_np[1])),
                                     obs={k: jnp.asarray(padded[k]) for k in mlp_keys},
                                     prev_actions=jnp.asarray(prev_a_boot),
@@ -484,7 +546,7 @@ def main(fabric: Any, cfg: Any) -> None:
                     k: jnp.asarray(np.asarray(obs[k], np.float32).reshape(num_envs, -1)) for k in mlp_keys
                 }
                 _, (_, last_v) = agent.apply(
-                    player_params, method=RecurrentPPOAgent.step,
+                    player_params, method=Agent.step,
                     carry=(jnp.asarray(carry_np[0]), jnp.asarray(carry_np[1])),
                     obs=dev_obs, prev_actions=jnp.asarray(prev_actions),
                     is_first=jnp.asarray(is_first),
@@ -494,7 +556,7 @@ def main(fabric: Any, cfg: Any) -> None:
                 last_v_flat = np.asarray(last_v)[..., 0]
                 ent_dev = stage_scalar(ent_coef_v)
                 with steady_guard(guard_on and update > start_iter):
-                    params, opt_state, last_losses = train_phase(
+                    params, opt_state, last_losses, _ = train_phase(
                         params, opt_state, rollout,
                         fabric.shard_batch(carry_pair, axis=0) if sharded_envs else fabric.replicate(carry_pair),
                         fabric.shard_batch(last_v_flat, axis=0) if sharded_envs else fabric.replicate(last_v_flat),
@@ -545,6 +607,8 @@ def main(fabric: Any, cfg: Any) -> None:
             fabric.print(f"Preemption: committed checkpoint at step {policy_step}, exiting")
             break
 
+    SPANS.end_iteration()
+    profiler.close()
     if envs is not None:
         envs.close()
     ckpt_mgr.finalize()

@@ -487,6 +487,10 @@ def registration(argv: Optional[List[str]] = None) -> None:
         print(f"Registered models: {versions}")
 
 
+#: recurrent cores an algorithm's ``algo.core`` selects between (the first is its default)
+ALGORITHM_CORES = {"ppo_recurrent": "lstm, decoder (exp=ppo_tokens)"}
+
+
 def available_agents() -> None:
     """Print the registered algorithms (reference: sheeprl/available_agents.py:7-34)."""
     import sheeprl_tpu
@@ -501,11 +505,13 @@ def available_agents() -> None:
         table.add_column("Module")
         table.add_column("Entrypoint")
         table.add_column("Decoupled")
+        table.add_column("Cores")
         for name, entries in sorted(algorithm_registry.items()):
             for e in entries:
-                table.add_row(name, e.module, e.entrypoint, str(e.decoupled))
+                table.add_row(name, e.module, e.entrypoint, str(e.decoupled), ALGORITHM_CORES.get(name, ""))
         Console().print(table)
     except Exception:
         for name, entries in sorted(algorithm_registry.items()):
             for e in entries:
-                print(f"{name}\t{e.module}\t{e.entrypoint}\tdecoupled={e.decoupled}")
+                cores = f"\tcores={ALGORITHM_CORES[name]}" if name in ALGORITHM_CORES else ""
+                print(f"{name}\t{e.module}\t{e.entrypoint}\tdecoupled={e.decoupled}{cores}")

@@ -285,9 +285,10 @@ def _classify_overrides(
         value = _parse_value(raw.strip())
         if "." not in key and key in groups:
             group_selection[key] = value
-        elif "@" in key and key.partition("@")[0] in groups and key.partition("@")[2]:
+        elif "@" in key and key.partition("@")[0].partition("/")[0] in groups and key.partition("@")[2]:
             # hydra's full placement grammar, "optim@algo.world_model.optimizer=sgd":
             # place group file optim/sgd.yaml AT the dotted destination path
+            # (a nested group too: "algo/decoder@algo.decoder=tiny")
             grp, _, dest = key.partition("@")
             placed_groups.append((dest, grp, value))
         elif "/" in key and key.rpartition("/")[2] in groups:
@@ -372,7 +373,16 @@ def _load_yaml_exp(
                 if k.startswith("override "):
                     k = k[len("override "):]
                 k = k.lstrip("/")
-                if k == "exp":
+                if "@" in k:
+                    # "/algo/decoder@algo.decoder: trinity_mini": the group file
+                    # placed at the dotted path; the exp's own values win
+                    src, _, at = k.partition("@")
+                    loaded = _load_group(src, v, dirs)
+                    loaded.pop("__root__", None)
+                    placed: Dict[str, Any] = {}
+                    set_by_path(placed, at, loaded)
+                    data = deep_merge(placed, data)
+                elif k == "exp":
                     base = _load_yaml_exp(v, dirs, cfg, cli_groups)
                     data = deep_merge(base, data)
                 elif k in cli_groups:

@@ -209,12 +209,15 @@ def make_recurrent_rollout_fn(
     action_space: gym.Space,
     gamma: float,
     rollout_steps: int,
+    store_values: bool = False,
 ) -> Callable:
-    """The recurrent (LSTM) twin of :func:`make_rollout_fn` for
-    ``ppo_recurrent`` (ROADMAP item 5's remaining half): the ``nn.scan``
-    policy's per-step method runs INSIDE the fused ``lax.scan`` rollout,
-    with the recurrent state, previous-action encoding and episode-start
-    mask all living in the donated device-resident actor carry.
+    """The recurrent twin of :func:`make_rollout_fn` for ``ppo_recurrent``
+    (ROADMAP item 5's remaining half): the policy's per-step method runs
+    INSIDE the fused ``lax.scan`` rollout, with the recurrent state,
+    previous-action encoding and episode-start mask all living in the
+    donated device-resident actor carry.  The recurrent state is any
+    pytree with the env axis leading: an LSTM's ``(c, h)``, a decoder's
+    caches and positions.
 
     ``step_apply(p, carry, obs, prev_actions, is_first) -> (carry',
     (actor_out, value))`` is the agent's single-step apply;
@@ -227,30 +230,48 @@ def make_recurrent_rollout_fn(
     the last step — everything the existing ``ppo_recurrent`` train phase
     takes, computed without a single host↔device transfer.
 
+    An env with a ``loss_mask(state)`` gives the rollout a ``mask`` key
+    beside ``is_first`` (1 where the step's action counts in the losses);
+    ``store_values`` keeps the steps' values under ``values``.
+
     Truncation bootstrap uses the POST-step recurrent state on the true
-    final observation (the host loop's padded re-dispatch, in-trace).
+    final observation (the host loop's padded re-dispatch, in-trace); an
+    env that ``never_truncates`` is spared that second policy step.
     """
     prep = prep_obs_fn((), mlp_keys)
     to_env = env_actions_fn(action_space)
     num_envs = venv.num_envs
+    never_truncates = bool(getattr(venv.env, "never_truncates", False))
+    loss_mask = jax.vmap(venv.env.loss_mask) if hasattr(venv.env, "loss_mask") else None
 
     def rollout(p: Any, actor: Dict[str, Any], key: jax.Array):
         init_carry = actor["carry"]
 
         def body(carry, k_step):
-            env_state, (c, h), prev_actions, is_first, ep_ret, ep_len = carry
+            env_state, rc, prev_actions, is_first, ep_ret, ep_len = carry
             pobs = prep(venv.observe(env_state))
-            (c2, h2), (actor_out, value) = step_apply(p, (c, h), pobs, prev_actions, is_first)
-            actions, logprob = sample_fn(actor_out, k_step)
-            env_state, _, reward, term, trunc, final_obs = venv.step(env_state, to_env(actions))
+            with jax.named_scope("rollout.policy"):
+                rc2, (actor_out, value) = step_apply(p, rc, pobs, prev_actions, is_first)
+                actions, logprob = sample_fn(actor_out, k_step)
+            extra = {}
+            if loss_mask is not None:
+                extra["mask"] = loss_mask(env_state)
+            if store_values:
+                extra["values"] = value[..., 0]
+            with jax.named_scope("rollout.env_step"):
+                env_state, _, reward, term, trunc, final_obs = venv.step(env_state, to_env(actions))
             prev_a_next = encode_prev_actions(actions)
-            # truncation bootstrap with the post-step recurrent state
-            _, (_, v_final) = step_apply(
-                p, (c2, h2), prep(final_obs), prev_a_next,
-                jnp.zeros((num_envs, 1), jnp.float32),
-            )
-            trunc_f = trunc.astype(jnp.float32)
-            boot_reward = reward + gamma * v_final[..., 0] * trunc_f
+            if never_truncates:
+                boot_reward = reward
+            else:
+                # truncation bootstrap with the post-step recurrent state
+                with jax.named_scope("rollout.policy"):
+                    _, (_, v_final) = step_apply(
+                        p, rc2, prep(final_obs), prev_a_next,
+                        jnp.zeros((num_envs, 1), jnp.float32),
+                    )
+                trunc_f = trunc.astype(jnp.float32)
+                boot_reward = reward + gamma * v_final[..., 0] * trunc_f
             done = jnp.logical_or(term, trunc)
             done_f = done.astype(jnp.float32)
             ep_ret = ep_ret + reward
@@ -263,6 +284,7 @@ def make_recurrent_rollout_fn(
                 "dones": done_f,
                 "is_first": is_first,
                 "prev_actions": prev_actions,
+                **extra,
                 "ep_done": done,
                 "ep_ret": ep_ret,
                 "ep_len": ep_len,
@@ -272,7 +294,7 @@ def make_recurrent_rollout_fn(
             # episode boundary resets the next step's recurrent inputs
             prev_a_next = prev_a_next * (1.0 - done_f[..., None])
             is_first_next = done_f[..., None]
-            return (env_state, (c2, h2), prev_a_next, is_first_next, ep_ret, ep_len), step_out
+            return (env_state, rc2, prev_a_next, is_first_next, ep_ret, ep_len), step_out
 
         keys = jax.random.split(key, rollout_steps)
         (env_state, carry2, prev_actions, is_first, ep_ret, ep_len), traj = jax.lax.scan(
@@ -285,9 +307,10 @@ def make_recurrent_rollout_fn(
         )
         stats = {k: traj.pop(k) for k in ("ep_done", "ep_ret", "ep_len")}
         # bootstrap values for the post-rollout state, with the live carry
-        _, (_, last_v) = step_apply(
-            p, carry2, prep(venv.observe(env_state)), prev_actions, is_first
-        )
+        with jax.named_scope("rollout.policy"):
+            _, (_, last_v) = step_apply(
+                p, carry2, prep(venv.observe(env_state)), prev_actions, is_first
+            )
         new_actor = {
             "env": env_state,
             "carry": carry2,

@@ -56,6 +56,13 @@ def _multiroom(**kwargs: Any) -> JaxEnv:
     return JaxMultiRoom(**kwargs)
 
 
+@_register("tokens")
+def _tokens(**kwargs: Any) -> JaxEnv:
+    from sheeprl_tpu.envs.jax.tokens import JaxTokens
+
+    return JaxTokens(**kwargs)
+
+
 def make_jax_env(env_id: str, **kwargs: Any) -> JaxEnv:
     """Build a registered pure-JAX env; accepts both the bare registry name
     (``cartpole``) and the config-group spelling (``jax_cartpole``)."""

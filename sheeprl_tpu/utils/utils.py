@@ -143,8 +143,13 @@ def two_hot_decoder(probs: jax.Array, support_range: int = 300) -> jax.Array:
 # misc numerics
 # --------------------------------------------------------------------------
 
-def normalize_tensor(x: jax.Array, eps: float = 1e-8) -> jax.Array:
+def normalize_tensor(x: jax.Array, eps: float = 1e-8, mask: Optional[jax.Array] = None) -> jax.Array:
     # ddof=1: torch.std is unbiased (reference: sheeprl/utils/utils.py:126)
+    if mask is not None:  # mean and deviation over the steps that count (mask 1) alone
+        n = jnp.maximum(mask.sum(), 1.0)
+        mean = (x * mask).sum() / n
+        std = jnp.sqrt((mask * (x - mean) ** 2).sum() / jnp.maximum(n - 1.0, 1.0))
+        return (x - mean) / (std + eps)
     return (x - x.mean()) / (x.std(ddof=1) + eps)
 
 

@@ -1,0 +1,67 @@
+"""The fused programs that existed before the recurrent carry became a pytree lower to what they lowered to.
+
+``FINGERPRINTS`` were taken at the parent of PR 30 (commit 39a5b61) with ``fingerprint`` below: the SHA-256 of
+the lowered program's text and, should a later JAX print the same program under other names, the three
+losses of the first three dispatches to the bit on the CPU.
+"""
+
+import hashlib
+
+import pytest
+
+from sheeprl_tpu.parallel.fabric import Fabric
+
+COMMON = [
+    "env=jax_cartpole", "env.num_envs=4", "algo.rollout_steps=8", "algo.per_rank_batch_size=16", "algo.update_epochs=2",
+    "algo.total_steps=96", "algo.run_test=False", "fabric.accelerator=cpu", "fabric.devices=1", "metric.log_level=0",
+    "checkpoint.every=1000000", "checkpoint.save_last=False", "print_config=False", "seed=5",
+]
+CASES = {
+    "ppo_recurrent.anakin_phase": ["exp=ppo_recurrent", "algo.mlp_keys.encoder=[state]"] + COMMON,
+    "ppo.anakin_phase": ["exp=ppo", "algo.mlp_keys.encoder=[state]"] + COMMON,
+}
+FINGERPRINTS = {
+    "ppo_recurrent.anakin_phase": ("295e03695902f42525d77e76f856469841fcf8b19025fc877d60cf4480933e96", "-0x1.069b840000000p+2,0x1.0d9c6e0000000p+4,-0x1.2ec3820000000p-1;-0x1.4c3fd80000000p+1,0x1.dc898c0000000p+2,-0x1.46a50e0000000p-1;-0x1.c2165e0000000p+1,0x1.ace03c0000000p+3,-0x1.43f95e0000000p-1"),
+    "ppo.anakin_phase": ("124a0c14a6451bd4888c5a8f10af4dc0ae044467edc94bbe217df49948a54721", "-0x1.cb614c0000000p+1,0x1.d8694c0000000p+3,-0x1.6191ac0000000p-1;-0x1.dd68000000000p+1,0x1.d4426e0000000p+3,-0x1.6039cc0000000p-1;-0x1.a229760000000p+1,0x1.aeb9ca0000000p+3,-0x1.5c9c8a0000000p-1"),
+}
+
+
+def fingerprint(program, log_dir):
+    """(sha256 of the lowered text, the losses of every dispatch as hex floats) of one short run."""
+    from sheeprl_tpu.cli import run
+
+    texts, losses = [], []
+    real = Fabric.compile
+
+    class Probe:
+        def __init__(self, aot):
+            self.aot = aot
+
+        def __getattr__(self, name):
+            return getattr(self.aot, name)
+
+        def __call__(self, *args):
+            if not texts:
+                texts.append(self.aot.lower(*args).as_text())
+            out = self.aot(*args)
+            losses.append(",".join(float(x).hex() for x in out[4]))
+            return out
+
+    def probed(fabric, fn, **kwargs):
+        aot = real(fabric, fn, **kwargs)
+        return Probe(aot) if kwargs.get("name") == program else aot
+
+    Fabric.compile = probed
+    try:
+        run(CASES[program] + [f"log_dir={log_dir}"])
+    finally:
+        Fabric.compile = real
+    return hashlib.sha256(texts[0].encode()).hexdigest(), ";".join(losses)
+
+
+@pytest.mark.parametrize("program", sorted(CASES))
+def test_program_is_the_one_the_parent_lowered(program, tmp_path):
+    digest, losses = fingerprint(program, tmp_path)
+    pinned_digest, pinned_losses = FINGERPRINTS[program]
+    assert losses.count(";") == 2  # three dispatches
+    assert digest == pinned_digest or losses == pinned_losses, (digest, losses)

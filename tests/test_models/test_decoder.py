@@ -1,0 +1,225 @@
+"""The decoder core against the plain reference (``chipbench/reference/trinity_mini_ep8.py``, which imports
+nothing from the program) at tiny widths on the CPU: hidden 64, 4 query heads on 2 key-value heads of 16,
+window 8, 8 experts of width 32 with 2 a token, vocabulary 64."""
+
+import importlib.util
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from sheeprl_tpu.models import decoder
+from sheeprl_tpu.models.decoder import DecoderConfig
+
+ROOT = Path(__file__).resolve().parents[2]
+LAYERS = ("sliding_attention",) * 4 + ("full_attention",)
+TINY = dict(
+    hidden_size=64, num_attention_heads=4, num_key_value_heads=2, head_dim=16, sliding_window=8,
+    intermediate_size=128, moe_intermediate_size=32, num_experts=8, num_experts_per_tok=2, experts_held=(0, 2),
+    num_shared_experts=1, num_dense_layers=1, layer_types=LAYERS, rms_norm_eps=1e-5, rope_theta=10000.0,
+    route_scale=2.826, route_norm=True, mup_enabled=True, load_balance_coeff=0.001,
+)
+VOCAB, MAX_LEN = 64, 32
+TOL = dict(rtol=2e-4, atol=2e-4)
+
+
+def load_reference():
+    spec = importlib.util.spec_from_file_location("trinity_reference", ROOT / "chipbench/reference/trinity_mini_ep8.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+ref = load_reference()
+
+
+@pytest.fixture(autouse=True)
+def query_blocks_of_four(monkeypatch):
+    """A segment of these tests then spans several query blocks, as a rollout's does at the published sizes."""
+    monkeypatch.setattr(decoder, "Q_BLOCK", 4)
+
+
+def config(**changes):
+    return DecoderConfig.from_dict({**TINY, **changes}, vocab_size=VOCAB, max_len=MAX_LEN)
+
+
+def ref_config(cfg: DecoderConfig):
+    return ref._Static({**TINY, "experts_held": cfg.experts_held})
+
+
+def episode(seed, T, B, resets=()):
+    """Tokens (T, B) and is_first (T, B): a reset at step 0 and at every (t, b) of ``resets``."""
+    tokens = jax.random.randint(jax.random.PRNGKey(seed), (T, B), 0, VOCAB)
+    first = np.zeros((T, B), np.float32)
+    first[0] = 1.0
+    for t, b in resets:
+        first[t, b] = 1.0
+    return tokens, jnp.asarray(first)
+
+
+def reference_full(params, cfg, tokens, first, **how):
+    """The reference's full forward over whole episodes from nothing: (logits, values) as (T, B, ...)."""
+    T, B = tokens.shape
+    pos, ep = ref.positions(first, jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32))
+    logits, values, counts, _ = ref.forward(params, dict(ref_config(cfg)), tokens.T, pos.T, ep.T, ref.empty_past(TINY, B), **how)
+    return jnp.moveaxis(logits, 0, 1), values.T, counts
+
+
+@pytest.fixture(scope="module")
+def params():
+    return decoder.init_params(config(), jax.random.PRNGKey(0), std=0.1)
+
+
+@pytest.mark.parametrize("resets", [(), ((5, 0), (11, 1), (12, 1))], ids=["past_the_window", "resets_inside"])
+def test_segment_forward_matches_the_reference(params, resets):
+    """Episodes longer than the window (8), with and without a reset inside the segment: logits and values."""
+    cfg = config()
+    tokens, first = episode(1, 20, 3, resets)
+    logits, values, load = decoder.segment(params, cfg, decoder.init_carry(cfg, 3, jnp.float32), tokens, first, jnp.float32)
+    want_logits, want_values, want_load = reference_full(params, cfg, tokens, first)
+    np.testing.assert_allclose(logits, want_logits, **TOL)
+    np.testing.assert_allclose(values[..., 0], want_values, **TOL)
+    np.testing.assert_array_equal(load, want_load)
+
+
+def test_a_window_layer_differs_from_a_full_one_past_the_window(params):
+    """The reference's planted fault (a sliding layer that sees the whole episode) must move the result, or
+    the comparison above would not hold the window to anything."""
+    cfg = config()
+    tokens, first = episode(1, 20, 3)
+    sound, _, _ = reference_full(params, cfg, tokens, first)
+    faulty, _, _ = reference_full(params, cfg, tokens, first, fault="window")
+    np.testing.assert_allclose(sound[:8], faulty[:8], **TOL)
+    assert float(jnp.abs(sound[8:] - faulty[8:]).max()) > 1e-2
+
+
+def test_steps_through_the_cache_match_one_segment(params):
+    """T calls of ``step`` against one ``segment`` call on the same tokens, a reset inside included."""
+    cfg = config()
+    tokens, first = episode(2, 20, 3, ((7, 2), (13, 0)))
+    carry = decoder.init_carry(cfg, 3, jnp.float32)
+    want_logits, want_values, _ = decoder.segment(params, cfg, carry, tokens, first, jnp.float32)
+    step = jax.jit(lambda c, tok, f: decoder.step(params, cfg, c, tok, f, jnp.float32))
+    for t in range(tokens.shape[0]):
+        carry, logits, value = step(carry, tokens[t], first[t])
+        np.testing.assert_allclose(logits, want_logits[t], **TOL)
+        np.testing.assert_allclose(value, want_values[t], **TOL)
+    assert carry["pos"].tolist() == [7, 20, 13]
+
+
+@pytest.mark.parametrize("valid", [None, (8, 3, 0)], ids=["whole", "ragged"])
+def test_prefill_then_decode_then_the_full_pass_agree(params, valid):
+    """A segment on a cached prefix against the same tokens in one piece: prefill 8 tokens (ragged: only each
+    env's first ``valid``), decode 4 through the cache, run the next 8 as a segment on that cache; all of it
+    against the reference's full forward of every env's own tokens."""
+    cfg = config()
+    B = 3
+    tokens, first = episode(3, 20, B)
+    n = np.asarray(valid if valid is not None else (8,) * B)
+    carry = decoder.init_carry(cfg, B, jnp.float32)
+    _, _, _, carry = decoder.segment(
+        params, cfg, carry, tokens[:8], first[:8], jnp.float32, extend=True, valid=None if valid is None else jnp.asarray(n))
+    assert carry["pos"].tolist() == n.tolist()
+    # every env goes on from where its prefill ended: env b's stream is its first n_b tokens, then tokens[8:]
+    streams = [np.concatenate([np.asarray(tokens[: n[b], b]), np.asarray(tokens[8:, b])]) for b in range(B)]
+    got = []
+    for t in range(8, 12):
+        carry, logits, _ = decoder.step(params, cfg, carry, tokens[t], jnp.where(carry["pos"] == 0, 1.0, 0.0), jnp.float32)
+        got.append(logits)
+    seg_first = jnp.zeros((8, B)).at[0].set(jnp.where(carry["pos"] == 0, 1.0, 0.0))
+    logits, _, _ = decoder.segment(params, cfg, carry, tokens[12:], seg_first, jnp.float32)
+    got = jnp.concatenate([jnp.stack(got), logits])  # (12, B, V): the last 12 tokens of every stream
+    for b in range(B):
+        stream = jnp.asarray(streams[b])[:, None]
+        want, _, _ = reference_full(params, cfg, stream, jnp.zeros(stream.shape).at[0].set(1.0))
+        np.testing.assert_allclose(got[:, b], want[-12:, 0], **TOL)
+
+
+def moe_layer(params):
+    return params["layer_1"]["moe"]
+
+
+def test_the_shares_add_up_to_the_whole_layer():
+    """8 experts split 2 a share over 4 shares: the four partial results of the routed experts, and the shared
+    expert counted once, add up to the uncut reference's layer output; the router's counts sum to k x tokens
+    whatever is held."""
+    whole = decoder.init_params(config(experts_held=(0, 8)), jax.random.PRNGKey(4), std=0.1)
+    moe = moe_layer(whole)
+    m = jax.random.normal(jax.random.PRNGKey(5), (24, 64))
+    want, want_counts = ref.experts_part(moe, m, {**TINY, "experts_held": (0, 8)}, "f32", None)
+    total = decoder._ffn(moe["shared"], m)
+    for first in range(0, 8, 2):
+        cfg = config(experts_held=(first, 2))
+        experts, weights, counts = decoder.route(moe, m, cfg)
+        assert int(counts.sum()) == 2 * 24
+        np.testing.assert_array_equal(counts, want_counts)
+        share = {k: v[first:first + 2] for k, v in moe["experts"].items()}
+        total = total + decoder.held_experts(share, m, experts, weights, cfg)
+    np.testing.assert_allclose(total, want, **TOL)
+
+
+@pytest.mark.parametrize("tokens", [24, 4], ids=["a_segment_s_rows", "a_decode_step_s_rows"])
+@pytest.mark.parametrize("bias, all_here", [((9.0, 0, 0, 0, 0, 0, 0, 8.0), True), ((0, 0, -9.0, -9.0, 0, 0, 0, 0), False)],
+                         ids=["all_to_one_held_expert", "none_to_a_held_expert"])
+def test_routing_is_dropless_at_the_extremes(bias, all_here, tokens):
+    """Every token sent to one held expert (and to one held elsewhere), and no token to any held expert: the
+    reference's result both times, no token dropped."""
+    cfg = config(experts_held=(0, 2) if all_here else (2, 2))
+    layer = decoder.init_params(cfg, jax.random.PRNGKey(6), std=0.1)["layer_1"]
+    moe = dict(layer["moe"], router_bias=jnp.asarray(bias, jnp.float32))
+    m = jax.random.normal(jax.random.PRNGKey(7), (tokens, 64))
+    experts, weights, counts = decoder.route(moe, m, cfg)
+    held = counts[cfg.experts_held[0]: cfg.experts_held[0] + 2]
+    assert int(held.max()) == (tokens if all_here else 0) and int(counts.sum()) == 2 * tokens
+    got = decoder._ffn(moe["shared"], m) + decoder.held_experts(moe["experts"], m, experts, weights, cfg)
+    want, _ = ref.experts_part(moe, m, {**TINY, "experts_held": cfg.experts_held}, "f32", None)
+    np.testing.assert_allclose(got, want, **TOL)
+
+
+def test_gradients_through_the_grouped_product_match_the_dense_loop():
+    """Rows of experts held elsewhere belong to no group of the grouped product; nothing of them may reach the
+    gradients (on a TPU such rows are left unwritten, so the layer cuts them off on both sides of the product)."""
+    cfg = config(experts_held=(2, 3))
+    moe = decoder.init_params(cfg, jax.random.PRNGKey(8), std=0.1)["layer_1"]["moe"]
+    m = jax.random.normal(jax.random.PRNGKey(9), (24, 64))
+
+    def ours(moe, m):
+        experts, weights, _ = decoder.route(moe, m, cfg)
+        return jnp.sum(jnp.sin(decoder._ffn(moe["shared"], m) + decoder.held_experts(moe["experts"], m, experts, weights, cfg)))
+
+    def theirs(moe, m):
+        return jnp.sum(jnp.sin(ref.experts_part(moe, m, {**TINY, "experts_held": (2, 3)}, "f32", None)[0]))
+
+    got, want = jax.grad(ours, argnums=(0, 1))(moe, m), jax.grad(theirs, argnums=(0, 1))(moe, m)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, **TOL)
+
+
+def test_the_bias_rule_over_one_update(params):
+    cfg = config()
+    load = jnp.asarray(np.random.default_rng(0).integers(0, 9, (4, 8)))
+    got = decoder.update_router_bias(params, load, cfg)
+    want = ref.bias_step(params, load, TINY)
+    for i in cfg.moe_layers():
+        row = load[i - 1].astype(jnp.float32)
+        np.testing.assert_allclose(got[f"layer_{i}"]["moe"]["router_bias"], 0.001 * jnp.sign(row.mean() - row))
+        np.testing.assert_array_equal(got[f"layer_{i}"]["moe"]["router_bias"], want[f"layer_{i}"]["moe"]["router_bias"])
+    assert got["layer_0"] is params["layer_0"]
+
+
+def test_parameter_count_at_the_published_widths():
+    """The configuration's table (chipbench/configs/trinity_mini_ep8.json) from the shapes ``init_params`` makes."""
+    import json
+
+    from sheeprl_tpu.config.compose import compose
+
+    model = compose(["exp=ppo_tokens"]).as_dict()["algo"]["decoder"]
+    cfg = DecoderConfig.from_dict(model, vocab_size=25024, max_len=8192)
+    shapes = jax.eval_shape(lambda k: decoder.init_params(cfg, k), jax.random.PRNGKey(0))
+    count = lambda tree: sum(int(np.prod(x.shape)) for x in jax.tree.leaves(tree))  # noqa: E731
+    stated = json.loads((ROOT / "chipbench/configs/trinity_mini_ep8.json").read_text())["parameters"]
+    assert count(shapes["layer_0"]) == stated["dense layer"]
+    assert count(shapes["layer_1"]) == stated["expert layer (16 of 128 experts held)"]
+    assert count(shapes) == stated["total"] == 705476352
