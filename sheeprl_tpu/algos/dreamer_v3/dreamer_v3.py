@@ -15,9 +15,10 @@ TPU-native architecture:
   bulk-sample pattern, dreamer_v3.py:664-671) and the device scans over U
   full updates (world model + actor + critic + EMA);
 * the environment player is a latent-state policy on ``algo.player.device``
-  (host CPU by default — zero device round-trips during interaction —
-  or ``accelerator`` for thin links / big encoders), refreshed once per
-  ratio window via a packed single-transfer param pull;
+  (``auto``: beside the train state when a refresh would pull more than
+  ``PLAYER_PULL_BYTES`` to the host, as every preset from S up does; the
+  host CPU below that), refreshed once per ratio window: one on-device
+  tree copy beside the train state, one packed transfer to the host;
 * replay lives ON DEVICE (``buffer.device``, data/device_replay.py): the
   whole ring — pixels included — is a mesh-sharded HBM pytree, and
   sequence sampling compiles INTO the update dispatch, so steady-state
@@ -184,7 +185,8 @@ def dreamer_family_loop(
     timer.configure(cfg.metric)
 
     psync = PlayerSync(
-        fabric, cfg, extract=lambda p: {"world_model": p["world_model"], "actor": p["actor"]}
+        fabric, cfg, extract=lambda p: {"world_model": p["world_model"], "actor": p["actor"]},
+        params=params,
     )
     host = psync.device  # single resolution of algo.player.device
     stoch_flat = world_model.stoch_flat

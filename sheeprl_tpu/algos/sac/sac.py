@@ -227,7 +227,7 @@ def sac_loop(fabric: Any, cfg: Any, build_agent_fn: Any, critic_apply: Any) -> N
     )
     timer.configure(cfg.metric)
 
-    psync = PlayerSync(fabric, cfg, extract=lambda p: p["actor"])
+    psync = PlayerSync(fabric, cfg, extract=lambda p: p["actor"], params=params)
     host = psync.device  # single resolution of algo.player.device
     act_fn, train_phase = make_sac_train_fns(
         actor, critic, critic_apply, actor_opt, critic_opt, alpha_opt, cfg, act_dim

@@ -126,7 +126,7 @@ def main(fabric: Any, cfg: Any) -> None:
     timer.configure(cfg.metric)
 
     psync = PlayerSync(
-        fabric, cfg, extract=lambda p: {"encoder": p["encoder"], "actor": p["actor"]}
+        fabric, cfg, extract=lambda p: {"encoder": p["encoder"], "actor": p["actor"]}, params=params
     )
     host = psync.device  # single resolution of algo.player.device
     gamma = float(cfg.algo.gamma)

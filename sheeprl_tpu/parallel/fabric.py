@@ -23,6 +23,7 @@ Design differences from the reference, on purpose (SURVEY.md §2.2/§7):
 
 from __future__ import annotations
 
+import functools
 import os
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Tuple, Union
@@ -32,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from sheeprl_tpu.telemetry.recorder import RECORDER
 from sheeprl_tpu.telemetry.spans import span
 
 
@@ -245,13 +247,22 @@ class Fabric:
         """Copy a pytree to the host CPU device (one bulk transfer)."""
         return self.copy_to(tree, self.host_device)
 
-    def copy_to(self, tree: Any, device: Any) -> Any:
+    def copy_to(self, tree: Any, device: Any, into: Any = None) -> Any:
         """Copy a pytree onto ``device``.
 
         ALWAYS a real copy: when the source already lives on the target
         device, ``device_put`` would be a no-op alias — and the training
         step donates its params input, which would invalidate the player's
-        copy mid-rollout.  ``.copy()`` breaks the alias.
+        copy mid-rollout.  A tree that lives on the target whole is copied
+        by ONE compiled program (``_copy_tree``: one dispatch where a
+        ``.copy()`` per leaf made 75 for DV3-S, 24 ms on a v5e's host); a
+        lone such leaf among others still takes ``.copy()``.  ``into``, a
+        tree the caller is done with (the player's previous copy), is
+        donated to that program where it lives on the target too: the new
+        copy is written into its buffers and the caller must drop it.  On
+        a v5e's host every fresh output buffer costs the dispatch 45 us,
+        so the refresh of DV3-S's 75 leaves is 0.5 ms into the old copy
+        and 3.6 ms into new memory (PERF.md section 6, PR 31).
 
         Cross-platform trees (the host-player param pull) take the PACKED
         path: per-leaf transfers cost one D2H round-trip each (a player tree
@@ -266,6 +277,14 @@ class Fabric:
 
         fault_point("fabric.copy_to")
         leaves, treedef = jax.tree.flatten(tree)
+        if leaves and _lives_on(leaves, device):
+            # the whole tree already lives on the target (a player beside the
+            # train state): one executable copies every leaf, nothing leaves
+            # the device
+            old_leaves, old_def = jax.tree.flatten(into)
+            if old_def == treedef and _lives_on(old_leaves, device):
+                return _copy_tree_into(tree, into)
+            return _copy_tree(tree)
         if all(isinstance(x, jax.Array) and x.is_fully_addressable for x in leaves):
             # replicated multi-device params (any real mesh) carry the full
             # value in every shard — pack from the process-local one
@@ -315,23 +334,36 @@ class Fabric:
 
         return jax.tree.map(put, tree)
 
-    def player_device(self, cfg: Any) -> Any:
+    def player_device(self, cfg: Any, refresh_bytes: int = 0) -> Any:
         """The device the env-interaction player runs on.
 
-        ``algo.player.device=host`` (default) pins rollout inference to the
-        host CPU — the right call when device dispatch latency dominates
-        (small models).  ``accelerator`` runs the player on
-        the first mesh device instead — the right call for big pixel
-        encoders on-pod, where the host would become the bottleneck."""
-        choice = (cfg.algo.get("player", {}) or {}).get("device", "host")
+        ``algo.player.device=host`` pins rollout inference to the host CPU;
+        ``accelerator`` runs it on the first process-local mesh device.  Left
+        at ``auto`` (the default) the code decides from ``refresh_bytes``,
+        the weights one refresh moves (``PlayerSync`` counts them): above
+        ``PLAYER_PULL_BYTES`` the player runs beside the train state and its
+        refresh never leaves the device, below it on the host.  Measured on
+        a v5e (PERF.md section 6, PR 31): DV3-S pulled 67 MB in 54 ms every
+        iteration of 140; beside the train state the refresh is 1.2 ms and
+        the iteration 93 (27.3 -> 42.1 env-steps/s on one machine).
+
+        Without a count the answer is the host: the on-policy and decoupled
+        loops call this with ``cfg`` alone and keep the host player (their
+        rollouts need the current weights in one transfer, and none of them
+        was measured on the chip)."""
+        choice = (cfg.algo.get("player", {}) or {}).get("device", "auto")
+        if choice not in ("auto", "host", "accelerator"):
+            raise ValueError(
+                f"algo.player.device must be 'auto', 'host' or 'accelerator', got {choice!r}"
+            )
+        if choice == "auto":
+            choice = "accelerator" if refresh_bytes > PLAYER_PULL_BYTES else "host"
         if choice == "accelerator":
             # PROCESS-LOCAL first device: self.device is globally enumerated
             # and non-addressable from worker hosts in multi-host runs (the
             # on-pod scenario this option exists for)
             local = [d for d in jax.local_devices() if d.platform == self.accelerator]
             return local[0] if local else self.device
-        if choice != "host":
-            raise ValueError(f"algo.player.device must be 'host' or 'accelerator', got {choice!r}")
         return self.host_device
 
     # -- sharding helpers --------------------------------------------------
@@ -805,15 +837,43 @@ class PlayerSync:
     TRAINING window (``algo.player.sync_every``, sac_decoupled sets 10);
     the player then acts on weights up to k (+1 when deferred) training
     windows old — the reference's player↔trainer refresh cadence.
+
+    Where the player lives is decided here, once, from ``params``: the
+    builder hands over the train state's params (or their shapes) and
+    :meth:`Fabric.player_device` weighs ``extract(params)``, the tree every
+    refresh moves, against ``PLAYER_PULL_BYTES`` unless
+    ``algo.player.device`` pins the answer.  Beside the train state the
+    refresh is one on-device tree copy (``Fabric.copy_to``) under the same
+    protocol — deferred, cadence, staleness — so the player acts on the
+    weights it acted on when they crossed to the host; the ``bytes`` of the
+    ``player.sync`` span count only what crosses.  The decision is printed
+    once and recorded as the ``player.placement`` recorder event.
     """
 
-    def __init__(self, fabric: "Fabric", cfg: Any, extract: Callable[[Any], Any]):
+    def __init__(
+        self, fabric: "Fabric", cfg: Any, extract: Callable[[Any], Any], params: Any = None
+    ):
         player_cfg = cfg.algo.get("player", {}) or {}
         self.fabric = fabric
         self.extract = extract
-        self.device = fabric.player_device(cfg)
+        # `params` (the train state's, or their shapes) is what lets
+        # `algo.player.device=auto` decide: the bytes one refresh moves
+        refresh_bytes = 0 if params is None else tree_bytes(extract(params))
+        self.device = fabric.player_device(cfg, refresh_bytes)
         self.deferred = bool(player_cfg.get("deferred_sync", True))
         self.sync_every = max(1, int(player_cfg.get("sync_every", 1)))
+        if params is not None:
+            placed = {
+                "device": str(self.device),
+                "asked": player_cfg.get("device", "auto"),
+                "tree_bytes": refresh_bytes,
+                "threshold_bytes": PLAYER_PULL_BYTES,
+            }
+            RECORDER.record("player.placement", **placed)
+            fabric.print(
+                "player on {device} (algo.player.device={asked}): a refresh moves {tree_bytes} bytes, "
+                "the player leaves the host above {threshold_bytes}".format(**placed)
+            )
         self._pending: Any = None
         self._windows = 0  # completed training windows (dispatches)
         # staleness accounting (ISSUE 12 satellite): which window produced
@@ -846,21 +906,26 @@ class PlayerSync:
             "Player/param_staleness_max": float(self.staleness_max),
         }
 
-    def _pull(self, token: Any, params: Any) -> Any:
-        """The weight pull itself, its bytes counted on the open ``player.sync`` span."""
+    def _pull(self, token: Any, params: Any, player_params: Any) -> Any:
+        """The refresh itself.  ``bytes`` on the open ``player.sync`` span count
+        what crosses to the host: the tree's bytes where the player's platform
+        is not the train state's, none where the player sits beside it (there
+        the copy is written into ``player_params``, which the caller drops)."""
         tree = self.extract(params)
-        if token is not None:
-            token.count(bytes=sum(int(x.nbytes) for x in jax.tree.leaves(tree)))
-        return self.fabric.copy_to(tree, self.device)
+        if token is not None and self.device.platform != self.fabric.accelerator:
+            token.count(bytes=tree_bytes(tree))
+        return self.fabric.copy_to(tree, self.device, into=player_params)
 
     def before_dispatch(self, player_params: Any) -> Any:
-        """Pull the previous window's (long since finished) train output."""
+        """Pull the previous window's (long since finished) train output.
+        Rebind the player's tree to what this returns, as with
+        ``after_dispatch``: a refresh may write into the old one's buffers."""
         with span("player.sync", phase=False) as token:
             if self._pending is not None:
                 pending, self._pending = self._pending, None
                 self._player_version = self._pending_version
                 self._observe_staleness()
-                return self._pull(token, pending)
+                return self._pull(token, pending, player_params)
             self._observe_staleness()
             return player_params
 
@@ -885,7 +950,7 @@ class PlayerSync:
             return player_params
         self._player_version = self._windows
         self._observe_staleness()
-        return self._pull(token, params)
+        return self._pull(token, params, player_params)
 
     # -- checkpointing ------------------------------------------------------
     def state_dict(self) -> Dict[str, int]:
@@ -904,6 +969,48 @@ class PlayerSync:
         # see state_dict: staleness restarts at zero, only cadence persists
         self._player_version = self._windows
         self._pending = None
+
+
+# Where `algo.player.device=auto` moves the player off the host: the bytes one
+# refresh would pull.  The pull runs with the chip drained at 1.3 GB/s of a
+# v5e's host (DV3-S: 67 MB in 54 ms).  Beside the train state the refresh is
+# 1.2 ms, but every player step queues behind the train dispatch, so what the
+# host did under that dispatch it now does after it.  In dv3s_forage_coupled,
+# whose envs step on the chip and waited for the train dispatch already, that is
+# the player step, its copies and the ring write: the iteration less the pull
+# is 85 ms on the host and 92 beside the train state, 7 ms or 9 MB of pull
+# (PERF.md section 6, PR 31).  With envs stepped on the host it is all the
+# loop does between two dispatches, 25 to 35 ms there: 32 to 45 MB.  The lower
+# end of that, so that no preset smaller than the one measured (DV3-S) moves
+# unless its pull costs more than the most a host player can win back.
+PLAYER_PULL_BYTES = 32 * 2**20
+
+
+def tree_bytes(tree: Any) -> int:
+    """Bytes of a pytree of arrays or of their shapes (``jax.ShapeDtypeStruct``)."""
+    return sum(int(np.prod(x.shape)) * np.dtype(x.dtype).itemsize for x in jax.tree.leaves(tree))
+
+
+def _lives_on(leaves: Any, device: Any) -> bool:
+    return all(
+        isinstance(x, jax.Array) and x.committed and set(x.devices()) == {device} for x in leaves
+    )
+
+
+@jax.jit
+def _copy_tree(tree: Any) -> Any:
+    """Every leaf copied on the device it lives on, in one executable.  Real
+    copies: the inputs are not donated, so XLA gives each output a buffer of
+    its own (``jnp.copy`` keeps jit from forwarding the input array itself)."""
+    return jax.tree.map(jnp.copy, tree)
+
+
+@functools.partial(jax.jit, donate_argnums=1, keep_unused=True)
+def _copy_tree_into(tree: Any, into: Any) -> Any:
+    """``_copy_tree`` written into the donated buffers of ``into`` (same
+    shapes): no output buffer is allocated.  ``keep_unused`` keeps the
+    donated argument, which the computation never reads, in the executable."""
+    return jax.tree.map(jnp.copy, tree)
 
 
 def _packed_copy(leaves: Any, device: Any) -> Any:

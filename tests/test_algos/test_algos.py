@@ -644,6 +644,93 @@ def test_dreamer_v3_accelerator_player(tmp_path):
     run(args)
 
 
+def _cpu0():
+    import jax
+
+    return jax.devices("cpu")[0]
+
+
+def _placements():
+    from sheeprl_tpu.telemetry.recorder import RECORDER
+
+    return [e for e in RECORDER.snapshot() if e["kind"] == "player.placement"]
+
+
+def test_dreamer_v3_default_placement_beside_the_train_state(tmp_path, monkeypatch):
+    """``algo.player.device`` left alone and a tree above the threshold (lowered here: the tiny
+    model is 40 kB): the player runs on the mesh's first device, its refresh is the on-device
+    tree copy, and neither an episode's end nor a refresh makes a second player program."""
+    from sheeprl_tpu.parallel import fabric as fabric_mod
+    from sheeprl_tpu.telemetry import SPANS
+    from sheeprl_tpu.utils.profiler import COMPILE_MONITOR
+
+    monkeypatch.setattr(fabric_mod, "PLAYER_PULL_BYTES", 1024)
+    before = COMPILE_MONITOR.count("dreamer_v3.player_step")
+    args = standard_args(
+        tmp_path,
+        extra=[
+            "exp=dreamer_v3",
+            "env=dummy",
+            "env.id=discrete_dummy",
+            *DV3_XS_ARGS,
+            "dry_run=False",
+            "algo.learning_starts=8",
+            "algo.total_steps=32",  # 16 iterations of 2 envs: episodes end at 6 and 12
+            "env.max_episode_steps=6",
+            "algo.replay_ratio=0.5",  # one update a window from the first: one train program
+            "algo.per_rank_pretrain_steps=1",
+            "algo.max_recompiles=1",
+            "algo.run_test=False",
+            "checkpoint.every=1000000",
+            "checkpoint.save_last=False",
+            "metric.log_level=0",
+        ],
+    )
+    with mock.patch.object(fabric_mod, "_copy_tree_into", wraps=fabric_mod._copy_tree_into) as refresh:
+        run(args)
+    (placed,) = _placements()
+    assert placed["asked"] == "auto" and placed["tree_bytes"] > placed["threshold_bytes"] == 1024
+    assert placed["device"] == str(_cpu0())
+    assert refresh.call_count >= 5  # a refresh a window, each one executable written into the player's buffers
+    assert COMPILE_MONITOR.count("dreamer_v3.player_step") - before == 1
+    syncs = [r for r in SPANS.records() if r.name == "player.sync"]
+    assert syncs and not any((r.counts or {}).get("bytes") for r in syncs)  # nothing crossed to a host
+
+
+def test_sac_default_placement_stays_on_the_host(tmp_path):
+    """SAC's actor is far under the threshold: left alone, the player stays on the host and every
+    program compiles for what it compiles for under ``algo.player.device=host``."""
+    from sheeprl_tpu.utils.profiler import COMPILE_MONITOR
+
+    def compiled(extra, log_dir):
+        seen = {k: len(v["signatures"]) for k, v in COMPILE_MONITOR.summary().items()}
+        run(standard_args(
+            log_dir,
+            extra=[
+                "exp=sac",
+                "env=dummy",
+                "env.id=continuous_dummy",
+                "algo.per_rank_batch_size=8",
+                "algo.learning_starts=4",
+                "algo.mlp_keys.encoder=[state]",
+                "env.max_episode_steps=16",
+                "buffer.size=64",
+                *extra,
+            ],
+        ))
+        (placed,) = _placements()
+        return placed, {
+            k: v["signatures"][seen.get(k, 0):] for k, v in COMPILE_MONITOR.summary().items() if k.startswith("sac.")
+        }
+
+    placed, programs = compiled([], tmp_path / "auto")
+    assert placed["asked"] == "auto" and placed["tree_bytes"] < 2**20 < placed["threshold_bytes"]
+    assert placed["device"] == str(_cpu0())
+    pinned, pinned_programs = compiled(["algo.player.device=host"], tmp_path / "host")
+    assert pinned["asked"] == "host" and pinned["device"] == placed["device"]
+    assert programs == pinned_programs and any(programs.values())
+
+
 @pytest.mark.parametrize(
     "exp,extra",
     [

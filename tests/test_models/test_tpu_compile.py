@@ -198,3 +198,17 @@ def test_fused_dv3_gather_holds_no_copy_of_the_ring_on_v5e(one_chip):
     raw = window * n_envs * 64 * 64 * 3
     # the gathered block (0.2 GiB as the chip pads it), nothing of the ring's size (3 GiB)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.1 * raw
+
+
+def test_player_refresh_beside_the_train_state_is_a_real_copy_on_v5e(one_chip):
+    """``Fabric.copy_to``'s one-executable tree copy as the chip's compiler builds it: every
+    output a buffer of its own (the train phase donates the source), no temporaries."""
+    from sheeprl_tpu.parallel.fabric import _copy_tree, tree_bytes
+
+    tree = {"wm": {"kernel": _spec(one_chip, 1536, 1536), "bias": _spec(one_chip, 1536)},
+            "actor": {"kernel": _spec(one_chip, 1536, 5, dtype=jnp.bfloat16)}}
+    compiled = _copy_tree.lower(tree).compile()
+    assert "input_output_alias" not in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 0 and memory.temp_size_in_bytes == 0
+    assert memory.output_size_in_bytes >= tree_bytes(tree)

@@ -184,6 +184,89 @@ def test_player_device_selection():
         fab.player_device(dotdict({"algo": {"player": {"device": "gpu"}}}))
 
 
+@pytest.mark.parametrize(
+    "asked, mib, on_accelerator",
+    [
+        ("auto", 1, False),  # SAC's actor: stays on the host
+        ("auto", 64, True),  # DV3-S: beside the train state
+        (None, 64, True),  # a config without the key is `auto`
+        ("host", 64, False),
+        ("accelerator", 1, True),
+    ],
+)
+def test_player_placement_follows_the_bytes_a_refresh_pulls(asked, mib, on_accelerator):
+    from unittest import mock
+
+    from sheeprl_tpu.parallel.fabric import PLAYER_PULL_BYTES, PlayerSync, tree_bytes
+    from sheeprl_tpu.telemetry.recorder import RECORDER
+    from sheeprl_tpu.utils.structured import dotdict
+
+    fab = Fabric(devices=1, accelerator="cpu")
+    cfg = dotdict({"algo": {"player": {} if asked is None else {"device": asked}}})
+    # shapes are enough to decide: nothing of this size is allocated
+    params = {
+        "actor": {"w": jax.ShapeDtypeStruct((mib, 2**18), jnp.float32), "b": jax.ShapeDtypeStruct((7,), jnp.bfloat16)},
+        "critic": jax.ShapeDtypeStruct((2**28,), jnp.float32),  # never pulled, never counted
+    }
+    pulled = mib * 2**20 + 14
+    assert tree_bytes(params["actor"]) == pulled and (pulled > PLAYER_PULL_BYTES) == (mib == 64)
+    sentinel = mock.Mock(platform="cpu")
+    RECORDER.clear()
+    with mock.patch.object(type(fab), "host_device", new_callable=mock.PropertyMock, return_value=sentinel):
+        psync = PlayerSync(fab, cfg, extract=lambda p: p["actor"], params=params)
+        assert psync.device is (fab.device if on_accelerator else sentinel)
+        # without a tree (the on-policy and decoupled loops) only an explicit value leaves the host
+        assert fab.player_device(cfg) is (fab.device if asked == "accelerator" else sentinel)
+    (event,) = [e for e in RECORDER.snapshot() if e["kind"] == "player.placement"]
+    assert event["tree_bytes"] == pulled and event["threshold_bytes"] == PLAYER_PULL_BYTES
+    assert event["asked"] == (asked or "auto") and event["device"] == str(psync.device)
+
+
+def test_same_device_refresh_is_one_executable_and_a_real_copy():
+    """A player beside the train state: the refresh is one dispatch that writes into the player's
+    own buffers (no output is allocated), and what it returns outlives the donation of its source
+    (the train phase donates ``params``; under deferred sync the player acts on window N-1's
+    weights while window N's dispatch donates them)."""
+    from unittest import mock
+
+    from sheeprl_tpu.parallel import fabric as fabric_mod
+    from sheeprl_tpu.parallel.fabric import PlayerSync
+    from sheeprl_tpu.utils.structured import dotdict
+
+    fab = Fabric(devices=1, accelerator="cpu")
+    cfg = dotdict({"algo": {"player": {"device": "accelerator"}}})
+    params = fab.replicate(
+        {"actor": {f"layer_{i}": {"w": jnp.full((8, 8), float(i)), "b": jnp.arange(8.0)} for i in range(20)},
+         "log_alpha": jnp.array(0.5)}  # weak-typed, as SAC's is
+    )
+    psync = PlayerSync(fab, cfg, extract=lambda p: p, params=params)
+    step = jax.jit(lambda t: jax.tree.map(lambda x: x + 1, t), donate_argnums=0)
+    with mock.patch.object(fabric_mod, "_copy_tree", wraps=fabric_mod._copy_tree) as fresh, \
+            mock.patch.object(fabric_mod, "_copy_tree_into", wraps=fabric_mod._copy_tree_into) as in_place, \
+            mock.patch.object(type(jnp.ones(1)), "copy", side_effect=AssertionError("a copy per leaf")):
+        first = psync.init(params)
+        held = [x.unsafe_buffer_pointer() for x in jax.tree.leaves(first)]
+        params = step(params)  # window 1 trains: every weight moves by one
+        player = psync.after_dispatch(params, first)  # deferred: pending
+        assert player is first
+        player = psync.before_dispatch(player)  # the refresh
+        assert (fresh.call_count, in_place.call_count) == (1, 1)
+    assert all(x.is_deleted() for x in jax.tree.leaves(first))  # handed over: the caller rebinds
+    assert [x.unsafe_buffer_pointer() for x in jax.tree.leaves(player)] == held
+    sources = jax.tree.leaves(params)
+    for got, src in zip(jax.tree.leaves(player), sources):
+        assert got.unsafe_buffer_pointer() != src.unsafe_buffer_pointer()
+        assert got.weak_type == src.weak_type and got.sharding == src.sharding
+    params = step(params)  # window 2 donates what the player was copied from
+    assert all(x.is_deleted() for x in sources)
+    assert float(player["actor"]["layer_7"]["w"][0, 0]) == 8.0 and float(player["log_alpha"]) == 1.5
+    assert float(params["actor"]["layer_7"]["w"][0, 0]) == 9.0
+    # a tree of another structure is no place to write into: a fresh copy, and it is left alone
+    other = fab.replicate({"w": jnp.zeros(3)})
+    again = fab.copy_to(params, fab.device, into=other)
+    assert float(again["log_alpha"]) == 2.5 and not other["w"].is_deleted()
+
+
 def test_host_collectives_single_process():
     fab = Fabric(devices=2, accelerator="cpu")
     assert fab.broadcast_object({"a": 1}) == {"a": 1}

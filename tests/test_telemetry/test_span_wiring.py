@@ -224,7 +224,10 @@ def test_env_step_span_wraps_what_vectorize_returns():
     assert "env.step" not in SPANS.breakdown()["phases"]  # a boundary: a record and an annotation
 
 
-def test_player_sync_counts_the_bytes_it_pulls():
+@pytest.mark.parametrize("crosses", [False, True], ids=["beside_the_train_state", "to_another_platform"])
+def test_player_sync_counts_the_bytes_it_pulls(crosses):
+    from types import SimpleNamespace
+
     import jax.numpy as jnp
 
     from sheeprl_tpu.parallel.fabric import Fabric, PlayerSync
@@ -234,10 +237,15 @@ def test_player_sync_counts_the_bytes_it_pulls():
     cfg = dotdict({"algo": {"player": {"deferred_sync": True, "sync_every": 1, "device": "host"}}})
     psync = PlayerSync(fab, cfg, extract=lambda p: p["actor"])
     player = psync.init({"actor": jnp.zeros((3, 2), jnp.float32)})
+    if crosses:
+        # no second platform here: a player device of another platform, and the copy stood in for
+        psync.device = SimpleNamespace(platform="host-of-a-chip")
+        fab.copy_to = lambda tree, device, into=None: tree
     SPANS.reset()
     player = psync.after_dispatch({"actor": jnp.ones((3, 2), jnp.float32)}, player_params=player)  # deferred: nothing moves
     player = psync.before_dispatch(player)  # the pending weights land: 6 float32
     psync.before_dispatch(player)  # nothing pending
     syncs = [r for r in SPANS.records() if r.name == "player.sync"]
-    assert [(r.counts or {}).get("bytes", 0) for r in syncs] == [0, 24, 0]
+    assert [(r.counts or {}).get("bytes", 0) for r in syncs] == [0, 24 if crosses else 0, 0]
+    assert float(player[0, 0]) == 1.0
     assert "player.sync" not in SPANS.breakdown()["phases"]
