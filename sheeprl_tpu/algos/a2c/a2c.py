@@ -487,8 +487,7 @@ def main(fabric: Any, cfg: Any) -> None:
         envs.close()
     ckpt_mgr.finalize()
     if use_population and fabric.is_global_zero:
-        # machine-readable member snapshot for the run_ci PBT drill and
-        # bench --mode population
+        # machine-readable member snapshot for the run_ci PBT drill
         write_population_summary(log_dir, pop_state, hp_state, policy_step)
     if fabric.is_global_zero and cfg.algo.run_test and not ckpt_mgr.preempted:
         if use_population:

@@ -94,8 +94,8 @@ from sheeprl_tpu.utils.utils import (
 
 def build_dv3_optimizers(fabric, cfg, params, saved_opt_state=None):
     """Optimizers + (replicated) opt state for the three param groups —
-    shared by main(), bench.py and __graft_entry__.py so the benchmarked
-    program is the training program."""
+    shared by main(), __graft_entry__.py and the mesh tests so the program
+    they check is the training program."""
     wm_opt = build_optimizer(cfg.algo.world_model.optimizer, cfg.algo.world_model.clip_gradients)
     actor_opt = build_optimizer(cfg.algo.actor.optimizer, cfg.algo.actor.clip_gradients)
     critic_opt = build_optimizer(cfg.algo.critic.optimizer, cfg.algo.critic.clip_gradients)
@@ -734,10 +734,10 @@ def make_wm_stages(cfg, world_model, cnn_keys, mlp_keys):
     """Build the world-model forward and its pipeline stage chain.
 
     Returns ``(wm_forward, stage_fns, stage_names)``.  Module-level (not
-    nested in :func:`make_train_phase`) so ``bench.py --mode pipeline``
-    can compile standalone per-stage programs
-    (``parallel/pipeline.py compile_stage_pair``) from exactly the
-    functions the fused train phase pipelines.
+    nested in :func:`make_train_phase`) so that standalone per-stage
+    programs (``parallel/pipeline.py compile_stage_pair``) and the
+    described-TPU compile test (``tests/test_models/test_tpu_compile.py``)
+    build from exactly the functions the fused train phase pipelines.
     """
     obs_keys = tuple(cnn_keys) + tuple(mlp_keys)
     stoch_flat = world_model.stoch_flat
@@ -953,8 +953,9 @@ def make_train_phase(
     fabric, cfg, world_model, actor, critic, wm_opt, actor_opt, critic_opt,
     cnn_keys, mlp_keys, is_continuous, params=None, opt_state=None,
 ):
-    """Build the jitted multi-update train phase (shared with bench.py and
-    __graft_entry__.py so the benchmarked program IS the training program).
+    """Build the jitted multi-update train phase (shared with
+    __graft_entry__.py and the mesh tests so the program they check IS the
+    training program).
 
     ``params``/``opt_state``: the already-placed state trees.  When given,
     their partition-rules shardings are pinned as the program's in/out

@@ -10,8 +10,8 @@ suppression etiquette.
 Entry points:
 
 * ``sheeprl-tpu-lint`` / ``python -m sheeprl_tpu.analysis`` — the CLI
-* :func:`run_analysis` — in-process (the tier-1 test and ``bench.py
-  --mode lint`` call this)
+* :func:`run_analysis` — in-process (the tier-1 test
+  ``tests/test_analysis/test_repo_clean.py`` calls this)
 * ``# graftlint: disable=<rule>`` — inline suppression;
   ``analysis/baseline.json`` — the accepted-findings ledger
 """

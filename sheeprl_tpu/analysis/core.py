@@ -456,8 +456,8 @@ def run_analysis(
     root: Optional[Path] = None,
 ) -> Report:
     """Analyze ``paths`` (default: the ``sheeprl_tpu`` package) and return a
-    :class:`Report`.  This is the in-process entry the tier-1 test and
-    ``bench.py --mode lint`` call; the CLI wraps it."""
+    :class:`Report`.  This is the in-process entry the tier-1 test
+    (``tests/test_analysis/test_repo_clean.py``) calls; the CLI wraps it."""
     import time as _time
 
     from sheeprl_tpu.analysis import donation, prng, purity, registry

@@ -11,7 +11,7 @@ never — a dead YAML knob silently reassures whoever flips it):
   ``.get("k", default)`` steps are the sanctioned optional-access
   spelling and are never errors (they still count as reads).
 * ``cfg-dead-key`` — a YAML leaf no code path reads.  The read-set is
-  collected from the package PLUS the read-only roots (tests/, bench.py,
+  collected from the package PLUS the read-only roots (tests/,
   benchmarks/, examples/, the graft entry): prefix reads cover subtrees
   (``build_optimizer(cfg.algo.actor.optimizer)`` reads everything under
   it), ``${a.b.c}`` YAML interpolations count, and a final conservative
@@ -53,7 +53,7 @@ from sheeprl_tpu.analysis.core import (
 
 #: extra roots scanned for READS only (they never produce findings, but a
 #: key only they read is not dead)
-READ_ONLY_ROOTS = ("tests", "benchmarks", "examples", "bench.py", "__graft_entry__.py")
+READ_ONLY_ROOTS = ("tests", "benchmarks", "examples", "__graft_entry__.py")
 
 #: dict/dotdict methods that terminate a cfg chain without extending it
 _DICT_METHODS = (

@@ -366,9 +366,10 @@ def steady_guard(enabled: bool):
     """Arm ``jax.transfer_guard_host_to_device("disallow")`` around a
     steady-state train window: any IMPLICIT host→device transfer inside
     raises (explicit ``device_put`` staging stays legal).  This is the
-    red/green spelling of the zero-copy claim — the same guard ``bench.py``
-    arms around its timed loop and the ``run_ci.sh`` replay stage arms
-    around whole training runs.
+    red/green spelling of the zero-copy claim — the guard the
+    ``run_ci.sh`` replay stage arms around whole training runs
+    (``buffer.transfer_guard=True``) and the tier-1 replay tests around
+    their windows.
 
     Scoped to the H2D direction on purpose: device-to-device movement (the
     per-window PRNG key broadcasting onto a multi-device mesh, GSPMD
@@ -669,7 +670,7 @@ class DeviceReplay:
 
     @property
     def hbm_bytes(self) -> int:
-        """Resident ring bytes (the ``replay_hbm_bytes`` bench column)."""
+        """Resident ring bytes."""
         return sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in self._buf.values())
 
     def sampled_bytes_per_update(

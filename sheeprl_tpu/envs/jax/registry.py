@@ -100,7 +100,7 @@ def anakin_enabled(cfg: Any, fabric: Any) -> bool:
     ``algo.anakin``: ``auto`` (default) fuses whenever the env is
     jax-native and the run is single-process; ``True`` demands it (raising
     on a non-jax env); ``False`` forces the adapter/vector-env path even
-    for jax envs (useful for A/B benches and the scenario matrix).
+    for jax envs (the scenario matrix's adapter cells).
     Multi-process runs fall back to the adapter path: the fused program is
     a per-process dispatch and the cross-host rollout-pool semantics of
     the decoupled samplers don't apply to it yet.

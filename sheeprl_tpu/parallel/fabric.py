@@ -1068,8 +1068,8 @@ def build_fabric(cfg: Any) -> Fabric:
     ensure_distributed(cfg)
     if "tp_min_param_size" in fab_cfg and not _TP_MIN_PARAM_SIZE_WARNED:
         # fire ONCE per process, not per build_fabric call: long runs build
-        # fabrics repeatedly (supervisor relaunch probes, bench A/B arms,
-        # player clones) and a per-call DeprecationWarning floods the log —
+        # fabrics repeatedly (supervisor relaunch probes, player clones) and
+        # a per-call DeprecationWarning floods the log —
         # and "default"-filtered warnings dedupe per call SITE, which this
         # single callsite defeats.  Pinned by
         # tests/test_sharding/test_deprecation.py.  In a pod, only rank 0

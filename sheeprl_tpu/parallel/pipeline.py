@@ -48,8 +48,8 @@ tests/test_parallel/test_tensor_parallel.py).
 
 Telemetry: the schedule's bubble fraction ``(S-1)/(M+S-1)`` is a
 first-class metric (``Pipeline/bubble_frac`` through the hub;
-``Phase/pipeline.stage.*`` spans from the bench harness — taxonomy in
-docs/telemetry.md).  Tuning guide and schedule diagram: docs/pipeline.md.
+``Phase/pipeline.stage.*`` spans from :func:`compile_stage_pair`'s
+programs — taxonomy in docs/telemetry.md).  Tuning guide and schedule diagram: docs/pipeline.md.
 """
 
 from __future__ import annotations
@@ -175,8 +175,8 @@ def bubble_fraction(stages: int, microbatches: int) -> float:
 
     ``M + S - 1`` ticks drain ``M`` microbatches through ``S`` stages; the
     ``S - 1`` ramp-up/ramp-down ticks are bubble.  Per-stage-balanced
-    approximation — bench.py --mode pipeline also reports the measured
-    estimate from per-stage wall times."""
+    approximation: measured per-stage times need a run on the chip (no
+    cell yet: ROADMAP D5, R8)."""
     s, m = int(stages), int(microbatches)
     if s <= 1:
         return 0.0
@@ -486,14 +486,14 @@ def stage_batch_constraint(mesh: Any, data_axis: str, batch_axis: int = 1):
 
 
 # --------------------------------------------------------------------------
-# per-stage measurement harness (bench.py --mode pipeline)
+# per-stage measurement harness (no caller in the tree: ROADMAP D5)
 # --------------------------------------------------------------------------
 
 def compile_stage_pair(fabric: Any, stage_fn: Callable[[Any, Any], Any], *, name: str,
                        max_recompiles: Optional[int] = None) -> Tuple[Any, Any]:
     """Standalone compiled ``(forward, backward)`` programs for ONE stage —
-    the per-stage timing harness behind ``bench.py --mode pipeline``'s phase
-    breakdown (``Phase/pipeline.stage.*`` spans).
+    a per-stage timing harness (``Phase/pipeline.stage.*`` spans) that a
+    pipeline cell would drive; nothing in the tree calls it (ROADMAP D5).
 
     The backward rematerializes the stage forward (the 1F1B activation-
     recompute discipline, same lever as ``algo.remat``) and DONATES both the

@@ -322,8 +322,8 @@ def write_population_summary(
 ) -> str:
     """Land the run's final population snapshot as
     ``<log_dir>/population_summary.json`` — the machine-readable artifact
-    the run_ci PBT drill (stage 18) and bench ``--mode population`` read
-    to compare members across runs."""
+    ``tests/population_drill.py`` (run_ci's PBT drill) reads to compare
+    members across runs."""
     fitness = np.asarray(pop["fitness"], np.float64)
     summary = {
         "policy_step": int(policy_step),

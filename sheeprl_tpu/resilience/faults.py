@@ -18,7 +18,9 @@ The plan comes from the ``fault_injection`` config group
 the spelling that crosses process boundaries: spawned env workers, the
 decoupled trainer, subprocess drills).
 
-**Zero overhead when disabled is a hard guarantee** (gated in ``bench.py``):
+**Zero overhead when disabled is a hard guarantee** (held by
+``tests/test_resilience/test_faults.py``: an empty plan compiles to
+``None``, a disabled config installs nothing):
 :func:`install_plan` stores ``None`` when the plan has no specs, and every
 hot-path hook (:func:`fault_point`, :func:`fault_bytes`) starts with a
 single module-global ``is None`` test.  Nothing else — no dict lookups, no

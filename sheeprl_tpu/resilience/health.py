@@ -164,7 +164,7 @@ class HealthSentinel:
 
     1. ``HealthSentinel.from_config(cfg, fabric)`` — ``None`` when
        ``health.enabled=false`` (the guard is compiled OUT; call sites keep
-       the exact unguarded program — the bench A/B arm).
+       the exact unguarded program).
     2. ``train_phase = fabric.compile(sentinel.wrap(train_phase), ...)`` —
        the guarded program: ``(h, p, o, *rest) -> (h, p, o, metrics)``.
     3. ``h = sentinel.init_state()`` — the replicated device state.

@@ -35,7 +35,6 @@ the newest shared commit.
 
 from __future__ import annotations
 
-import json
 import threading
 import time
 from pathlib import Path
@@ -72,12 +71,6 @@ from sheeprl_tpu.utils.logger import get_log_dir, get_logger
 from sheeprl_tpu.utils.metric import MetricAggregator, flush_metrics
 from sheeprl_tpu.utils.timer import timer
 from sheeprl_tpu.utils.utils import polynomial_decay, save_configs
-
-# the marker line the learner prints its final stats behind — the pod
-# drill and ``bench.py --mode dcn`` parse it out of the (rank-prefixed)
-# combined fake-DCN output
-POD_STATS_MARKER = "POD_STATS_JSON="
-
 
 def _pod_knobs(cfg: Any) -> Tuple[Dict[str, Any], Dict[str, Any], Dict[str, Any]]:
     topo_cfg = topology_cfg(cfg)
@@ -123,19 +116,7 @@ def _start_watchdog(fabric: Any, dist: Dict[str, Any]) -> Optional[PeerWatchdog]
         return None  # KV client unavailable (tests with hand-built fabrics)
 
 
-def _jsonable(obj: Any) -> Any:
-    if isinstance(obj, dict):
-        return {str(k): _jsonable(v) for k, v in obj.items()}
-    if isinstance(obj, (list, tuple)):
-        return [_jsonable(v) for v in obj]
-    if isinstance(obj, (np.floating, np.integer)):
-        return obj.item()
-    if isinstance(obj, (int, float, str, bool)) or obj is None:
-        return obj
-    return str(obj)
-
-
-def run_pod(fabric: Any, cfg: Any) -> Dict[str, Any]:
+def run_pod(fabric: Any, cfg: Any) -> None:
     """Train through the cross-host pod topology.  Dispatches on this
     process's role; both roles run the identical preamble (seed, run-dir
     agreement, telemetry arm) so the fabric's host-collective sequence
@@ -159,14 +140,13 @@ def run_pod(fabric: Any, cfg: Any) -> Dict[str, Any]:
     try:
         if topo.role == "learner":
             save_configs(cfg, log_dir)
-            if flavor == "ppo":
-                return _learner_ppo(fabric, cfg, topo, key=key, log_dir=log_dir, logger=logger)
-            return _learner_sac(fabric, cfg, topo, key=key, log_dir=log_dir, logger=logger)
+            learner = _learner_ppo if flavor == "ppo" else _learner_sac
+            learner(fabric, cfg, topo, key=key, log_dir=log_dir, logger=logger)
+            return
         HUB.set_namespace(f"rank{topo.process_index}")
         try:
-            if flavor == "ppo":
-                return _actor_ppo(fabric, cfg, topo, key=key, log_dir=log_dir)
-            return _actor_sac(fabric, cfg, topo, key=key, log_dir=log_dir)
+            actor = _actor_ppo if flavor == "ppo" else _actor_sac
+            actor(fabric, cfg, topo, key=key, log_dir=log_dir)
         finally:
             HUB.set_namespace(None)
     finally:
@@ -214,48 +194,9 @@ def _finish_learner(
         traj_queue.close()
 
 
-def _pod_run_stats(
-    *,
-    topo: PodTopology,
-    updates: int,
-    wall_s: float,
-    env_steps: int,
-    traj_queue: TrajQueue,
-    broadcast: DcnParamBroadcast,
-    front: LearnerFront,
-    traj_staleness_max: int,
-    traj_staleness_sum: int,
-    segments_consumed: int,
-) -> Dict[str, Any]:
-    """The ``bench.py --mode dcn`` stats contract: Sebulba's throughput
-    block plus the DCN counters and the zero-drop ledger (segments the
-    queue accepted vs segments the transport delivered)."""
-    return {
-        "phase_breakdown": SPANS.breakdown(),
-        "topology": topo.describe(),
-        "updates": int(updates),
-        "wall_s": wall_s,
-        "env_steps": int(env_steps),
-        "env_steps_per_s": env_steps / max(wall_s, 1e-9),
-        "updates_per_s": updates / max(wall_s, 1e-9),
-        "queue_depth_frac": float(traj_queue.metrics()["Sebulba/queue_depth_frac"]),
-        "param_staleness_max": int(broadcast.staleness_max),
-        "traj_staleness_max": int(traj_staleness_max),
-        "traj_staleness_avg": traj_staleness_sum / max(segments_consumed, 1),
-        "segments_consumed": int(segments_consumed),
-        "torn_rejected": int(traj_queue.torn_rejected + front.segments_rejected),
-        "dcn": {k: float(v) for k, v in front.metrics().items()},
-        "zero_drop": {
-            "queue_total_put": int(traj_queue.total_put),
-            "segments_accepted": int(front.segments_accepted),
-            "segments_rejected": int(front.segments_rejected),
-        },
-    }
-
-
 def _learner_ppo(
     fabric: Any, cfg: Any, topo: PodTopology, *, key: Any, log_dir: str, logger: Any
-) -> Dict[str, Any]:
+) -> None:
     """The decoupled-PPO learner cell: ``sebulba/ppo.py``'s learner half
     with the local actor fleet replaced by the DCN front."""
     from sheeprl_tpu.algos.ppo.agent import build_agent
@@ -358,10 +299,7 @@ def _learner_ppo(
     staleness_sum = 0
     staleness_max = 0
     segments_consumed = 0
-    env_steps_consumed = 0
-    updates_done = 0
     last_losses = None
-    t_start = time.perf_counter()
 
     HUB.register("sebulba.traj_queue", traj_queue.metrics)
     HUB.register("dcn.front", front.metrics)
@@ -405,10 +343,8 @@ def _learner_ppo(
                 lag = broadcast.version - int(meta.get("version", 0))
                 staleness_sum += lag
                 staleness_max = max(staleness_max, lag)
-                env_steps_consumed += int(meta.get("env_steps", 0))
             segments_consumed += len(items)
             policy_step += policy_steps_per_iter
-            updates_done += 1
 
             with timer("Time/train_time"):
                 key, tk = jax.random.split(key)
@@ -467,26 +403,16 @@ def _learner_ppo(
         HUB.unregister("dcn.front")
         _finish_learner(fabric, ckpt_mgr, front, traj_queue)
 
-    run_stats = _pod_run_stats(
-        topo=topo, updates=updates_done,
-        wall_s=time.perf_counter() - t_start, env_steps=env_steps_consumed,
-        traj_queue=traj_queue, broadcast=broadcast, front=front,
-        traj_staleness_max=staleness_max, traj_staleness_sum=staleness_sum,
-        segments_consumed=segments_consumed,
-    )
-    fabric.print(POD_STATS_MARKER + json.dumps(_jsonable(run_stats)))
-
     ckpt_mgr.finalize()
     if cfg.algo.run_test and not ckpt_mgr.preempted:
         test(agent, fabric.to_host(params), cfg, log_dir, logger)
     if logger is not None:
         logger.close()
-    return run_stats
 
 
 def _learner_sac(
     fabric: Any, cfg: Any, topo: PodTopology, *, key: Any, log_dir: str, logger: Any
-) -> Dict[str, Any]:
+) -> None:
     """The decoupled-SAC learner cell: ``sebulba/sac.py``'s learner half
     (host replay + the ``Ratio``-owed gradient steps) fed by the front.
     Only the actor subtree crosses the DCN, as in-process."""
@@ -588,9 +514,7 @@ def _learner_sac(
     staleness_sum = 0
     staleness_max = 0
     segments_consumed = 0
-    env_steps_consumed = 0
     last_losses = None
-    t_start = time.perf_counter()
 
     HUB.register("sebulba.traj_queue", traj_queue.metrics)
     HUB.register("dcn.front", front.metrics)
@@ -639,7 +563,6 @@ def _learner_sac(
                 lag = broadcast.version - int(meta.get("version", 0))
                 staleness_sum += lag
                 staleness_max = max(staleness_max, lag)
-                env_steps_consumed += int(meta.get("env_steps", 0))
             segments_consumed += len(items)
             policy_step += steps_per_round
 
@@ -696,21 +619,11 @@ def _learner_sac(
         HUB.unregister("dcn.front")
         _finish_learner(fabric, ckpt_mgr, front, traj_queue)
 
-    run_stats = _pod_run_stats(
-        topo=topo, updates=windows,
-        wall_s=time.perf_counter() - t_start, env_steps=env_steps_consumed,
-        traj_queue=traj_queue, broadcast=broadcast, front=front,
-        traj_staleness_max=staleness_max, traj_staleness_sum=staleness_sum,
-        segments_consumed=segments_consumed,
-    )
-    fabric.print(POD_STATS_MARKER + json.dumps(_jsonable(run_stats)))
-
     ckpt_mgr.finalize()
     if cfg.algo.run_test and not ckpt_mgr.preempted:
         test(actor, fabric.to_host(params["actor"]), cfg, log_dir, logger)
     if logger is not None:
         logger.close()
-    return run_stats
 
 
 # ---------------------------------------------------------------------------
@@ -718,7 +631,7 @@ def _learner_sac(
 # ---------------------------------------------------------------------------
 
 
-def _actor_ppo(fabric: Any, cfg: Any, topo: PodTopology, *, key: Any, log_dir: str) -> Dict[str, Any]:
+def _actor_ppo(fabric: Any, cfg: Any, topo: PodTopology, *, key: Any, log_dir: str) -> None:
     from sheeprl_tpu.algos.ppo.agent import build_agent, sample_actions
     from sheeprl_tpu.algos.ppo.utils import normalize_obs_keys, spaces_to_dims
     from sheeprl_tpu.sebulba.ppo import PPOWorkerProtocol
@@ -751,7 +664,7 @@ def _actor_ppo(fabric: Any, cfg: Any, topo: PodTopology, *, key: Any, log_dir: s
         {k: np.zeros((1,) + tuple(obs_space[k].shape), obs_space[k].dtype) for k in obs_keys}
     )
     obs_spec = {k: (tuple(v.shape[1:]), v.dtype) for k, v in probe_prep.items()}
-    return _drive_actor_cell(
+    _drive_actor_cell(
         fabric, cfg, topo,
         key=key, log_dir=log_dir,
         protocol=protocol, policy_fn=policy_fn, obs_spec=obs_spec,
@@ -760,7 +673,7 @@ def _actor_ppo(fabric: Any, cfg: Any, topo: PodTopology, *, key: Any, log_dir: s
     )
 
 
-def _actor_sac(fabric: Any, cfg: Any, topo: PodTopology, *, key: Any, log_dir: str) -> Dict[str, Any]:
+def _actor_sac(fabric: Any, cfg: Any, topo: PodTopology, *, key: Any, log_dir: str) -> None:
     import gymnasium as gym
 
     from sheeprl_tpu.algos.sac.agent import build_agent
@@ -800,7 +713,7 @@ def _actor_sac(fabric: Any, cfg: Any, topo: PodTopology, *, key: Any, log_dir: s
     protocol = SACWorkerProtocol(
         mlp_keys, act_space, prefill_steps=-(-learning_starts // global_workers)
     )
-    return _drive_actor_cell(
+    _drive_actor_cell(
         fabric, cfg, topo,
         key=key, log_dir=log_dir,
         protocol=protocol, policy_fn=policy_fn,
@@ -822,7 +735,7 @@ def _drive_actor_cell(
     obs_spec: Dict[str, Any],
     segment_steps: int,
     bootstrap_keys: Tuple[str, ...],
-) -> Dict[str, Any]:
+) -> None:
     """The algorithm-agnostic actor cell: local inference engines + env
     workers into a host-side queue; a pusher thread ships segments over
     the DCN; the main thread runs the ``/poll`` control loop (param
@@ -943,9 +856,7 @@ def _drive_actor_cell(
     arm_preemption(cfg)
     poll_interval = float(pod.get("poll_interval_s", 0.5))
     last_shard = -1
-    shards_written = 0
     reason = "done"
-    t_start = time.perf_counter()
     pusher = threading.Thread(target=_pusher, name="dcn.pusher", daemon=True)
     try:
         for eng in engines:
@@ -985,7 +896,6 @@ def _drive_actor_cell(
                         },
                     )
                     last_shard = commit_step
-                    shards_written += 1
                 if resp.get("done"):
                     break
             if pusher_errors:
@@ -1005,16 +915,3 @@ def _drive_actor_cell(
         shutdown(stop_event, local_queue, obs_queue, engines, supervisor)
         pusher.join(timeout=5.0)
         client.goodbye(reason)
-
-    return {
-        "topology": topo.describe(),
-        "role": "actor",
-        "cell": cell,
-        "wall_s": time.perf_counter() - t_start,
-        "segments_pushed": int(client.segments_pushed),
-        "push_retries": int(client.push_retries),
-        "param_fetches": int(client.fetches),
-        "applied_version": int(applied),
-        "shards_written": int(shards_written),
-        "worker_restarts": supervisor.restarts,
-    }

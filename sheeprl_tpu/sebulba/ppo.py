@@ -29,7 +29,6 @@ from __future__ import annotations
 
 import os
 import threading
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -135,14 +134,15 @@ class PPOWorkerProtocol:
 
 
 def run_sebulba(fabric: Any, cfg: Any) -> Dict[str, Any]:
-    """Train decoupled PPO through the Sebulba topology.  Returns a stats
-    dict (throughput/queue/staleness counters) for ``bench.py``."""
+    """Train decoupled PPO through the Sebulba topology.  Returns the run's
+    counters (``runner.collect_run_stats``)."""
     if fabric.num_processes > 1:
         # multi-process runs split actors and learner across HOSTS, not
         # devices: the in-process topology below assumes one device view
         from sheeprl_tpu.sebulba.pod import run_pod
 
-        return run_pod(fabric, cfg)
+        run_pod(fabric, cfg)
+        return {}
     from sheeprl_tpu.envs.jax.registry import is_jax_native
 
     topo_cfg = topology_cfg(cfg)
@@ -372,12 +372,11 @@ def run_sebulba(fabric: Any, cfg: Any) -> Dict[str, Any]:
     env_steps_consumed = 0
     updates_done = 0
     last_losses = None
-    t_start = time.perf_counter()
 
     # ---------------- run ----------------------------------------------------
     # queue/broadcast counters become live hub sources for the duration of
     # the run (scrapeable via /metrics mid-run, not just at log intervals);
-    # a fresh span window makes the end-of-run phase breakdown cover the
+    # a fresh span window makes the first flush's phase breakdown cover the
     # training loop, not agent construction/compilation
     from sheeprl_tpu.telemetry import HUB, SPANS
 
@@ -498,11 +497,9 @@ def run_sebulba(fabric: Any, cfg: Any) -> Dict[str, Any]:
         shutdown(stop_event, traj_queue, obs_queue, engines, supervisor)
 
     run_stats = collect_run_stats(
-        topo=topo, updates=updates_done,
-        wall_s=time.perf_counter() - t_start, env_steps=env_steps_consumed,
+        updates=updates_done, env_steps=env_steps_consumed,
         engines=engines, traj_queue=traj_queue, broadcast=broadcast,
-        traj_staleness_max=staleness_max, traj_staleness_sum=staleness_sum,
-        segments_consumed=segments_consumed, supervisor=supervisor,
+        traj_staleness_max=staleness_max, supervisor=supervisor,
     )
 
     ckpt_mgr.finalize()

@@ -14,8 +14,8 @@ device-resident trajectory queue.
   replicated otherwise — the ``data/device_replay.py`` placement, one
   window at a time).  Capacity bounds the HBM the queue may pin; a full
   queue **blocks producers** (backpressure — trajectories are never
-  dropped), and depth is tracked so ``bench.py --mode sebulba`` can report
-  how full the pipe runs.
+  dropped), and depth is tracked (``Sebulba/queue_depth_frac``) so a run
+  reports how full the pipe runs.
 
 Both queues carry the ``sebulba.traj_queue`` / ``sebulba.env_worker``
 fault sites' consequences: a ``truncate`` fault at the trajectory queue

@@ -12,7 +12,7 @@ import numpy as np
 
 from sheeprl_tpu.sebulba.actor import EnvWorker, WorkerSupervisor
 from sheeprl_tpu.sebulba.queues import ObsQueue, ServiceStopped, TrajQueue
-from sheeprl_tpu.telemetry.spans import SPANS, span
+from sheeprl_tpu.telemetry.spans import span
 from sheeprl_tpu.utils.env import make_env, vectorize
 
 
@@ -225,35 +225,24 @@ def shutdown(
 
 def collect_run_stats(
     *,
-    topo: Any,
     updates: int,
-    wall_s: float,
     env_steps: int,
     engines: List[Any],
     traj_queue: TrajQueue,
     broadcast: Any,
     traj_staleness_max: int,
-    traj_staleness_sum: int,
-    segments_consumed: int,
     supervisor: Optional[WorkerSupervisor],
 ) -> Dict[str, Any]:
-    """The ``bench.py --mode sebulba`` stats contract, assembled once."""
+    """What ``run_sebulba`` returns: the run's counters, which
+    ``tests/test_algos/test_sebulba.py`` holds the topology to (nothing
+    torn or lost, staleness bounded, one executable per actor shape)."""
     return {
-        # the current span window's phase-breakdown fractions (queue.wait /
-        # rollout / update.dispatch / param.broadcast / other, summing to
-        # ~1.0) — bench.py republishes this as its `phase_breakdown` block
-        "phase_breakdown": SPANS.breakdown(),
-        "topology": topo.describe(),
         "updates": int(updates),
-        "wall_s": wall_s,
         "env_steps": int(env_steps),
-        "env_steps_per_s": env_steps / max(wall_s, 1e-9),
-        "updates_per_s": updates / max(wall_s, 1e-9),
         "actor_idle_frac": float(np.mean([eng.actor_idle_frac() for eng in engines])),
         "queue_depth_frac": float(traj_queue.metrics()["Sebulba/queue_depth_frac"]),
         "param_staleness_max": int(broadcast.staleness_max),
         "traj_staleness_max": int(traj_staleness_max),
-        "traj_staleness_avg": traj_staleness_sum / max(segments_consumed, 1),
         "actor_cache_sizes": [eng.cache_sizes() for eng in engines],
         "worker_restarts": supervisor.restarts if supervisor is not None else 0,
         "torn_rejected": traj_queue.torn_rejected,

@@ -28,7 +28,6 @@ from __future__ import annotations
 
 import os
 import threading
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import gymnasium as gym
@@ -141,14 +140,15 @@ class SACWorkerProtocol:
 
 
 def run_sebulba(fabric: Any, cfg: Any) -> Dict[str, Any]:
-    """Train decoupled SAC through the Sebulba topology.  Returns a stats
-    dict (throughput/queue/staleness counters) for ``bench.py``."""
+    """Train decoupled SAC through the Sebulba topology.  Returns the run's
+    counters (``runner.collect_run_stats``)."""
     if fabric.num_processes > 1:
         # multi-process runs split actors and learner across HOSTS, not
         # devices: the in-process topology below assumes one device view
         from sheeprl_tpu.sebulba.pod import run_pod
 
-        return run_pod(fabric, cfg)
+        run_pod(fabric, cfg)
+        return {}
     topo_cfg = topology_cfg(cfg)
     topo = DeviceTopology.from_config(fabric, cfg)
     learner_fab = topo.learner_fabric
@@ -339,10 +339,9 @@ def run_sebulba(fabric: Any, cfg: Any) -> Dict[str, Any]:
     env_steps_consumed = 0
     last_losses = None
     counter_dev = None
-    t_start = time.perf_counter()
 
     # ---------------- run ----------------------------------------------------
-    # live hub sources for the run + a fresh span window so the end-of-run
+    # live hub sources for the run + a fresh span window so the first flush's
     # phase breakdown covers the training loop (see sebulba/ppo.py)
     from sheeprl_tpu.telemetry import HUB, SPANS
 
@@ -480,11 +479,9 @@ def run_sebulba(fabric: Any, cfg: Any) -> Dict[str, Any]:
         shutdown(stop_event, traj_queue, obs_queue, engines, supervisor)
 
     run_stats = collect_run_stats(
-        topo=topo, updates=windows,
-        wall_s=time.perf_counter() - t_start, env_steps=env_steps_consumed,
+        updates=windows, env_steps=env_steps_consumed,
         engines=engines, traj_queue=traj_queue, broadcast=broadcast,
-        traj_staleness_max=staleness_max, traj_staleness_sum=staleness_sum,
-        segments_consumed=segments_consumed, supervisor=supervisor,
+        traj_staleness_max=staleness_max, supervisor=supervisor,
     )
 
     if getattr(rb, "spill", None) is not None:

@@ -261,7 +261,7 @@ class FleetRouter:
 
     def wait_healthy(self, min_replicas: int = 1, timeout: float = 120.0) -> bool:
         """Block until ``min_replicas`` replicas are routable (startup
-        barrier for the CLI/bench/tests)."""
+        barrier for the CLI and the tests)."""
         deadline = time.monotonic() + float(timeout)
         while time.monotonic() < deadline:
             if sum(1 for r in self.replica_list() if r.routable) >= min_replicas:

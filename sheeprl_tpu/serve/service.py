@@ -12,8 +12,8 @@
   a new ``COMMIT`` without dropping in-flight requests,
 * per-session latent carries for stateful players (dreamer_v3).
 
-Used directly by ``bench.py --mode serve`` and the tests, and wrapped by
-``serve.server`` for the HTTP surface.
+Used directly by the tests (``tests/test_serve/``, ``tests/serve_smoke.py``)
+and wrapped by ``serve.server`` for the HTTP surface.
 """
 
 from __future__ import annotations

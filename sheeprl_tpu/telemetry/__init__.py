@@ -89,7 +89,7 @@ def setup_run(cfg: Any, log_dir: Optional[str], rank: int = 0) -> None:
             port=int(port),
             stall_after_s=float(tcfg.get("stall_after_s", 600.0) or 0.0),
         ).start()
-    # flush: harnesses (run_ci stage 12) parse this line off a pipe while
+    # flush: harnesses (run_ci stage 11) parse this line off a pipe while
     # the run itself may not print again for minutes
     print(f"telemetry introspection on {_SERVER.url}", flush=True)
 

@@ -2,7 +2,7 @@
 
 Before this subsystem, run telemetry was scattered across three ad-hoc
 process-global monitors (``COMPILE_MONITOR`` / ``CHECKPOINT_MONITOR`` /
-``RESILIENCE_MONITOR``), Sebulba's private stats sink and one-off bench
+``RESILIENCE_MONITOR``), Sebulba's private stats sink and one-off
 counters — each with its own read path, none of them reachable from an
 exception exit.  :class:`TelemetryHub` absorbs them all behind a single
 contract:

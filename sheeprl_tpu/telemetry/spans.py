@@ -47,9 +47,9 @@ Span taxonomy (docs/telemetry.md has the table with counts and parents):
 * ``ckpt.snapshot``    — checkpoint serialize+write (writer thread; its
   ``parent`` and ``iteration`` are those of the ``ckpt.save`` that queued it)
 * ``pipeline.stage.<name>.fwd`` / ``.bwd`` — per-stage forward/backward
-  wall time of the pipelined world-model update, measured by
-  ``bench.py --mode pipeline``'s standalone stage programs
-  (``parallel/pipeline.py compile_stage_pair``); inside the fused train
+  wall time of the pipelined world-model update, opened by the
+  standalone stage programs of ``parallel/pipeline.py
+  compile_stage_pair`` (no caller in the tree: ROADMAP D5); inside the fused train
   phase the stages appear as ``pipeline.<name>`` ``named_scope``s in
   device traces instead (one dispatch = one ``update.dispatch`` span).
   The derived first-class metric is ``Pipeline/bubble_frac`` — the

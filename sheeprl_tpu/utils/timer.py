@@ -12,8 +12,8 @@ blocks (on a single-stream host that is usually the env phase's next
 device call).  ``metric.sync_timers=True`` (``timer.sync``) makes every
 timed phase drain the device at entry and exit, so phase times are
 attributable at the cost of losing host/device overlap — totals stay the
-same on a single-stream host, only the split moves.  bench captures turn
-it on; leave it off for throughput runs.
+same on a single-stream host, only the split moves.  Leave it off for
+throughput runs (the benchmark, ``chipbench/``, fences its own probes).
 """
 
 from __future__ import annotations
@@ -63,7 +63,7 @@ class timer(ContextDecorator):
         # loop already wraps ARE the rollout / update.dispatch phases — one
         # mapping here wires all 12 loops.  Independent of `disabled`: spans
         # (and the tracer tick stream they drive) stay live at
-        # metric.log_level=0, which is how bench runs get phase breakdowns.
+        # metric.log_level=0, so a run that logs nothing still records them.
         phase = TIMER_PHASES.get(self.name)
         self._span = SPANS.push(phase) if phase is not None else None
         self._start = time.perf_counter()

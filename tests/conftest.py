@@ -16,6 +16,15 @@ _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (_flags + " --xla_force_host_platform_device_count=8").strip()
 
+# XLA:CPU runs a program's independent collectives concurrently, each holding a
+# worker of its intra-op pool until every participant has arrived, and sizes
+# that pool from NPROC (else one worker per core).  8 virtual devices at three
+# such collectives need up to 24 workers: with 8, on a loaded host, the waiting
+# ones hold them all, the rest never get one, and the rendezvous aborts the
+# process after 40 s (an xdist "node down": tier-1's one red from PR 27 to
+# PR 31, the pipelined DV3 phase of tests/test_parallel/test_pipeline.py).
+os.environ.setdefault("NPROC", "32")
+
 import pytest  # noqa: E402
 
 

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""run_ci stage 16: fault-tolerant serving-fleet drill.
+"""run_ci stage 15: fault-tolerant serving-fleet drill.
 
 A tiny committed PPO snapshot is served by a REAL 2-replica fleet
 (``LocalFleet`` spawning ``python -m sheeprl_tpu.serve`` twice) behind a

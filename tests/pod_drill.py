@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""run_ci stage 17: pod-scale fault-tolerance drill (multi-controller).
+"""run_ci stage 16: pod-scale fault-tolerance drill (multi-controller).
 
 A short decoupled-PPO run is driven as a REAL 2-process pod — the fake-DCN
 protocol spawns a learner cell (rank 0) and an actor cell (rank 1), with

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""run_ci stage 18: the PBT-beats-fixed-hyperparams drill (ISSUE 20).
+"""run_ci stage 17: the PBT-beats-fixed-hyperparams drill (ISSUE 20).
 
 Two seeded population=4 CartPole PPO runs at EQUAL env steps through the
 real CLI, differing in exactly one knob:

@@ -1,4 +1,4 @@
-"""CI smoke for the policy server CLI (run_ci.sh stage 6).
+"""CI smoke for the policy server CLI (run_ci.sh stage 7).
 
 Trains a tiny committed dryrun checkpoint, launches the REAL
 ``python -m sheeprl_tpu.serve`` process on an ephemeral port, streams a

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""run_ci stage 13: self-healing supervisor drill.
+"""run_ci stage 12: self-healing supervisor drill.
 
 A short SAC training run is supervised end-to-end across a REAL process
 boundary (``sheeprl_tpu.supervisor`` spawning ``python -m sheeprl_tpu``):

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""run_ci stage 12: live-introspection + postmortem drill.
+"""run_ci stage 11: live-introspection + postmortem drill.
 
 Launches a short dreamer_v3 training run as a SUBPROCESS with
 ``telemetry.introspect.port=0`` armed and a seeded ``env.step`` raise

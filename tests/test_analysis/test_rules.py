@@ -153,7 +153,7 @@ class TestPipelineStageDonation:
     inter-stage activation buffer (arg 1) and the incoming cotangent (arg 2)
     — donate a stage-N output, read it again for the 1F1B backward, and the
     buffer is gone.  Curated-table entry 'compile_stage_pair@1' makes the
-    cross-module call sites (bench.py) visible to the flow scan."""
+    cross-module call sites visible to the flow scan."""
 
     def test_violating_activation_read_after_backward(self):
         code = """

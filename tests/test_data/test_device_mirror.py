@@ -10,7 +10,7 @@ compat test per shim pins that they still honor the old contract (and
 warn).  The old ``device_mirror`` True/False e2e equivalence runs became
 vacuous when the loops stopped reading ``buffer.device_mirror`` — the live
 e2e coverage of the device-resident dataflow is
-``tests/test_data/test_device_replay_e2e.py`` and run_ci stage 9.
+``tests/test_data/test_device_replay_e2e.py`` and run_ci stage 8.
 """
 
 import numpy as np
