@@ -35,6 +35,7 @@ from sheeprl_tpu.algos.ppo.utils import (
 )
 from sheeprl_tpu.data.buffers import ReplayBuffer
 from sheeprl_tpu.data.device_replay import stage_rollout, steady_guard
+from sheeprl_tpu.envs.jax.anakin import read_obs_fn
 from sheeprl_tpu.envs.jax.registry import anakin_enabled
 from sheeprl_tpu.utils.env import episode_stats, final_obs_rows, make_env, vectorize
 from sheeprl_tpu.utils.logger import get_log_dir, get_logger
@@ -92,6 +93,7 @@ def main(fabric: Any, cfg: Any) -> None:
     cnn_keys = tuple(cfg.algo.cnn_keys.encoder)
     mlp_keys = tuple(cfg.algo.mlp_keys.encoder)
     obs_keys = cnn_keys + mlp_keys
+    read_obs = read_obs_fn(cnn_keys, obs_space)
     dist_type = cfg.get("distribution", {}).get("type", "auto")
 
     state: Dict[str, Any] = {}
@@ -160,7 +162,9 @@ def main(fabric: Any, cfg: Any) -> None:
         bakes in the static config value."""
         e_coef = ent_coef if traced_ent_coef is None else traced_ent_coef
         T, B = rollout["rewards"].shape
-        flat_obs = {k: rollout[k].reshape((T * B,) + rollout[k].shape[2:]) for k in obs_keys}
+        # a fused rollout's uint8 pixel leaves back to float frames; a rollout
+        # staged from the host passes through (envs/jax/anakin.py)
+        flat_obs = read_obs({k: rollout[k].reshape((T * B,) + rollout[k].shape[2:]) for k in obs_keys})
         _, values0 = agent.apply(p, flat_obs)
         values0 = values0[..., 0].reshape(T, B)
         next_value = values_fn(p, last_obs)

@@ -48,6 +48,7 @@ from sheeprl_tpu.algos.ppo.utils import (
 )
 from sheeprl_tpu.data.buffers import ReplayBuffer
 from sheeprl_tpu.data.device_replay import stage_rollout, stage_scalar, steady_guard
+from sheeprl_tpu.envs.jax.anakin import read_obs_fn
 from sheeprl_tpu.envs.jax.registry import anakin_enabled
 from sheeprl_tpu.telemetry.spans import SPANS
 from sheeprl_tpu.utils.env import episode_stats, final_obs_rows, make_env, vectorize
@@ -147,6 +148,9 @@ def main(fabric: Any, cfg: Any) -> None:
     cnn_keys = tuple(cfg.algo.cnn_keys.encoder)
     mlp_keys = tuple(cfg.algo.mlp_keys.encoder)
     obs_keys = cnn_keys + mlp_keys
+    # a fused rollout's uint8 pixel leaves back to float frames; a rollout
+    # staged from the host passes through (envs/jax/anakin.py)
+    read_obs = read_obs_fn(cnn_keys, obs_space)
     dist_type = cfg.get("distribution", {}).get("type", "auto")
 
     # ---------------- agent / optimizer -------------------------------------
@@ -254,7 +258,7 @@ def main(fabric: Any, cfg: Any) -> None:
         T, B = rollout["rewards"].shape
         flat_obs = {key_: rollout[key_].reshape((T * B,) + rollout[key_].shape[2:]) for key_ in obs_keys}
         with jax.named_scope("gae"):  # the value pass over the whole rollout included
-            _, values = agent.apply(p, flat_obs)
+            _, values = agent.apply(p, read_obs(flat_obs))
             values = values[..., 0].reshape(T, B)
             next_value = values_fn(p, last_obs)
             returns, advantages = gae(
@@ -283,7 +287,7 @@ def main(fabric: Any, cfg: Any) -> None:
                 p, o_state, losses = carry2
                 with jax.named_scope("update.gather"):
                     idx = jax.lax.dynamic_slice(perm, (i * batch_size,), (batch_size,))
-                    batch = {kk: jnp.take(vv, idx, axis=0) for kk, vv in flat.items()}
+                    batch = read_obs({kk: jnp.take(vv, idx, axis=0) for kk, vv in flat.items()})
                 (_, (pg, vl, ent)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                     p, batch, clip_coef, ent_coef
                 )

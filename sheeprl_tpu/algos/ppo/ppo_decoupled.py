@@ -44,6 +44,7 @@ from sheeprl_tpu.algos.ppo.utils import (
     test,
 )
 from sheeprl_tpu.data.buffers import ReplayBuffer
+from sheeprl_tpu.envs.jax.anakin import read_obs_fn
 from sheeprl_tpu.parallel.compile import compile_once
 from sheeprl_tpu.utils.env import episode_stats, final_obs_rows, make_env, vectorize
 from sheeprl_tpu.utils.logger import get_log_dir, get_logger
@@ -55,10 +56,13 @@ from sheeprl_tpu.utils.timer import timer
 from sheeprl_tpu.utils.utils import gae, save_configs, should_unroll_updates, window_scan
 
 
-def _build_train_fns(agent, optimizer, cfg, obs_keys, actions_dim, is_continuous, dist_type):
+def _build_train_fns(agent, optimizer, cfg, obs_keys, actions_dim, is_continuous, dist_type, obs_space):
     """The jitted policy/value/train-phase programs shared by the pipelined
     (single-controller) and dedicated (cross-process) decoupled topologies."""
     cnn_keys = tuple(cfg.algo.cnn_keys.encoder)
+    # a fused actor's uint8 pixel leaves (sebulba's jax-native actors) back to
+    # float frames; a rollout staged from the host passes through
+    read_obs = read_obs_fn(cnn_keys, obs_space)
     reduction = cfg.algo.loss_reduction
     clip_vloss = bool(cfg.algo.clip_vloss)
     normalize_adv = bool(cfg.algo.normalize_advantages)
@@ -101,7 +105,7 @@ def _build_train_fns(agent, optimizer, cfg, obs_keys, actions_dim, is_continuous
     def train_phase(p, o_state, rollout, last_obs, k, clip_coef, ent_coef, batch_size, num_minibatches):
         T, B = rollout["rewards"].shape
         flat_obs = {kk: rollout[kk].reshape((T * B,) + rollout[kk].shape[2:]) for kk in obs_keys}
-        _, values = agent.apply(p, flat_obs)
+        _, values = agent.apply(p, read_obs(flat_obs))
         values = values[..., 0].reshape(T, B)
         next_value = values_fn(p, last_obs)
         returns, advantages = gae(rollout["rewards"], values, rollout["dones"], next_value, gamma, gae_lambda)
@@ -125,7 +129,7 @@ def _build_train_fns(agent, optimizer, cfg, obs_keys, actions_dim, is_continuous
             def mb_body(i, carry2):
                 p, o_state, _ = carry2
                 idx = jax.lax.dynamic_slice(perm, (i * batch_size,), (batch_size,))
-                batch = {kk: jnp.take(vv, idx, axis=0) for kk, vv in flat.items()}
+                batch = read_obs({kk: jnp.take(vv, idx, axis=0) for kk, vv in flat.items()})
                 (_, (pg, vl, ent)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                     p, batch, clip_coef, ent_coef
                 )
@@ -319,7 +323,7 @@ def main(fabric: Any, cfg: Any) -> None:
     host = fabric.player_device(cfg)
     gamma = float(cfg.algo.gamma)
     policy_step_fn, values_fn, train_phase, _ = _build_train_fns(
-        agent, optimizer, cfg, obs_keys, actions_dim, is_continuous, dist_type
+        agent, optimizer, cfg, obs_keys, actions_dim, is_continuous, dist_type, obs_space
     )
 
     rollout_steps = int(cfg.algo.rollout_steps)
@@ -549,7 +553,7 @@ def _dedicated_main(fabric: Any, cfg: Any) -> None:
         opt_state = trainer_fabric.replicate(state.get("opt_state") or optimizer.init(params))
 
     policy_step_fn, values_fn, train_phase, _ = _build_train_fns(
-        agent, optimizer, cfg, obs_keys, actions_dim, is_continuous, dist_type
+        agent, optimizer, cfg, obs_keys, actions_dim, is_continuous, dist_type, obs_space
     )
 
     aggregator = MetricAggregator(cfg.metric.aggregator.metrics if cfg.metric.log_level > 0 else {})

@@ -30,8 +30,12 @@ The pieces:
 Rollout semantics match the host loops: SAME_STEP auto-reset (via
 :class:`~sheeprl_tpu.envs.jax.core.VectorJaxEnv`), truncation bootstrap
 ``r += γ·V(final_obs)`` on truncated rows with the current params, dones =
-terminated | truncated, observations stored pre-normalized (uint8 images →
-float32/255) in the layout the train phases already consume.
+terminated | truncated.  Vector observations are stored as the policy read
+them (float32); a ``uint8`` pixel leaf is stored as the env gave it, its
+feature flattened to one lane-dense axis (``u8[T, B, F_pad]``, the replay
+ring's rule: ``data/device_replay.stored_feature``), and the train phases
+turn what they gather of it back into ``float32 / 255`` frames with
+:func:`read_obs_fn`.
 """
 
 from __future__ import annotations
@@ -43,7 +47,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from sheeprl_tpu.data.device_replay import from_stored, stored_feature
 from sheeprl_tpu.envs.jax.core import VectorJaxEnv
+from sheeprl_tpu.telemetry.recorder import RECORDER
 
 
 def traced_polynomial_decay(
@@ -55,6 +61,12 @@ def traced_polynomial_decay(
     return jnp.float32((initial - final)) * frac + jnp.float32(final)
 
 
+def _pixels_to_float(x: jax.Array) -> jax.Array:
+    """THE pixel normalization: :func:`prep_obs_fn` applies it before the
+    policy, :func:`read_obs_fn` after the gather, to the same bytes."""
+    return x.astype(jnp.float32) / 255.0
+
+
 def prep_obs_fn(cnn_keys: Sequence[str], mlp_keys: Sequence[str]) -> Callable:
     """Device-side observation normalization: the traced twin of
     ``ppo.utils.obs_to_np`` (uint8 images → float32/255, vectors →
@@ -63,12 +75,43 @@ def prep_obs_fn(cnn_keys: Sequence[str], mlp_keys: Sequence[str]) -> Callable:
     def prep(obs: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
         out = {}
         for k in cnn_keys:
-            out[k] = obs[k].astype(jnp.float32) / 255.0
+            out[k] = _pixels_to_float(obs[k])
         for k in mlp_keys:
             out[k] = obs[k].astype(jnp.float32)
         return out
 
     return prep
+
+
+def _stored_pixel_feats(cnn_keys: Sequence[str], obs_space: gym.spaces.Dict) -> Dict[str, Tuple[int, ...]]:
+    """The leaves the fused rollout stores as the env's bytes: ``uint8``
+    leaves of ``cnn_keys`` whose feature has more than one axis, each with
+    that feature's shape."""
+    return {
+        k: tuple(obs_space[k].shape)
+        for k in cnn_keys
+        if obs_space[k].dtype == np.uint8 and len(obs_space[k].shape) > 1
+    }
+
+
+def read_obs_fn(cnn_keys: Sequence[str], obs_space: gym.spaces.Dict) -> Callable:
+    """What turns a rollout's observation leaves into what ``agent.apply``
+    takes, on any leading axes: a ``uint8`` leaf of a pixel key is the fused
+    rollout's stored form (``(..., F_pad)``: sliced to the feature, reshaped,
+    ``float32 / 255``, the same float ``prep_obs_fn`` makes of the same
+    byte); every other leaf (vectors; a rollout staged from the host by
+    ``obs_to_np``, float32 and normalized already) passes through as the
+    same array.  Call it on what was gathered, never on the pool before the
+    gather."""
+    feats = _stored_pixel_feats(cnn_keys, obs_space)
+
+    def read(obs: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
+        return {
+            k: _pixels_to_float(from_stored(v, feats[k])) if k in feats and v.dtype == jnp.uint8 else v
+            for k, v in obs.items()
+        }
+
+    return read
 
 
 def env_actions_fn(action_space: gym.Space) -> Callable:
@@ -132,15 +175,43 @@ def make_rollout_fn(
 ) -> Callable:
     """Build ``rollout(p, actor, key) -> (actor', rollout, last_obs, stats)``.
 
-    ``rollout`` leaves are ``(T, B, *feat)`` in the exact layout the
-    on-policy train phases consume (obs pre-normalized, actions in storage
-    float layout, rewards truncation-bootstrapped, dones float).  ``stats``
+    ``rollout`` leaves are ``(T, B, *feat)`` as the on-policy train phases
+    take them (vector obs float32, actions in storage float layout, rewards
+    truncation-bootstrapped, dones float), but for ``uint8`` pixel leaves:
+    those are ``u8[T, B, F_pad]``, the env's bytes with the feature flattened
+    and zero-padded to whole lanes, and the train phases read what they
+    gather of them through :func:`read_obs_fn`.  A float frame with a last
+    axis of 3 costs four times the bytes and, on a TPU, a relayout of the
+    whole pool at the scan's stacking and again at the minibatch gather.
+    Each such leaf records one ``rollout.store`` event here.  ``stats``
     carries per-step ``(T, B)`` episode-completion arrays — small, pulled
     D2H by the loop for logging (legal under the H2D-scoped guard).
     """
     prep = prep_obs_fn(cnn_keys, mlp_keys)
     to_env = env_actions_fn(action_space)
     obs_keys = tuple(cnn_keys) + tuple(mlp_keys)
+    feats = _stored_pixel_feats(cnn_keys, venv.single_observation_space)
+    stored = {k: stored_feature(feat)[0] for k, feat in feats.items()}
+    for k, f_pad in stored.items():
+        RECORDER.record(
+            "rollout.store",
+            key=k,
+            feature=feats[k],
+            stored=(rollout_steps, venv.num_envs, f_pad),
+            dtype="uint8",
+            bytes=rollout_steps * venv.num_envs * f_pad,
+        )
+
+    def store(obs: Dict[str, jax.Array], pobs: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
+        """One step's observation leaves as the scan stacks them: the env's
+        bytes for a stored pixel leaf (flattened here, on the uint8 frame,
+        where it is a quarter of the float one), the policy's input else."""
+        out = {k: pobs[k] for k in obs_keys}
+        for k, f_pad in stored.items():
+            flat = obs[k].reshape(obs[k].shape[0], -1)
+            pad = f_pad - flat.shape[-1]
+            out[k] = jnp.pad(flat, ((0, 0), (0, pad))) if pad else flat
+        return out
 
     def rollout(p: Any, actor: Dict[str, Any], key: jax.Array):
         def body(carry, k_step):
@@ -148,7 +219,9 @@ def make_rollout_fn(
             # named scopes (docs/telemetry.md): where a device trace puts
             # the rollout's time — the render, the policy, the env
             with jax.named_scope("rollout.observe"):
-                pobs = prep(venv.observe(env_state))
+                obs = venv.observe(env_state)
+                pobs = prep(obs)
+                step_obs = store(obs, pobs)
             with jax.named_scope("rollout.policy"):
                 out, value = agent_apply(p, pobs)
                 actions, logprob, _ = sample_fn(out, k_step)
@@ -166,7 +239,7 @@ def make_rollout_fn(
             ep_ret = ep_ret + reward
             ep_len = ep_len + 1
             step_out = {
-                **{k: pobs[k] for k in obs_keys},
+                **step_obs,
                 "actions": actions,
                 "logprobs": logprob,
                 "rewards": boot_reward,

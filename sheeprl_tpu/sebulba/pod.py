@@ -238,7 +238,7 @@ def _learner_ppo(
     opt_state = learner_fab.replicate(state.get("opt_state") or optimizer.init(params))
 
     _, _, _, train_phase_raw = _build_train_fns(
-        agent, optimizer, cfg, obs_keys, actions_dim, is_continuous, dist_type
+        agent, optimizer, cfg, obs_keys, actions_dim, is_continuous, dist_type, obs_space
     )
 
     T, B = rollout_steps, num_envs

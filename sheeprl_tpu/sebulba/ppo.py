@@ -205,7 +205,7 @@ def run_sebulba(fabric: Any, cfg: Any) -> Dict[str, Any]:
     opt_state = learner_fab.replicate(state.get("opt_state") or optimizer.init(params))
 
     _, _, _, train_phase_raw = _build_train_fns(
-        agent, optimizer, cfg, obs_keys, actions_dim, is_continuous, dist_type
+        agent, optimizer, cfg, obs_keys, actions_dim, is_continuous, dist_type, obs_space
     )
 
     T, B = rollout_steps, num_envs

@@ -212,3 +212,97 @@ def test_player_refresh_beside_the_train_state_is_a_real_copy_on_v5e(one_chip):
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes == 0 and memory.temp_size_in_bytes == 0
     assert memory.output_size_in_bytes >= tree_bytes(tree)
+
+
+# --------------------------------------------------------------------------
+# the fused rollout's pixel store (envs/jax/anakin.make_rollout_fn): frames
+# stay the env's uint8, lane-dense, and are normalised where they are read
+# --------------------------------------------------------------------------
+
+def _unfused_instructions(text):
+    """``(op, dtype, elements)`` of every array an instruction outside a fusion's own computation
+    produces: what stands in memory between two kernels of the compiled program."""
+    import math
+    import re
+
+    head = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
+    inst = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = (.*?) ([a-z\-]+)\(")
+    array = re.compile(r"\b([a-z]+\d+)\[([\d,]*)\]")
+    fused = True
+    for line in text.splitlines():
+        m = head.match(line)
+        if m:
+            fused = m.group(1).startswith("fused_computation")
+        elif not fused and (m := inst.match(line)):
+            for dtype, dims in array.findall(m.group(1)):
+                yield m.group(2), dtype, math.prod(int(d) for d in dims.split(",") if d)
+
+
+def pixel_rollout_and_update():
+    """``(program, specs)``: ``make_rollout_fn`` on 512 multiroom envs x 128 steps, then what
+    ``ppo.train_phase`` does with the frames: the value pass over the pool and one minibatch of 16,384
+    gathered and differentiated, in bf16.  ``specs(sharding)`` gives the arguments' shapes."""
+    from sheeprl_tpu.algos.ppo.agent import build_agent, sample_actions
+    from sheeprl_tpu.algos.ppo.utils import spaces_to_dims
+    from sheeprl_tpu.config.compose import compose
+    from sheeprl_tpu.envs.jax import anakin
+    from sheeprl_tpu.envs.jax.core import VectorJaxEnv
+    from sheeprl_tpu.envs.jax.multiroom import JaxMultiRoom
+    from sheeprl_tpu.parallel.fabric import Fabric
+
+    T, B, minibatch = 128, 512, 16384
+    cfg = compose([
+        "exp=ppo", "env=jax_multiroom", "algo.cnn_keys.encoder=[rgb]", "algo.mlp_keys.encoder=[]",
+        "algo.dense_units=512", "algo.mlp_layers=1", "algo.encoder.cnn_features_dim=512",
+    ])
+    fabric = Fabric(devices=1, accelerator="cpu", precision="bf16-mixed")
+    venv = VectorJaxEnv(JaxMultiRoom(), B)
+    actions_dim, is_continuous = spaces_to_dims(venv.single_action_space)
+    agent, params = build_agent(fabric, actions_dim, is_continuous, cfg, venv.single_observation_space)
+    read = anakin.read_obs_fn(("rgb",), venv.single_observation_space)
+    rollout_fn = anakin.make_rollout_fn(
+        venv, agent.apply, lambda out, k: sample_actions(out, actions_dim, is_continuous, k),
+        cnn_keys=("rgb",), mlp_keys=(), action_space=venv.single_action_space, gamma=0.99, rollout_steps=T,
+    )
+
+    def program(p, actor, key):
+        k_roll, k_perm = jax.random.split(key)
+        actor, rollout, _, _ = rollout_fn(p, actor, k_roll)
+        pool = rollout["rgb"].reshape((T * B,) + rollout["rgb"].shape[2:])
+        _, values = agent.apply(p, read({"rgb": pool}))
+        idx = jax.random.permutation(k_perm, T * B)[:minibatch]
+
+        def loss(p):
+            out, new_values = agent.apply(p, read({"rgb": jnp.take(pool, idx, axis=0)}))
+            return jnp.mean((new_values - jnp.take(values, idx, axis=0)) ** 2) + jnp.mean(out[0] * rollout["actions"][0, 0, 0])
+
+        return actor, values, jax.grad(loss)(p)
+
+    def specs(sharding):
+        put = lambda x: _spec(sharding, *x.shape, dtype=x.dtype)  # noqa: E731
+        key = jax.random.PRNGKey(0)
+        env_state = jax.eval_shape(lambda k: venv.reset(k)[0], key)
+        actor = {
+            "env": env_state, "ep_ret": jax.ShapeDtypeStruct((B,), jnp.float32),
+            "ep_len": jax.ShapeDtypeStruct((B,), jnp.int32), "update": jax.ShapeDtypeStruct((), jnp.int32),
+        }
+        return jax.tree.map(put, (params, actor, key))
+
+    return program, specs
+
+
+def test_pixel_rollout_stores_uint8_and_nothing_re_lays_a_float_pool_on_v5e(one_chip):
+    """At the Anakin cell's shapes: it compiles; no float32 array of the pool's element count stands
+    between two kernels; no ``copy`` of one step's frames is left in the scan; and the temporaries
+    (4.57 GB, 34.1 GB accessed) are under the float store's: on the parent of PR 33 (commit 2328e5c,
+    the reader an identity) this program is refused, its stacked ``bf16[128,512,64,64,3]`` tiled with
+    the last axis of 3 in the lanes wanting 68.7 GB, and the whole ``ppo.anakin_phase`` took 12.90 GB
+    with 69.4 GB accessed where it now takes 5.92 GB with 35.5 (CHANGES.md, PR 33)."""
+    program, specs = pixel_rollout_and_update()
+    compiled = jax.jit(program).lower(*specs(one_chip)).compile()
+    step, pool = 512 * 12288, 128 * 512 * 12288
+    standing = set(_unfused_instructions(compiled.as_text()))
+    assert ("while", "u8", pool) in standing  # the scan stacks bytes
+    assert not [x for x in standing if x[1] == "f32" and x[2] == pool]
+    assert not [x for x in standing if x[0] == "copy" and x[2] == step]
+    assert 0 < compiled.memory_analysis().temp_size_in_bytes < 8 * 2**30
