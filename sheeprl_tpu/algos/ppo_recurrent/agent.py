@@ -15,6 +15,7 @@ none of the reference's per-episode splitting/padding machinery
 
 from __future__ import annotations
 
+from collections import Counter
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import flax.linen as nn
@@ -24,6 +25,7 @@ import numpy as np
 
 from sheeprl_tpu.models import decoder
 from sheeprl_tpu.models.models import MLP
+from sheeprl_tpu.telemetry.recorder import RECORDER
 
 
 class RecurrentPPOAgent(nn.Module):
@@ -212,8 +214,8 @@ class DecoderPPOAgent:
     """The decoder core (``algo.core: decoder``): a token-level policy over ``models/decoder.py``.
 
     It answers the calls of :class:`LSTMCore` itself.  The observation is the one integer key
-    ``mlp_keys[0]``; the recurrent carry is the decoder's caches and positions; the previous action is not
-    read (the env's observation is the token emitted last)."""
+    ``mlp_keys[0]``; the recurrent carry is the decoder's caches, convolution windows and positions; the
+    previous action is not read (the env's observation is the token emitted last)."""
 
     stores_values = True
     prev_action_width = 1  # the action itself: no one-hot of the vocabulary
@@ -222,13 +224,17 @@ class DecoderPPOAgent:
         if len(mlp_keys) != 1:
             raise ValueError(f"the decoder core reads one integer observation, got mlp_keys={mlp_keys}")
         self.config, self.key, self.dtype = config, mlp_keys[0], dtype
-        self.prefill_chunk = config.sliding_window  # a longer prefill segment would write a ring's slot twice
+        self.carry_dtype = jnp.float32 if dtype == jnp.float32 else jnp.bfloat16
+        self.window = config.sliding_window if config.layers_of(decoder.SLIDING) else None
+        # a longer prefill segment would write a ring's slot twice; a model without a ring has no such bound
+        self.prefill_chunk = self.window or config.max_len
+        self.carry_bytes = decoder.carry_bytes(config, self.carry_dtype)  # an env, by kind of layer
 
     def init(self, rng: jax.Array) -> Dict[str, Any]:
         return {"params": decoder.init_params(self.config, rng)}
 
     def initial_state(self, batch: int) -> Dict[str, Any]:
-        return decoder.init_carry(self.config, batch, jnp.float32 if self.dtype == jnp.float32 else jnp.bfloat16)
+        return decoder.init_carry(self.config, batch, self.carry_dtype)
 
     def policy_step(self, p, carry, obs, prev_actions, is_first):
         carry, logits, value = decoder.step(
@@ -265,26 +271,29 @@ class DecoderPPOAgent:
 
     def rollout_stats(self, rollout, init_carry, venv, actor) -> Dict[str, Any]:
         """The rollout as the caches produced it, with the observation that follows it (what a check against a
-        full forward needs), and how many of its steps lay past the window."""
+        full forward needs), and how many of its steps lay past the window (none where no layer has one)."""
         pos, _ = decoder.segment_positions(rollout["is_first"][..., 0], init_carry["pos"])
+        beyond = jnp.zeros((), jnp.int32) if self.window is None else jnp.sum(pos >= self.window)
         kept = ("actions", "logprobs", "values", "rewards", "dones", "is_first", "mask")
         return {
             **{k: rollout[k] for k in kept},
             "tokens": rollout[self.key], "next_tokens": venv.observe(actor["env"])[self.key],
             "next_is_first": actor["is_first"],
-            "beyond_window": jnp.sum(pos >= self.config.sliding_window), "steps": jnp.asarray(pos.size, jnp.int32),
+            "beyond_window": beyond, "steps": jnp.asarray(pos.size, jnp.int32),
         }
 
     def host_counts(self, stats) -> Dict[str, Any]:
         first, held = self.config.experts_held
         load = np.asarray(stats["load"])[:, first:first + held]  # tokens per held expert of the dispatch's updates
         return {"moe_load_max": load.max(), "moe_load_mean": load.mean(),
-                "beyond_window": np.asarray(stats["beyond_window"]), "steps": np.asarray(stats["steps"])}
+                "beyond_window": np.asarray(stats["beyond_window"]), "steps": np.asarray(stats["steps"]),
+                "carry_bytes": sum(self.carry_bytes.values())}
 
 
 def build_decoder_agent(fabric: Any, cfg: Any, action_space: Any, max_len: int, agent_state: Optional[Any] = None):
     config = decoder.DecoderConfig.from_dict(dict(cfg.algo.decoder), vocab_size=int(action_space.n), max_len=max_len)
     agent = DecoderPPOAgent(config, tuple(cfg.algo.mlp_keys.encoder), fabric.precision.compute_dtype)
+    RECORDER.record("decoder.carry", layers=dict(Counter(config.layer_types)), bytes_per_env=agent.carry_bytes)
     if agent_state is not None:
         return agent, fabric.replicate(agent_state)
     return agent, jax.jit(agent.init, out_shardings=fabric.replicated)(jax.random.PRNGKey(cfg.seed))

@@ -1,13 +1,17 @@
 """A decoder sequence block for token-level policies: pure functions over a parameter dict.
 
 What the other networks of ``models/models.py`` do not have: RMS norm, rotary
-positions, grouped-query attention with a sliding window in some layers and
-full attention in others, a gated feed-forward, and a sparse-expert layer that
-is told which experts it holds (``experts_held``), routes over all of them and
-computes its own experts' part of the result.  The layer equations are those of
-the ``afmoe`` family (Arcee Trinity); ``howto/ppo_tokens.md`` and
-``chipbench/configs/trinity_mini_ep8.json`` say which of them the published
-``config.json`` settles and which are assumed.
+positions, three kinds of sequence mixer (grouped-query attention with a
+sliding window, the same over the whole episode, and a gated short convolution,
+``layer_types``), a gated feed-forward, and a sparse-expert layer that is told
+which experts it holds (``experts_held``), routes over all of them and computes
+its own experts' part of the result.  What stands around a mixer (the norms, the
+output gate, which layers take rotary positions, the embedding multiplier, the
+shared expert) is data of :class:`DecoderConfig`, stated by a yaml of
+``configs/algo/decoder``; its defaults are the ``afmoe`` family's (Arcee
+Trinity), and ``lfm2_24b.yaml`` states the ``lfm2_moe`` family's (LiquidAI).
+``howto/ppo_tokens.md`` and the files of ``chipbench/configs`` say which
+equations a published ``config.json`` settles and which are assumed.
 
 Two entry points serve the recurrent PPO loop, and share every projection:
 
@@ -18,11 +22,14 @@ Two entry points serve the recurrent PPO loop, and share every projection:
   ``extend=True`` it also returns the carry with the segment written into it
   (prefill).
 
-The carry is a pytree, per env: for every layer a buffer of keys and of values
-(a ring of ``sliding_window`` positions for a sliding layer, ``max_len`` for a
-full one; slot = position mod size) and the position of the next token.  A
-reset only zeroes the position: which slots hold keys of the running episode
-follows from the position alone, so nothing has to be cleared.
+The carry is a pytree, per env: for every attention layer a buffer of keys and
+of values (a ring of ``sliding_window`` positions for a sliding layer,
+``max_len`` for a full one; slot = position mod size), for every conv layer the
+gated inputs of the last ``conv_L_cache - 1`` tokens (oldest first), and the
+position of the next token.  A reset only zeroes the position: which slots hold
+keys of the running episode, and which of a convolution's taps reach a token of
+it (tap ``j`` of the token at position ``p`` iff ``p - j >= 0``), follows from
+the position alone, so nothing has to be cleared.
 """
 
 from __future__ import annotations
@@ -37,7 +44,9 @@ import jax.numpy as jnp
 Params = Dict[str, Any]
 Carry = Dict[str, Any]
 
-SLIDING = "sliding_attention"  # every other entry of `layer_types` attends to the whole episode
+SLIDING = "sliding_attention"
+FULL = "full_attention"  # attends to the whole episode
+CONV = "conv"  # a gated short convolution: no keys, a window of gated inputs
 Q_BLOCK = 64  # queries per attention block of a segment: 64 x (prefix + T) x 32 heads of float32 scores at a time
 
 
@@ -48,7 +57,6 @@ class DecoderConfig:
     num_attention_heads: int
     num_key_value_heads: int
     head_dim: int
-    sliding_window: int
     intermediate_size: int  # the dense layers' feed-forward width
     moe_intermediate_size: int  # one expert's width (the shared expert's too)
     num_experts: int  # the router's outputs: ALL experts of the layer
@@ -57,19 +65,30 @@ class DecoderConfig:
     layer_types: Tuple[str, ...]
     num_dense_layers: int  # leading layers with a dense feed-forward
     max_len: int  # positions a full-attention layer caches: the longest episode
-    num_shared_experts: int = 1
+    sliding_window: int = 0  # positions a sliding layer sees; a model without such a layer has none
+    conv_L_cache: int = 3  # taps of a conv layer
+    num_shared_experts: int = 1  # 0: no shared expert beside the routed ones
+    post_norms: bool = True  # a norm on what the mixer and the feed-forward give, before the residual add
+    attn_output_gate: bool = True  # o * sigmoid(a Wg) before the output projection
+    rope_layers: Tuple[str, ...] = (SLIDING,)  # the kinds of attention layer that take rotary positions
     rms_norm_eps: float = 1e-5
     rope_theta: float = 10000.0
     route_scale: float = 1.0
     route_norm: bool = True
+    route_eps: float = 1e-20  # beside the sum of the selected scores
     mup_enabled: bool = True
     load_balance_coeff: float = 1e-3
+    init_std: float = 0.02  # of the seeded matrices; about 1 / sqrt(hidden_size) keeps a tiny model's signal like a wide one's
 
     @staticmethod
     def from_dict(d: Dict[str, Any], vocab_size: int, max_len: int) -> "DecoderConfig":
         fields = {f.name for f in dataclasses.fields(DecoderConfig)}
         kw = {k: v for k, v in d.items() if k in fields}
         kw["layer_types"] = tuple(kw["layer_types"])
+        kw["rope_layers"] = tuple(kw.get("rope_layers", (SLIDING,)))
+        unknown = set(kw["layer_types"]) - {SLIDING, FULL, CONV}
+        if unknown or (SLIDING in kw["layer_types"] and not kw.get("sliding_window")):
+            raise ValueError(f"layer_types {kw['layer_types']}: unknown kinds {sorted(unknown)}, or a sliding layer without sliding_window")
         kw["experts_held"] = tuple(int(x) for x in kw["experts_held"])
         return DecoderConfig(**{**kw, "vocab_size": int(vocab_size), "max_len": int(max_len)})
 
@@ -78,7 +97,16 @@ class DecoderConfig:
         return self.num_attention_heads // self.num_key_value_heads
 
     def cache_len(self, layer: int) -> int:
+        """Positions attention layer ``layer`` caches."""
         return self.sliding_window if self.layer_types[layer] == SLIDING else self.max_len
+
+    def layers_of(self, *kinds: str) -> Tuple[int, ...]:
+        return tuple(i for i, kind in enumerate(self.layer_types) if kind in kinds)
+
+    def carry_slot(self, layer: int) -> int:
+        """Where the carry keeps layer ``layer``'s state, among those of its kind (attention, or conv)."""
+        kinds = (CONV,) if self.layer_types[layer] == CONV else (SLIDING, FULL)
+        return self.layers_of(*kinds).index(layer)
 
     def moe_layers(self) -> Tuple[int, ...]:
         return tuple(i for i in range(len(self.layer_types)) if i >= self.num_dense_layers)
@@ -88,8 +116,9 @@ class DecoderConfig:
 # parameters and carry
 # ----------------------------------------------------------------------------
 
-def init_params(dc: DecoderConfig, key: jax.Array, std: float = 0.02) -> Params:
-    """Seeded float32 parameters: normal(0, ``std``) matrices, unit norms, zero selection bias."""
+def init_params(dc: DecoderConfig, key: jax.Array, std: Optional[float] = None) -> Params:
+    """Seeded float32 parameters: normal(0, ``std``) matrices (``init_std`` unless given), unit norms, zero selection bias."""
+    std = dc.init_std if std is None else std
     H, D = dc.hidden_size, dc.head_dim
     Q, KV = dc.num_attention_heads * D, dc.num_key_value_heads * D
     keys = iter(jax.random.split(key, 16 * len(dc.layer_types) + 8))
@@ -101,35 +130,54 @@ def init_params(dc: DecoderConfig, key: jax.Array, std: float = 0.02) -> Params:
         return {"w1": mat(*lead, H, width), "w3": mat(*lead, H, width), "w2": mat(*lead, width, H)}
 
     params: Params = {"embed": mat(dc.vocab_size, H)}
-    for i in range(len(dc.layer_types)):
-        layer = {
-            "norm_in": jnp.ones((H,)), "norm_post_attn": jnp.ones((H,)),
-            "norm_pre_mlp": jnp.ones((H,)), "norm_post_mlp": jnp.ones((H,)),
-            "q_norm": jnp.ones((D,)), "k_norm": jnp.ones((D,)),
-            "wq": mat(H, Q), "wk": mat(H, KV), "wv": mat(H, KV), "wg": mat(H, Q), "wo": mat(Q, H),
-        }
+    for i, kind in enumerate(dc.layer_types):
+        layer = {"norm_in": jnp.ones((H,)), "norm_pre_mlp": jnp.ones((H,))}
+        if dc.post_norms:
+            layer.update(norm_post_attn=jnp.ones((H,)), norm_post_mlp=jnp.ones((H,)))
+        if kind == CONV:  # conv_w[k] weighs the gated input `conv_L_cache - 1 - k` tokens back
+            layer.update(w_in=mat(H, 3 * H), conv_w=mat(dc.conv_L_cache, H), w_out=mat(H, H))
+        else:
+            layer.update(q_norm=jnp.ones((D,)), k_norm=jnp.ones((D,)), wq=mat(H, Q), wk=mat(H, KV), wv=mat(H, KV))
+            if dc.attn_output_gate:
+                layer["wg"] = mat(H, Q)
+            layer["wo"] = mat(Q, H)
         if i < dc.num_dense_layers:
             layer["mlp"] = ffn(dc.intermediate_size)
         else:
-            layer["moe"] = {
-                "router": mat(H, dc.num_experts),
-                "router_bias": jnp.zeros((dc.num_experts,)),
-                "shared": ffn(dc.moe_intermediate_size * dc.num_shared_experts),
-                "experts": ffn(dc.moe_intermediate_size, lead=(dc.experts_held[1],)),
-            }
+            layer["moe"] = {"router": mat(H, dc.num_experts), "router_bias": jnp.zeros((dc.num_experts,))}
+            if dc.num_shared_experts:
+                layer["moe"]["shared"] = ffn(dc.moe_intermediate_size * dc.num_shared_experts)
+            layer["moe"]["experts"] = ffn(dc.moe_intermediate_size, lead=(dc.experts_held[1],))
         params[f"layer_{i}"] = layer
     params.update(norm_out=jnp.ones((H,)), head=mat(H, dc.vocab_size), value_head=mat(H, 1))
     return params
 
 
 def init_carry(dc: DecoderConfig, batch: int, dtype: Any = jnp.bfloat16) -> Carry:
+    """``k`` and ``v`` hold one buffer per attention layer and ``conv`` one window per conv layer, each in the
+    layers' order; a kind the model lacks has no entry."""
     shape = lambda i: (batch, dc.cache_len(i), dc.num_key_value_heads, dc.head_dim)  # noqa: E731
-    n = len(dc.layer_types)
-    return {
-        "k": [jnp.zeros(shape(i), dtype) for i in range(n)],
-        "v": [jnp.zeros(shape(i), dtype) for i in range(n)],
+    attn, conv = dc.layers_of(SLIDING, FULL), dc.layers_of(CONV)
+    carry = {
+        "k": [jnp.zeros(shape(i), dtype) for i in attn],
+        "v": [jnp.zeros(shape(i), dtype) for i in attn],
         "pos": jnp.zeros((batch,), jnp.int32),
     }
+    if conv:
+        carry["conv"] = [jnp.zeros((batch, dc.conv_L_cache - 1, dc.hidden_size), dtype) for _ in conv]
+    return carry
+
+
+def carry_bytes(dc: DecoderConfig, dtype: Any = jnp.bfloat16) -> Dict[str, int]:
+    """Bytes of recurrent carry one env holds, by kind of layer (and its position)."""
+    shapes = jax.eval_shape(lambda: init_carry(dc, 1, dtype))
+    size = lambda x: int(math.prod(x.shape)) * x.dtype.itemsize  # noqa: E731
+    out = {"pos": size(shapes["pos"])}
+    for i, k, v in zip(dc.layers_of(SLIDING, FULL), shapes["k"], shapes["v"]):
+        out[dc.layer_types[i]] = out.get(dc.layer_types[i], 0) + size(k) + size(v)
+    if "conv" in shapes:
+        out[CONV] = sum(size(z) for z in shapes["conv"])
+    return out
 
 
 def update_router_bias(params: Params, load: jax.Array, dc: DecoderConfig) -> Params:
@@ -206,13 +254,13 @@ def _ffn(w: Params, x: jax.Array) -> jax.Array:
 
 def route(moe: Params, m: jax.Array, dc: DecoderConfig) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Sigmoid scores over ALL experts, the ``k`` largest of ``score + bias`` (the bias has no gradient),
-    weights ``route_scale * s / sum s``.  Returns (experts (N, k), weights (N, k) float32, counts (E,))."""
+    weights ``route_scale * s / (sum s + route_eps)``.  Returns (experts (N, k), weights (N, k) float32, counts (E,))."""
     s = jax.nn.sigmoid(jnp.matmul(  # few columns: cheap in full precision, and a coarser product reorders near ties
         m.astype(jnp.float32), moe["router"].astype(jnp.float32), precision=jax.lax.Precision.HIGHEST))
     _, experts = jax.lax.top_k(s + jax.lax.stop_gradient(moe["router_bias"].astype(jnp.float32)), dc.num_experts_per_tok)
     w = jnp.take_along_axis(s, experts, axis=-1)
     if dc.route_norm:
-        w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+        w = w / (w.sum(axis=-1, keepdims=True) + dc.route_eps)
     counts = jnp.zeros((dc.num_experts,), jnp.int32).at[experts.reshape(-1)].add(1)
     return experts, w * dc.route_scale, counts
 
@@ -243,7 +291,7 @@ def held_experts(w: Params, m: jax.Array, experts: jax.Array, weights: jax.Array
 
 
 def _mlp(layer: Params, x: jax.Array, dc: DecoderConfig) -> Tuple[jax.Array, Optional[jax.Array]]:
-    """``x + norm_post_mlp(f(norm_pre_mlp(x)))`` on (N, H) rows; the router's counts where the layer has experts."""
+    """``x + [norm_post_mlp](f(norm_pre_mlp(x)))`` on (N, H) rows; the router's counts where the layer has experts."""
     m = rms_norm(x, layer["norm_pre_mlp"], dc.rms_norm_eps)
     counts = None
     if "mlp" in layer:
@@ -252,14 +300,17 @@ def _mlp(layer: Params, x: jax.Array, dc: DecoderConfig) -> Tuple[jax.Array, Opt
         moe = layer["moe"]
         with jax.named_scope("policy.moe.route"):
             experts, weights, counts = route(moe, m, dc)
-        with jax.named_scope("policy.moe.shared"):
-            f = _ffn(moe["shared"], m)
+        shared = None
+        if dc.num_shared_experts:
+            with jax.named_scope("policy.moe.shared"):
+                shared = _ffn(moe["shared"], m)
         with jax.named_scope("policy.moe.experts"):
-            f = f + held_experts(moe["experts"], m, experts, weights, dc)
-    return x + rms_norm(f, layer["norm_post_mlp"], dc.rms_norm_eps), counts
+            f = held_experts(moe["experts"], m, experts, weights, dc)
+            f = f if shared is None else shared + f
+    return x + (rms_norm(f, layer["norm_post_mlp"], dc.rms_norm_eps) if dc.post_norms else f), counts
 
 
-def _qkv(layer: Params, a: jax.Array, pos: jax.Array, sliding: bool, dc: DecoderConfig):
+def _qkv(layer: Params, a: jax.Array, pos: jax.Array, rotary: bool, dc: DecoderConfig):
     """Projections of normed rows ``a`` (..., H) at positions ``pos`` (...): q (..., KV, G, D), k, v (..., KV, D)."""
     dt, D, KV = a.dtype, dc.head_dim, dc.num_key_value_heads
     lead = a.shape[:-1]
@@ -268,15 +319,38 @@ def _qkv(layer: Params, a: jax.Array, pos: jax.Array, sliding: bool, dc: Decoder
     v = (a @ layer["wv"].astype(dt)).reshape(lead + (KV, D))
     q = rms_norm(q, layer["q_norm"], dc.rms_norm_eps)
     k = rms_norm(k, layer["k_norm"], dc.rms_norm_eps)
-    if sliding:
+    if rotary:
         q, k = rope(q, pos, dc.rope_theta), rope(k, pos, dc.rope_theta)
     return q, k, v
 
 
+def _after_mixer(layer: Params, x: jax.Array, y: jax.Array, dc: DecoderConfig) -> jax.Array:
+    return x + (rms_norm(y, layer["norm_post_attn"], dc.rms_norm_eps) if dc.post_norms else y)
+
+
 def _after_attention(layer: Params, x: jax.Array, a: jax.Array, o: jax.Array, dc: DecoderConfig) -> jax.Array:
     dt = x.dtype
-    o = o * jax.nn.sigmoid(a @ layer["wg"].astype(dt))
-    return x + rms_norm(o @ layer["wo"].astype(dt), layer["norm_post_attn"], dc.rms_norm_eps)
+    if dc.attn_output_gate:
+        o = o * jax.nn.sigmoid(a @ layer["wg"].astype(dt))
+    return _after_mixer(layer, x, o @ layer["wo"].astype(dt), dc)
+
+
+def _conv_gates(layer: Params, a: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Normed rows ``a`` (..., H) -> the gated input ``B * u`` the convolution reads, and the gate ``C`` on what it gives."""
+    b, c, u = jnp.split(a @ layer["w_in"].astype(a.dtype), 3, axis=-1)
+    return b * u, c
+
+
+def _conv_taps(layer: Params, window: jax.Array, pos: jax.Array, T: int) -> jax.Array:
+    """The causal depthwise convolution as shifted multiply-adds.  ``window`` (B, L - 1 + T, H): the gated
+    inputs of the ``L - 1`` tokens before the ``T`` tokens whose positions are ``pos`` (B, T), then theirs.
+    Tap ``j`` reads the token ``j`` back, and is cut where that token lies before the episode's start."""
+    last = window.shape[1] - T  # L - 1
+    w = layer["conv_w"].astype(window.dtype)
+    out = w[last] * window[:, last:]
+    for j in range(1, last + 1):
+        out = out + jnp.where((pos >= j)[..., None], w[last - j] * window[:, last - j: last - j + T], 0)
+    return out
 
 
 def _heads(params: Params, x: jax.Array, dc: DecoderConfig) -> Tuple[jax.Array, jax.Array]:
@@ -292,8 +366,13 @@ def _embed(params: Params, tokens: jax.Array, dc: DecoderConfig, dtype: Any) -> 
     return x * jnp.asarray(math.sqrt(dc.hidden_size), dtype) if dc.mup_enabled else x
 
 
-def _scope(sliding: bool) -> str:
-    return "policy.attn.window" if sliding else "policy.attn.full"
+def _next_carry(carry: Carry, new: Carry, pos: jax.Array) -> Carry:
+    """``carry``'s own kinds of state from ``new``, at ``pos``."""
+    return {**{k: v for k, v in new.items() if k in carry}, "pos": pos}
+
+
+def _scope(kind: str) -> str:
+    return {SLIDING: "policy.attn.window", FULL: "policy.attn.full", CONV: "policy.conv"}[kind]
 
 
 # ----------------------------------------------------------------------------
@@ -305,37 +384,46 @@ def step(params: Params, dc: DecoderConfig, carry: Carry, tokens: jax.Array, is_
     env's caches (its position goes to nought) before the token is read."""
     pos = jnp.where(is_first > 0, 0, carry["pos"]).astype(jnp.int32)
     x = _embed(params, tokens, dc, dtype)
-    new_k, new_v = [], []
+    new: Carry = {"k": [], "v": [], "conv": []}
     for i, kind in enumerate(dc.layer_types):
-        layer, sliding = params[f"layer_{i}"], kind == SLIDING
-        size = dc.cache_len(i)
-        with jax.named_scope(_scope(sliding)):
+        layer = params[f"layer_{i}"]
+        with jax.named_scope(_scope(kind)):
             a = rms_norm(x, layer["norm_in"], dc.rms_norm_eps)
-            q, k, v = _qkv(layer, a, pos, sliding, dc)
-            slot = jnp.mod(pos, size)
-            write = jax.vmap(lambda c, s, row: jax.lax.dynamic_update_slice(c, row[None].astype(c.dtype), (s, 0, 0)))
-            ck, cv = write(carry["k"][i], slot, k), write(carry["v"][i], slot, v)
-            mask = slot_positions(pos, size) >= 0  # a ring keeps the last `size` positions and nothing older
-            o = _attend(q[:, None], ck.astype(dtype), cv.astype(dtype), mask[:, None])[:, 0]
-            x = _after_attention(layer, x, a, o, dc)
+            if kind == CONV:
+                z, gate = _conv_gates(layer, a)
+                state = carry["conv"][dc.carry_slot(i)]
+                window = jnp.concatenate([state.astype(dtype), z[:, None]], axis=1)
+                y = gate * _conv_taps(layer, window, pos[:, None], 1)[:, 0]
+                x = _after_mixer(layer, x, y @ layer["w_out"].astype(dtype), dc)
+                new["conv"].append(window[:, 1:].astype(state.dtype))  # the window moves on by one token
+            else:
+                n, size = dc.carry_slot(i), dc.cache_len(i)
+                q, k, v = _qkv(layer, a, pos, kind in dc.rope_layers, dc)
+                slot = jnp.mod(pos, size)
+                write = jax.vmap(lambda c, s, row: jax.lax.dynamic_update_slice(c, row[None].astype(c.dtype), (s, 0, 0)))
+                ck, cv = write(carry["k"][n], slot, k), write(carry["v"][n], slot, v)
+                mask = slot_positions(pos, size) >= 0  # a ring keeps the last `size` positions and nothing older
+                o = _attend(q[:, None], ck.astype(dtype), cv.astype(dtype), mask[:, None])[:, 0]
+                x = _after_attention(layer, x, a, o, dc)
+                new["k"].append(ck)
+                new["v"].append(cv)
         x, _ = _mlp(layer, x, dc)
-        new_k.append(ck)
-        new_v.append(cv)
     logits, value = _heads(params, x, dc)
-    return {"k": new_k, "v": new_v, "pos": pos + 1}, logits, value
+    return _next_carry(carry, new, pos + 1), logits, value
 
 
 # ----------------------------------------------------------------------------
 # a segment on a cached prefix
 # ----------------------------------------------------------------------------
 
-def _segment_layer(layer: Params, x, pos, seg, prefix_k, prefix_v, prefix_pos, dc: DecoderConfig, sliding: bool):
-    """One layer over (B, T, H) rows.  Returns (x', router counts or None, k, v of the segment)."""
+def _segment_layer(layer: Params, x, pos, seg, prefix_k, prefix_v, prefix_pos, dc: DecoderConfig, kind: str):
+    """One attention layer over (B, T, H) rows.  Returns (x', router counts or None, k, v of the segment)."""
     B, T, H = x.shape
     dt = x.dtype
-    with jax.named_scope(_scope(sliding)):
+    sliding = kind == SLIDING
+    with jax.named_scope(_scope(kind)):
         a = rms_norm(x, layer["norm_in"], dc.rms_norm_eps)
-        q, k, v = _qkv(layer, a, pos, sliding, dc)
+        q, k, v = _qkv(layer, a, pos, kind in dc.rope_layers, dc)
         keys = jnp.concatenate([prefix_k.astype(dt), k], axis=1)
         values = jnp.concatenate([prefix_v.astype(dt), v], axis=1)
         t = jnp.arange(T, dtype=jnp.int32)
@@ -358,6 +446,20 @@ def _segment_layer(layer: Params, x, pos, seg, prefix_k, prefix_v, prefix_pos, d
     return y.reshape(B, T, H), counts, k, v
 
 
+def _segment_conv_layer(layer: Params, x, pos, state, dc: DecoderConfig):
+    """One conv layer over (B, T, H) rows whose first taps read the carry's ``state`` (B, L - 1, H).
+    Returns (x', router counts or None, the window: the carry's rows, then the segment's gated inputs)."""
+    B, T, H = x.shape
+    with jax.named_scope(_scope(CONV)):
+        a = rms_norm(x, layer["norm_in"], dc.rms_norm_eps)
+        z, gate = _conv_gates(layer, a)
+        window = jnp.concatenate([state.astype(x.dtype), z], axis=1)
+        y = gate * _conv_taps(layer, window, pos, T)
+        x = _after_mixer(layer, x, y @ layer["w_out"].astype(x.dtype), dc)
+    y, counts = _mlp(layer, x.reshape(B * T, H), dc)
+    return y.reshape(B, T, H), counts, window
+
+
 def segment(
     params: Params, dc: DecoderConfig, carry: Carry, tokens: jax.Array, is_first: jax.Array, dtype: Any,
     extend: bool = False, valid: Optional[jax.Array] = None,
@@ -371,29 +473,38 @@ def segment(
     pos, seg = pos_tb.T, seg_tb.T  # (B, T)
     x = _embed(params, tokens.T, dc, dtype)
     T = x.shape[1]
-    counts, new_k, new_v = [], [], []
+    if extend:  # real tokens an env
+        n = jnp.full(pos.shape[:1], T, jnp.int32) if valid is None else valid.astype(jnp.int32)
+    counts = []
+    new: Carry = {"k": [], "v": [], "conv": []}
     for i, kind in enumerate(dc.layer_types):
-        sliding = kind == SLIDING
-        size = dc.cache_len(i)
-        prefix_pos = slot_positions(carry["pos"] - 1, size)
-        run = jax.checkpoint(_segment_layer, static_argnums=(7, 8))
-        x, c, k, v = run(params[f"layer_{i}"], x, pos, seg, carry["k"][i], carry["v"][i], prefix_pos, dc, sliding)
+        if kind == CONV:
+            state = carry["conv"][dc.carry_slot(i)]
+            run = jax.checkpoint(_segment_conv_layer, static_argnums=(4,))
+            x, c, window = run(params[f"layer_{i}"], x, pos, state, dc)
+            if extend:  # the gated inputs of the last L - 1 real tokens; the carry's own rows where there are fewer
+                keep = jax.vmap(lambda rows, start: jax.lax.dynamic_slice_in_dim(rows, start, state.shape[1], axis=0))
+                new["conv"].append(keep(window, n).astype(state.dtype))
+        else:
+            at, size = dc.carry_slot(i), dc.cache_len(i)
+            prefix_pos = slot_positions(carry["pos"] - 1, size)
+            run = jax.checkpoint(_segment_layer, static_argnums=(7, 8))
+            x, c, k, v = run(params[f"layer_{i}"], x, pos, seg, carry["k"][at], carry["v"][at], prefix_pos, dc, kind)
+            if extend:
+                if T > size:
+                    raise ValueError("a prefill segment longer than the window would write a slot twice")
+                real = jnp.arange(T)[None] < n[:, None]
+                slot = jnp.where(real, jnp.mod(pos, size), size)  # out of range: dropped
+                put = jax.vmap(lambda cache, s, rows: cache.at[s].set(rows.astype(cache.dtype), mode="drop"))
+                new["k"].append(put(carry["k"][at], slot, k))
+                new["v"].append(put(carry["v"][at], slot, v))
         if c is not None:
             counts.append(c)
-        if extend:
-            if T > size:
-                raise ValueError("a prefill segment longer than the window would write a slot twice")
-            real = jnp.arange(T)[None] < (valid[:, None] if valid is not None else T)
-            slot = jnp.where(real, jnp.mod(pos, size), size)  # out of range: dropped
-            put = jax.vmap(lambda cache, s, rows: cache.at[s].set(rows.astype(cache.dtype), mode="drop"))
-            new_k.append(put(carry["k"][i], slot, k))
-            new_v.append(put(carry["v"][i], slot, v))
     logits, values = _heads(params, x, dc)
     logits, values = jnp.moveaxis(logits, 0, 1), jnp.moveaxis(values, 0, 1)
     load = jnp.stack(counts) if counts else jnp.zeros((0, dc.num_experts), jnp.int32)
     if not extend:
         return logits, values, load
-    n = jnp.full(pos.shape[:1], T, jnp.int32) if valid is None else valid.astype(jnp.int32)
     last = jnp.take_along_axis(pos, jnp.maximum(n - 1, 0)[:, None], axis=1)[:, 0]
     new_pos = jnp.where(n > 0, last + 1, carry["pos"])
-    return logits, values, load, {"k": new_k, "v": new_v, "pos": new_pos}
+    return logits, values, load, _next_carry(carry, new, new_pos)

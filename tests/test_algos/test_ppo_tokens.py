@@ -5,6 +5,7 @@ cell under its probes gives what ``correct`` compares, i.e. the masked loss, its
 moment after the first dispatch) and the parameters' change, each against the plain reference."""
 
 import re
+import time
 
 import pytest
 
@@ -20,11 +21,14 @@ TINY = [
 ]
 POLICY_SCOPES = ("policy.attn.window", "policy.attn.full", "policy.moe.route", "policy.moe.experts",
                  "policy.moe.shared", "policy.head")
+HYBRID = [o.replace("decoder=tiny", "decoder=tiny_hybrid") for o in TINY]
+HYBRID_SCOPES = ("policy.conv", "policy.attn.full", "policy.moe.route", "policy.moe.experts", "policy.head")
 
 
-@pytest.fixture(scope="module")
-def tiny_run(tmp_path_factory):
+def run_probed(overrides, tmp_path_factory):
+    """One short run: the lowered phase's text, the span log and the recorder's events."""
     from sheeprl_tpu.cli import run
+    from sheeprl_tpu.telemetry.recorder import RECORDER
 
     lowered = []
     real = Fabric.compile
@@ -47,11 +51,23 @@ def tiny_run(tmp_path_factory):
 
     SPANS.reset()
     Fabric.compile = probed
+    t0 = time.time()
     try:
-        run(TINY + [f"log_dir={tmp_path_factory.mktemp('ppo_tokens')}"])
+        run(overrides + [f"log_dir={tmp_path_factory.mktemp('ppo_tokens')}"])
     finally:
         Fabric.compile = real
-    return {"lowered": lowered[0], "records": SPANS.records()}
+    return {"lowered": lowered[0], "records": SPANS.records(),
+            "carry_events": [e for e in RECORDER.snapshot() if e["kind"] == "decoder.carry" and e["t"] >= t0]}
+
+
+@pytest.fixture(scope="module")
+def tiny_run(tmp_path_factory):
+    return run_probed(TINY, tmp_path_factory)
+
+
+@pytest.fixture(scope="module")
+def hybrid_run(tmp_path_factory):
+    return run_probed(HYBRID, tmp_path_factory)
 
 
 def test_the_lowered_phase_carries_the_policy_scopes_inside_the_known_ones(tiny_run):
@@ -74,21 +90,46 @@ def test_spans_and_counters_of_a_run(tiny_run):
     pulls = [r for r in records if r.name == "stats.pull"]
     assert len(pulls) == 3
     for r in pulls:
-        assert set(r.counts) == {"moe_load_max", "moe_load_mean", "beyond_window", "steps"}
+        assert set(r.counts) == {"moe_load_max", "moe_load_mean", "beyond_window", "steps", "carry_bytes"}
         assert r.counts["steps"] == 4 * 8 and 0 <= r.counts["beyond_window"] <= 32
         assert r.counts["moe_load_max"] >= r.counts["moe_load_mean"] > 0
+        assert r.counts["carry_bytes"] == (4 * 8 + 32) * 2 * 16 * 2 * 4 + 4  # four rings of 8, a cache of 32, float32; pos
     assert sum(r.counts["beyond_window"] for r in pulls) > 0  # warm-started past the window of 8
 
 
-@pytest.fixture(scope="module")
-def rehearsal():
-    """The benchmark's cell at rehearsal size under the harness's probes, kept with what they copied."""
+def test_the_hybrid_runs_through_the_cli_with_its_scopes_its_event_and_its_counts(hybrid_run):
+    """``algo/decoder@algo.decoder=tiny_hybrid`` through ``cli.run`` on the fused path: ``policy.conv`` beside
+    ``policy.attn.full`` inside the known scopes (no window layer, no shared expert: neither scope), one
+    ``decoder.carry`` event, ``carry_bytes`` on every ``stats.pull`` and no step counted beyond a window."""
+    text = hybrid_run["lowered"]
+    for name in HYBRID_SCOPES:
+        assert re.search(r"rollout\.policy/[^\"]*" + re.escape(name), text), f"{name} not inside rollout.policy"
+        assert re.search(r"update\.loss\)?/[^\"]*" + re.escape(name), text), f"{name} not inside update.loss"
+    assert "policy.attn.window" not in text and "policy.moe.shared" not in text
+    (event,) = hybrid_run["carry_events"]
+    by_kind = {"conv": 4 * 2 * 64 * 4, "full_attention": 32 * 2 * 16 * 2 * 4, "pos": 4}  # float32 under 32-true
+    assert event["layers"] == {"conv": 4, "full_attention": 1} and event["bytes_per_env"] == by_kind
+    pulls = [r for r in hybrid_run["records"] if r.name == "stats.pull"]
+    assert len(pulls) == 3 and {"exec.ppo_recurrent.prefill", "exec.ppo_recurrent.anakin_phase"} <= {r.name for r in hybrid_run["records"]}
+    for r in pulls:
+        assert r.counts["carry_bytes"] == sum(by_kind.values()) and r.counts["beyond_window"] == 0
+        assert r.counts["moe_load_max"] >= r.counts["moe_load_mean"] > 0
+
+
+def test_the_trinity_run_records_its_carry_too(tiny_run):
+    (event,) = tiny_run["carry_events"]
+    assert event["layers"] == {"sliding_attention": 4, "full_attention": 1}
+    assert event["bytes_per_env"] == {"sliding_attention": 4 * 8 * 2 * 16 * 2 * 4, "full_attention": 32 * 2 * 16 * 2 * 4, "pos": 4}
+
+
+def rehearse(cell):
+    """A benchmark cell at rehearsal size under the harness's probes, kept with what they copied."""
     from chipbench import harness
     from sheeprl_tpu.cli import run
     from sheeprl_tpu.config.compose import compose
 
     SPANS.reset()
-    h = harness.Harness("trinity_tokens_longgen", 2147483693, 1.0, True, rehearse=True)
+    h = harness.Harness(cell, 2147483693, 1.0, True, rehearse=True)
     overrides = h.overrides()
     h.cfg = compose(overrides).as_dict()
     h.install()
@@ -99,6 +140,16 @@ def rehearsal():
     finally:
         h.uninstall()
     return h
+
+
+@pytest.fixture(scope="module")
+def rehearsal():
+    return rehearse("trinity_tokens_longgen")
+
+
+@pytest.fixture(scope="module")
+def hybrid_rehearsal():
+    return rehearse("lfm2_tokens_longgen")
 
 
 def test_masked_loss_gradients_and_change_match_the_reference(rehearsal):
@@ -126,6 +177,43 @@ def test_the_cell_s_readers_return_numbers(rehearsal):
     assert 0.0 < harness.load_module("metrics", "cache.beyond_window_pct").read(ctx) <= 100.0
     assert harness.load_module("metrics", "loop.host_ms_per_iter").read(ctx) >= 0.0
     assert h.program.flops_per_update(h.cfg, ctx["param_shapes"]) > 0
+
+
+def test_the_hybrid_cell_matches_its_reference_and_its_readers_return_numbers(hybrid_rehearsal):
+    """float32 against float32 for the hybrid: the six numbers and the two over the steps whose taps reach
+    outside the segment; then every reader of the cell's per-layer metrics that needs no device trace."""
+    from chipbench import harness
+
+    h = hybrid_rehearsal
+    correct, compared, numbers, _ = harness.judge(h.program, h.cfg, h.snap, h.spec["config"], h.compiles_in_window)
+    gaps = {k: v["value"] for k, v in compared.items() if k != "compiles_in_window"}
+    assert set(gaps) == {"logprob_gap", "value_gap", "moment_gap", "change_gap", "load_gap", "carry_tap_gap", "reset_tap_gap"}
+    assert correct and max(gaps.values()) < 5e-4, gaps
+    assert numbers["first_loss_gap"]["value"] < 5e-4  # read and recorded, not judged (PERF.md section 2 says why)
+    assert numbers["where"]["value"]["skipped"] == [f"layer_{i}/moe/router_bias" for i in (1, 2, 3, 4)]
+    ctx = {"window": h.window, "calls": h.calls, "cfg": h.cfg, "trace": None, "chips": 1, "peak": None,
+           "program": h.program, "param_shapes": h.program.param_shapes(h.snap["inputs"][0])}
+    assert harness.load_module("metrics", "tokens.dispatch_ms").read(ctx) > 0
+    assert harness.load_module("metrics", "moe.load_max_over_mean").read(ctx) >= 1.0
+    assert harness.load_module("metrics", "carry.mb_per_env").read(ctx) == pytest.approx((4 * 2 * 64 * 4 + 32 * 2 * 16 * 2 * 4 + 4) / 1e6)
+    assert harness.load_module("metrics", "cache.beyond_window_pct").read(ctx) == 0.0  # not one of this cell's: no window
+    assert harness.load_module("metrics", "loop.host_ms_per_iter").read(ctx) >= 0.0
+    assert h.program.flops_per_update(h.cfg, ctx["param_shapes"]) > 0
+    cell_metrics = harness.metric_names(h.spec["bench"], "lfm2_tokens_longgen", "per_layer")
+    assert "carry.mb_per_env" in cell_metrics and "cache.beyond_window_pct" not in cell_metrics
+
+
+def test_the_carry_reader_finds_nothing_in_a_program_that_counts_no_carry_bytes(hybrid_rehearsal, monkeypatch):
+    """On a checkout from before this counter the reader returns nothing and does not raise."""
+    from chipbench import harness, spanlog
+
+    h = hybrid_rehearsal
+    stripped = [type("R", (), {"name": r.name, "start": r.start, "end": r.end,
+                               "counts": {k: v for k, v in (r.counts or {}).items() if k != "carry_bytes"}})() for r in SPANS.records()]
+    monkeypatch.setattr(spanlog, "records", lambda: stripped)
+    assert harness.load_module("metrics", "carry.mb_per_env").read({"window": h.window}) is None
+    monkeypatch.setattr(spanlog, "records", lambda: None)
+    assert harness.load_module("metrics", "carry.mb_per_env").read({"window": h.window}) is None
 
 
 def test_the_decoder_core_needs_the_fused_path():

@@ -223,3 +223,201 @@ def test_parameter_count_at_the_published_widths():
     assert count(shapes["layer_0"]) == stated["dense layer"]
     assert count(shapes["layer_1"]) == stated["expert layer (16 of 128 experts held)"]
     assert count(shapes) == stated["total"] == 705476352
+
+
+# ----------------------------------------------------------------------------
+# the hybrid: gated short convolutions beside full attention (configs/algo/decoder/tiny_hybrid.yaml) against
+# chipbench/reference/lfm2_24b_ep8.py
+# ----------------------------------------------------------------------------
+
+def load_hybrid_reference():
+    spec = importlib.util.spec_from_file_location("lfm2_reference", ROOT / "chipbench/reference/lfm2_24b_ep8.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+href = load_hybrid_reference()
+
+
+def hybrid_model(**changes):
+    from sheeprl_tpu.config.compose import compose
+
+    model = compose(["exp=ppo_tokens", "algo/decoder@algo.decoder=tiny_hybrid"]).as_dict()["algo"]["decoder"]
+    return {**model, **changes}
+
+
+def hybrid_config(**changes):
+    return DecoderConfig.from_dict(hybrid_model(**changes), vocab_size=VOCAB, max_len=MAX_LEN)
+
+
+def hybrid_ref_config(**changes):
+    model = hybrid_model(**changes)
+    return href._Static({**model, "layer_types": tuple(model["layer_types"]), "experts_held": tuple(model["experts_held"])})
+
+
+@pytest.fixture(scope="module")
+def hybrid_params():
+    params = decoder.init_params(hybrid_config(), jax.random.PRNGKey(10), std=0.1)
+    for i in (0, 2, 3, 4):  # taps large enough that a tap cut in the wrong place moves the result
+        params[f"layer_{i}"]["conv_w"] = params[f"layer_{i}"]["conv_w"] * 10.0
+    return params
+
+
+def hybrid_reference_full(params, tokens, first, **how):
+    """The hybrid reference's full forward over whole episodes from nothing: (logits, values) as (T, B, ...)."""
+    T, B = tokens.shape
+    cfg = dict(hybrid_ref_config())
+    pos, ep = href.positions(first, jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32))
+    logits, values, counts, _ = href.forward(params, cfg, tokens.T, pos.T, ep.T, href.empty_past(cfg, B), **how)
+    return jnp.moveaxis(logits, 0, 1), values.T, counts
+
+
+def test_the_hybrid_carry_holds_two_kinds_of_state():
+    cfg = hybrid_config()
+    carry = decoder.init_carry(cfg, 3, jnp.float32)
+    assert [x.shape for x in carry["k"]] == [(3, MAX_LEN, 2, 16)] and len(carry["v"]) == 1
+    assert [x.shape for x in carry["conv"]] == [(3, 2, 64)] * 4
+    assert decoder.carry_bytes(cfg, jnp.bfloat16) == {"pos": 4, "full_attention": 2 * MAX_LEN * 2 * 16 * 2, "conv": 4 * 2 * 64 * 2}
+    assert "conv" not in decoder.init_carry(config(), 3)  # a model without the kind has no entry for it
+
+
+@pytest.mark.parametrize("resets", [(), ((5, 0), (11, 1), (12, 1), (14, 1))], ids=["one_episode", "resets_inside"])
+def test_hybrid_segment_matches_the_reference(hybrid_params, resets):
+    """A segment from nothing, with and without resets inside it (two one step apart: an episode of one token,
+    whose successor has one live tap): logits, values and the router's counts."""
+    cfg = hybrid_config()
+    tokens, first = episode(11, 20, 3, resets)
+    logits, values, load = decoder.segment(hybrid_params, cfg, decoder.init_carry(cfg, 3, jnp.float32), tokens, first, jnp.float32)
+    want_logits, want_values, want_load = hybrid_reference_full(hybrid_params, tokens, first)
+    np.testing.assert_allclose(logits, want_logits, **TOL)
+    np.testing.assert_allclose(values[..., 0], want_values, **TOL)
+    np.testing.assert_array_equal(load, want_load)
+
+
+@pytest.mark.parametrize("fault", ["conv_prefix", "conv_reset"])
+def test_the_hybrid_reference_s_planted_faults_move_the_result(hybrid_params, fault):
+    """Taps that reach across an episode's start, and taps that read nought where the past's rows belong, must
+    each move the result where they apply and nowhere else, or the comparisons here hold the taps to nothing."""
+    tokens, first = episode(11, 20, 3, ((5, 0),))
+    code = href.FAULT_CODES[fault]
+    sound, _, _ = hybrid_reference_full(hybrid_params, tokens, first)
+    if fault == "conv_reset":
+        faulty, _, _ = hybrid_reference_full(hybrid_params, tokens, first, fault_code=code)
+        np.testing.assert_allclose(sound[:, 1:], faulty[:, 1:], **TOL)  # envs without a reset inside
+        np.testing.assert_allclose(sound[:5, 0], faulty[:5, 0], **TOL)
+        assert float(jnp.abs(sound[5:7, 0] - faulty[5:7, 0]).max()) > 1e-2
+    else:  # the second half on the first half as its past: the fault cuts what the past gives the first two tokens
+        cfg = dict(hybrid_ref_config())
+        pos, ep = (z.T for z in href.positions(first, jnp.zeros((3,), jnp.int32), jnp.zeros((3,), jnp.int32)))
+        made = href.forward(hybrid_params, cfg, tokens.T[:, :10], pos[:, :10], ep[:, :10], href.empty_past(cfg, 3))[3]
+        past = {"layers": made, "pos": pos[:, :10], "ep": ep[:, :10]}
+        on_past = lambda c: href.forward(hybrid_params, cfg, tokens.T[:, 10:], pos[:, 10:], ep[:, 10:], past, fault_code=c)[0]  # noqa: E731
+        np.testing.assert_allclose(on_past(0), jnp.moveaxis(sound, 0, 1)[:, 10:], **TOL)
+        assert float(jnp.abs(on_past(code)[:, :2] - on_past(0)[:, :2]).max()) > 1e-2
+
+
+def test_hybrid_steps_through_the_carry_match_one_segment(hybrid_params):
+    """T calls of ``step`` (a window shifted, a slot written) against one ``segment`` call, resets inside."""
+    cfg = hybrid_config()
+    tokens, first = episode(12, 20, 3, ((7, 2), (8, 2), (13, 0)))
+    carry = decoder.init_carry(cfg, 3, jnp.float32)
+    want_logits, want_values, _ = decoder.segment(hybrid_params, cfg, carry, tokens, first, jnp.float32)
+    step = jax.jit(lambda c, tok, f: decoder.step(hybrid_params, cfg, c, tok, f, jnp.float32))
+    for t in range(tokens.shape[0]):
+        carry, logits, value = step(carry, tokens[t], first[t])
+        np.testing.assert_allclose(logits, want_logits[t], **TOL)
+        np.testing.assert_allclose(value, want_values[t], **TOL)
+    assert carry["pos"].tolist() == [7, 20, 12] and set(carry) == {"k", "v", "conv", "pos"}
+
+
+@pytest.mark.parametrize("valid", [None, (8, 2, 1, 0)], ids=["whole", "ragged"])
+def test_hybrid_prefill_then_decode_then_the_full_pass_agree(hybrid_params, valid):
+    """Prefill 8 tokens (ragged: each env's first ``valid``: 0, 1, 2 and more real tokens), decode 4 through
+    the carry, run the next 8 as a segment on that carry: the logits of all 12 against the reference's full
+    forward of every env's own tokens.  The window left behind is the gated input of the last two REAL tokens."""
+    cfg = hybrid_config()
+    B = 4
+    tokens, first = episode(13, 20, B)
+    n = np.asarray(valid if valid is not None else (8,) * B)
+    carry = decoder.init_carry(cfg, B, jnp.float32)
+    _, _, _, carry = decoder.segment(
+        hybrid_params, cfg, carry, tokens[:8], first[:8], jnp.float32, extend=True, valid=None if valid is None else jnp.asarray(n))
+    assert carry["pos"].tolist() == n.tolist()
+    streams = [np.concatenate([np.asarray(tokens[: n[b], b]), np.asarray(tokens[8:, b])]) for b in range(B)]
+    got = []
+    for t in range(8, 12):
+        carry, logits, _ = decoder.step(hybrid_params, cfg, carry, tokens[t], jnp.where(carry["pos"] == 0, 1.0, 0.0), jnp.float32)
+        got.append(logits)
+    seg_first = jnp.zeros((8, B)).at[0].set(jnp.where(carry["pos"] == 0, 1.0, 0.0))
+    logits, _, _ = decoder.segment(hybrid_params, cfg, carry, tokens[12:], seg_first, jnp.float32)
+    got = jnp.concatenate([jnp.stack(got), logits])  # (12, B, V): the last 12 tokens of every stream
+    for b in range(B):
+        stream = jnp.asarray(streams[b])[:, None]
+        want, _, _ = hybrid_reference_full(hybrid_params, stream, jnp.zeros(stream.shape).at[0].set(1.0))
+        np.testing.assert_allclose(got[:, b], want[-12:, 0], **TOL)
+
+
+def test_hybrid_gradients_match_the_reference(hybrid_params):
+    """Gradients of a function of logits and values through conv taps, resets and the grouped product."""
+    cfg = hybrid_config()
+    tokens, first = episode(14, 12, 2, ((5, 0),))
+    carry = decoder.init_carry(cfg, 2, jnp.float32)
+
+    def ours(p):
+        logits, values, _ = decoder.segment(p, cfg, carry, tokens, first, jnp.float32)
+        return jnp.sum(jnp.sin(logits)) + jnp.sum(values ** 2)
+
+    def theirs(p):
+        logits, values, _ = hybrid_reference_full(p, tokens, first)
+        return jnp.sum(jnp.sin(logits)) + jnp.sum(values ** 2)
+
+    got, want = jax.grad(ours)(hybrid_params), jax.grad(theirs)(hybrid_params)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-4, err_msg=jax.tree_util.keystr(path))
+
+
+def test_the_hybrid_shares_add_up_to_the_whole_layer():
+    """8 experts split 2 a share over 4 shares, no shared expert: the four partial results add up to the uncut
+    reference's layer output."""
+    whole = decoder.init_params(hybrid_config(experts_held=(0, 8)), jax.random.PRNGKey(4), std=0.1)
+    moe = whole["layer_1"]["moe"]
+    assert "shared" not in moe
+    m = jax.random.normal(jax.random.PRNGKey(5), (24, 64))
+    want, want_counts = href.experts_part(moe, m, dict(hybrid_ref_config(experts_held=(0, 8))), "f32")
+    total = 0.0
+    for first in range(0, 8, 2):
+        cfg = hybrid_config(experts_held=(first, 2))
+        experts, weights, counts = decoder.route(moe, m, cfg)
+        np.testing.assert_array_equal(counts, want_counts)
+        np.testing.assert_allclose(weights.sum(-1), 1.0, rtol=1e-5)  # norm_topk_prob, routed_scaling_factor 1
+        share = {k: v[first:first + 2] for k, v in moe["experts"].items()}
+        total = total + decoder.held_experts(share, m, experts, weights, cfg)
+    np.testing.assert_allclose(total, want, **TOL)
+
+
+@pytest.mark.parametrize("cut", [True, False], ids=["one_chip_s_cut", "the_uncut_model"])
+def test_lfm2_parameter_count_at_the_published_widths(cut):
+    """The configuration's table (chipbench/configs/lfm2_24b_ep8.json) from the shapes ``init_params`` makes, and
+    the whole model (40 layers, 2 of them dense, 64 experts held, the whole vocabulary) within 1% of its 24.0 B."""
+    import json
+
+    from sheeprl_tpu.config.compose import compose
+
+    model = compose(["exp=ppo_tokens", "algo/decoder@algo.decoder=lfm2_24b"]).as_dict()["algo"]["decoder"]
+    file = json.loads((ROOT / "chipbench/configs/lfm2_24b_ep8.json").read_text())
+    count = lambda tree: sum(int(np.prod(x.shape)) for x in jax.tree.leaves(tree))  # noqa: E731
+    if cut:
+        cfg = DecoderConfig.from_dict(model, vocab_size=8192, max_len=8192)
+        shapes = jax.eval_shape(lambda k: decoder.init_params(cfg, k), jax.random.PRNGKey(0))
+        stated = file["parameters"]
+        assert count(shapes["layer_0"]) == stated["layer 0: conv mixer, dense feed-forward"] == 89139200
+        assert count(shapes["layer_1"]) == stated["attention expert layer (8 of 64 experts held)"] == 86118592
+        assert [count(shapes[f"layer_{i}"]) for i in (2, 3, 4)] == [stated["conv expert layer (8 of 64 experts held)"]] * 3 == [92416064] * 3
+        assert count(shapes) == stated["total"] == 486064512
+    else:
+        whole = dict(model, layer_types=file["published"]["layer_types_list"], num_dense_layers=2, experts_held=[0, 64])
+        cfg = DecoderConfig.from_dict(whole, vocab_size=65536, max_len=8192)
+        assert cfg.layer_types.count("conv") == 30 and cfg.layer_types.count("full_attention") == 10
+        shapes = jax.eval_shape(lambda k: decoder.init_params(cfg, k), jax.random.PRNGKey(0))
+        assert abs(count(shapes) / 24.0e9 - 1.0) < 0.01, count(shapes)

@@ -306,3 +306,25 @@ def test_pixel_rollout_stores_uint8_and_nothing_re_lays_a_float_pool_on_v5e(one_
     assert not [x for x in standing if x[1] == "f32" and x[2] == pool]
     assert not [x for x in standing if x[0] == "copy" and x[2] == step]
     assert 0 < compiled.memory_analysis().temp_size_in_bytes < 8 * 2**30
+
+
+def test_hybrid_decode_step_compiles_for_v5e_at_lfm2_widths_with_a_window_and_no_cache_for_a_conv_layer(one_chip):
+    """One decode step of 32 envs through the LFM2 cut (``configs/algo/decoder/lfm2_24b.yaml``, bf16): it compiles
+    for the chip, the carry it takes is one full cache (keys and values of 8,192 positions) and four windows of
+    two rows, 16.8 MB an env, and what it hands back is as large (nothing of a conv layer grows with the episode)."""
+    from sheeprl_tpu.config.compose import compose
+    from sheeprl_tpu.models import decoder
+
+    model = compose(["exp=ppo_tokens", "algo/decoder@algo.decoder=lfm2_24b"]).as_dict()["algo"]["decoder"]
+    dc = decoder.DecoderConfig.from_dict(model, vocab_size=8192, max_len=8192)
+    on_chip = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)  # noqa: E731
+    params = on_chip(jax.eval_shape(lambda k: jax.tree.map(lambda x: x.astype(jnp.bfloat16), decoder.init_params(dc, k)), jax.random.PRNGKey(0)))
+    carry = on_chip(jax.eval_shape(lambda: decoder.init_carry(dc, 32)))
+    assert [x.shape for x in carry["conv"]] == [(32, 2, 2048)] * 4 and [x.shape for x in carry["k"]] == [(32, 8192, 8, 64)]
+    step = jax.jit(lambda p, c, tok, first: decoder.step(p, dc, c, tok, first, jnp.bfloat16), donate_argnums=(1,))
+    compiled = step.lower(params, carry, _spec(one_chip, 32, dtype=jnp.int32), _spec(one_chip, 32)).compile()
+    carry_bytes = 32 * sum(decoder.carry_bytes(dc).values())
+    assert carry_bytes == 32 * (2 * 8192 * 8 * 64 * 2 + 4 * 2 * 2048 * 2 + 4)
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= carry_bytes - 32 * 4 - 4 * 32 * 2 * 2048 * 2  # the cache is updated in place
+    assert ma.temp_size_in_bytes < 2**30
