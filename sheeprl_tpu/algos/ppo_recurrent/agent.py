@@ -25,6 +25,7 @@ import numpy as np
 
 from sheeprl_tpu.models import decoder
 from sheeprl_tpu.models.models import MLP
+from sheeprl_tpu.ops import decode_attention
 from sheeprl_tpu.telemetry.recorder import RECORDER
 
 
@@ -229,6 +230,9 @@ class DecoderPPOAgent:
         # a longer prefill segment would write a ring's slot twice; a model without a ring has no such bound
         self.prefill_chunk = self.window or config.max_len
         self.carry_bytes = decoder.carry_bytes(config, self.carry_dtype)  # an env, by kind of layer
+        sizes = [config.cache_len(i) for i in config.layers_of(decoder.SLIDING, decoder.FULL)]
+        self.cache_held = sum(sizes)  # positions a decode step's attention layers hold an env
+        self.ragged_sizes = [s for s in sizes if decode_attention.engages(s)]  # of the layers read as far as written
 
     def init(self, rng: jax.Array) -> Dict[str, Any]:
         return {"params": decoder.init_params(self.config, rng)}
@@ -271,7 +275,8 @@ class DecoderPPOAgent:
 
     def rollout_stats(self, rollout, init_carry, venv, actor) -> Dict[str, Any]:
         """The rollout as the caches produced it, with the observation that follows it (what a check against a
-        full forward needs), and how many of its steps lay past the window (none where no layer has one)."""
+        full forward needs), how many of its steps lay past the window (none where no layer has one), and how many
+        blocks of positions its ragged attention layers fetched."""
         pos, _ = decoder.segment_positions(rollout["is_first"][..., 0], init_carry["pos"])
         beyond = jnp.zeros((), jnp.int32) if self.window is None else jnp.sum(pos >= self.window)
         kept = ("actions", "logprobs", "values", "rewards", "dones", "is_first", "mask")
@@ -279,15 +284,28 @@ class DecoderPPOAgent:
             **{k: rollout[k] for k in kept},
             "tokens": rollout[self.key], "next_tokens": venv.observe(actor["env"])[self.key],
             "next_is_first": actor["is_first"],
-            "beyond_window": beyond, "steps": jnp.asarray(pos.size, jnp.int32),
+            "beyond_window": beyond, "steps": jnp.asarray(pos.size, jnp.int32), "cache_blocks": self.cache_blocks(pos),
         }
+
+    def cache_blocks(self, pos) -> jax.Array:
+        """Blocks of positions the ragged attention layers fetch over decode steps at the positions ``pos``."""
+        return sum(
+            (jnp.sum(decode_attention.blocks_read(jnp.minimum(pos + 1, size))) for size in self.ragged_sizes),
+            start=jnp.zeros((), jnp.int32))
+
+    def cache_counts(self, steps: int, blocks: int) -> Dict[str, int]:
+        """Positions ``steps`` decode steps fetched from the attention caches (whole blocks of a ragged layer, all
+        of a plain one), and positions those caches held."""
+        plain = self.cache_held - sum(self.ragged_sizes)
+        return {"cache_read": blocks * decode_attention.BLOCK + steps * plain, "cache_held": steps * self.cache_held}
 
     def host_counts(self, stats) -> Dict[str, Any]:
         first, held = self.config.experts_held
         load = np.asarray(stats["load"])[:, first:first + held]  # tokens per held expert of the dispatch's updates
+        steps = int(stats["steps"])
         return {"moe_load_max": load.max(), "moe_load_mean": load.mean(),
-                "beyond_window": np.asarray(stats["beyond_window"]), "steps": np.asarray(stats["steps"]),
-                "carry_bytes": sum(self.carry_bytes.values())}
+                "beyond_window": np.asarray(stats["beyond_window"]), "steps": steps,
+                "carry_bytes": sum(self.carry_bytes.values()), **self.cache_counts(steps, int(stats["cache_blocks"]))}
 
 
 def build_decoder_agent(fabric: Any, cfg: Any, action_space: Any, max_len: int, agent_state: Optional[Any] = None):

@@ -24,9 +24,11 @@ Two entry points serve the recurrent PPO loop, and share every projection:
 
 The carry is a pytree, per env: for every attention layer a buffer of keys and
 of values (a ring of ``sliding_window`` positions for a sliding layer,
-``max_len`` for a full one; slot = position mod size), for every conv layer the
-gated inputs of the last ``conv_L_cache - 1`` tokens (oldest first), and the
-position of the next token.  A reset only zeroes the position: which slots hold
+``max_len`` for a full one; slot = position mod size; a slot is one row of
+``num_key_value_heads * head_dim`` lanes, the heads side by side, so that a row
+is whole lanes whatever the head width), for every conv layer the gated inputs
+of the last ``conv_L_cache - 1`` tokens (oldest first), and the position of the
+next token.  A reset only zeroes the position: which slots hold
 keys of the running episode, and which of a convolution's taps reach a token of
 it (tap ``j`` of the token at position ``p`` iff ``p - j >= 0``), follows from
 the position alone, so nothing has to be cleared.
@@ -40,6 +42,8 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from sheeprl_tpu.ops import decode_attention
 
 Params = Dict[str, Any]
 Carry = Dict[str, Any]
@@ -156,7 +160,7 @@ def init_params(dc: DecoderConfig, key: jax.Array, std: Optional[float] = None) 
 def init_carry(dc: DecoderConfig, batch: int, dtype: Any = jnp.bfloat16) -> Carry:
     """``k`` and ``v`` hold one buffer per attention layer and ``conv`` one window per conv layer, each in the
     layers' order; a kind the model lacks has no entry."""
-    shape = lambda i: (batch, dc.cache_len(i), dc.num_key_value_heads, dc.head_dim)  # noqa: E731
+    shape = lambda i: (batch, dc.cache_len(i), dc.num_key_value_heads * dc.head_dim)  # noqa: E731
     attn, conv = dc.layers_of(SLIDING, FULL), dc.layers_of(CONV)
     carry = {
         "k": [jnp.zeros(shape(i), dtype) for i in attn],
@@ -245,6 +249,11 @@ def _attend(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array) -> jax.Ar
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgts,bskd->btkgd", probs.astype(v.dtype), v)
     return out.reshape(out.shape[:2] + (-1,))
+
+
+def _per_head(cache: jax.Array, dc: DecoderConfig) -> jax.Array:
+    """The carry's rows (B, S, KV * D) as ``_attend`` reads keys and values: (B, S, KV, D)."""
+    return cache.reshape(cache.shape[:2] + (dc.num_key_value_heads, dc.head_dim))
 
 
 def _ffn(w: Params, x: jax.Array) -> jax.Array:
@@ -400,10 +409,14 @@ def step(params: Params, dc: DecoderConfig, carry: Carry, tokens: jax.Array, is_
                 n, size = dc.carry_slot(i), dc.cache_len(i)
                 q, k, v = _qkv(layer, a, pos, kind in dc.rope_layers, dc)
                 slot = jnp.mod(pos, size)
-                write = jax.vmap(lambda c, s, row: jax.lax.dynamic_update_slice(c, row[None].astype(c.dtype), (s, 0, 0)))
+                write = jax.vmap(lambda c, s, row: jax.lax.dynamic_update_slice(c, row.reshape(1, -1).astype(c.dtype), (s, 0)))
                 ck, cv = write(carry["k"][n], slot, k), write(carry["v"][n], slot, v)
-                mask = slot_positions(pos, size) >= 0  # a ring keeps the last `size` positions and nothing older
-                o = _attend(q[:, None], ck.astype(dtype), cv.astype(dtype), mask[:, None])[:, 0]
+                if decode_attention.engages(size):  # a cache with blocks to skip is read as far as each env has written it
+                    # a ring keeps the last `size` positions and nothing older: unwrapped, and in a full cache, slots [0, pos]
+                    o = decode_attention.decode_attention(q, ck, cv, jnp.minimum(pos + 1, size)).astype(dtype)
+                else:
+                    mask = slot_positions(pos, size) >= 0
+                    o = _attend(q[:, None], _per_head(ck, dc).astype(dtype), _per_head(cv, dc).astype(dtype), mask[:, None])[:, 0]
                 x = _after_attention(layer, x, a, o, dc)
                 new["k"].append(ck)
                 new["v"].append(cv)
@@ -489,13 +502,14 @@ def segment(
             at, size = dc.carry_slot(i), dc.cache_len(i)
             prefix_pos = slot_positions(carry["pos"] - 1, size)
             run = jax.checkpoint(_segment_layer, static_argnums=(7, 8))
-            x, c, k, v = run(params[f"layer_{i}"], x, pos, seg, carry["k"][at], carry["v"][at], prefix_pos, dc, kind)
+            prefix_k, prefix_v = _per_head(carry["k"][at], dc), _per_head(carry["v"][at], dc)
+            x, c, k, v = run(params[f"layer_{i}"], x, pos, seg, prefix_k, prefix_v, prefix_pos, dc, kind)
             if extend:
                 if T > size:
                     raise ValueError("a prefill segment longer than the window would write a slot twice")
                 real = jnp.arange(T)[None] < n[:, None]
                 slot = jnp.where(real, jnp.mod(pos, size), size)  # out of range: dropped
-                put = jax.vmap(lambda cache, s, rows: cache.at[s].set(rows.astype(cache.dtype), mode="drop"))
+                put = jax.vmap(lambda cache, s, rows: cache.at[s].set(rows.reshape(T, -1).astype(cache.dtype), mode="drop"))
                 new["k"].append(put(carry["k"][at], slot, k))
                 new["v"].append(put(carry["v"][at], slot, v))
         if c is not None:

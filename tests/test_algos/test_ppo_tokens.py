@@ -90,7 +90,8 @@ def test_spans_and_counters_of_a_run(tiny_run):
     pulls = [r for r in records if r.name == "stats.pull"]
     assert len(pulls) == 3
     for r in pulls:
-        assert set(r.counts) == {"moe_load_max", "moe_load_mean", "beyond_window", "steps", "carry_bytes"}
+        assert set(r.counts) == {"moe_load_max", "moe_load_mean", "beyond_window", "steps", "carry_bytes", "cache_read", "cache_held"}
+        assert r.counts["cache_read"] == r.counts["cache_held"] == 4 * 8 * (4 * 8 + 32)  # caches this short are read whole
         assert r.counts["steps"] == 4 * 8 and 0 <= r.counts["beyond_window"] <= 32
         assert r.counts["moe_load_max"] >= r.counts["moe_load_mean"] > 0
         assert r.counts["carry_bytes"] == (4 * 8 + 32) * 2 * 16 * 2 * 4 + 4  # four rings of 8, a cache of 32, float32; pos
@@ -175,6 +176,7 @@ def test_the_cell_s_readers_return_numbers(rehearsal):
     assert harness.load_module("metrics", "tokens.dispatch_ms").read(ctx) > 0
     assert harness.load_module("metrics", "moe.load_max_over_mean").read(ctx) >= 1.0
     assert 0.0 < harness.load_module("metrics", "cache.beyond_window_pct").read(ctx) <= 100.0
+    assert harness.load_module("metrics", "cache.read_pct").read(ctx) == 100.0  # the tiny caches are read whole
     assert harness.load_module("metrics", "loop.host_ms_per_iter").read(ctx) >= 0.0
     assert h.program.flops_per_update(h.cfg, ctx["param_shapes"]) > 0
 
@@ -201,19 +203,21 @@ def test_the_hybrid_cell_matches_its_reference_and_its_readers_return_numbers(hy
     assert h.program.flops_per_update(h.cfg, ctx["param_shapes"]) > 0
     cell_metrics = harness.metric_names(h.spec["bench"], "lfm2_tokens_longgen", "per_layer")
     assert "carry.mb_per_env" in cell_metrics and "cache.beyond_window_pct" not in cell_metrics
+    assert "cache.read_pct" in cell_metrics and harness.load_module("metrics", "cache.read_pct").read(ctx) == 100.0
 
 
-def test_the_carry_reader_finds_nothing_in_a_program_that_counts_no_carry_bytes(hybrid_rehearsal, monkeypatch):
-    """On a checkout from before this counter the reader returns nothing and does not raise."""
+@pytest.mark.parametrize("reader, counted", [("carry.mb_per_env", ("carry_bytes",)), ("cache.read_pct", ("cache_read", "cache_held"))])
+def test_a_count_s_reader_finds_nothing_in_a_program_that_does_not_count_it(hybrid_rehearsal, monkeypatch, reader, counted):
+    """On a checkout from before its counter a reader returns nothing and does not raise."""
     from chipbench import harness, spanlog
 
     h = hybrid_rehearsal
     stripped = [type("R", (), {"name": r.name, "start": r.start, "end": r.end,
-                               "counts": {k: v for k, v in (r.counts or {}).items() if k != "carry_bytes"}})() for r in SPANS.records()]
+                               "counts": {k: v for k, v in (r.counts or {}).items() if k not in counted}})() for r in SPANS.records()]
     monkeypatch.setattr(spanlog, "records", lambda: stripped)
-    assert harness.load_module("metrics", "carry.mb_per_env").read({"window": h.window}) is None
+    assert harness.load_module("metrics", reader).read({"window": h.window}) is None
     monkeypatch.setattr(spanlog, "records", lambda: None)
-    assert harness.load_module("metrics", "carry.mb_per_env").read({"window": h.window}) is None
+    assert harness.load_module("metrics", reader).read({"window": h.window}) is None
 
 
 def test_the_decoder_core_needs_the_fused_path():

@@ -12,6 +12,7 @@ import pytest
 
 from sheeprl_tpu.models import decoder
 from sheeprl_tpu.models.decoder import DecoderConfig
+from sheeprl_tpu.ops import decode_attention
 
 ROOT = Path(__file__).resolve().parents[2]
 LAYERS = ("sliding_attention",) * 4 + ("full_attention",)
@@ -23,6 +24,10 @@ TINY = dict(
 )
 VOCAB, MAX_LEN = 64, 32
 TOL = dict(rtol=2e-4, atol=2e-4)
+# caches of two of the kernel's blocks: every attention layer then takes the ragged read of ops/decode_attention.py
+# (the rings too: with a window this long a sliding layer sees the whole of these short episodes)
+TWO_BLOCKS = 2 * decode_attention.BLOCK
+CACHES = pytest.mark.parametrize("caches", [MAX_LEN, TWO_BLOCKS], ids=["plain", "ragged"])
 
 
 def load_reference():
@@ -41,12 +46,26 @@ def query_blocks_of_four(monkeypatch):
     monkeypatch.setattr(decoder, "Q_BLOCK", 4)
 
 
-def config(**changes):
-    return DecoderConfig.from_dict({**TINY, **changes}, vocab_size=VOCAB, max_len=MAX_LEN)
+def config(max_len=MAX_LEN, **changes):
+    return DecoderConfig.from_dict({**TINY, **changes}, vocab_size=VOCAB, max_len=max_len)
+
+
+def config_with(caches):
+    """The tiny model with the tests' own caches (rings of 8, a cache of 32), or with rings of two blocks and a
+    cache of four (a ring wraps before the cache is full)."""
+    return config() if caches == MAX_LEN else config(max_len=2 * caches, sliding_window=caches)
 
 
 def ref_config(cfg: DecoderConfig):
-    return ref._Static({**TINY, "experts_held": cfg.experts_held})
+    return ref._Static({**TINY, "experts_held": cfg.experts_held, "sliding_window": cfg.sliding_window})
+
+
+def mid_episode(carry, pos, seed=9):
+    """``carry`` as if every env stood at ``pos`` of an episode: random keys and values (constants to whatever
+    reads them, as a cache written under older parameters is), so that steps cross a block's end and a ring's."""
+    fill = lambda i, z: jax.random.normal(jax.random.PRNGKey(seed + i), z.shape, z.dtype)  # noqa: E731
+    return {**carry, "k": [fill(2 * i, z) for i, z in enumerate(carry["k"])],
+            "v": [fill(2 * i + 1, z) for i, z in enumerate(carry["v"])], "pos": jnp.asarray(pos, jnp.int32)}
 
 
 def episode(seed, T, B, resets=()):
@@ -95,26 +114,32 @@ def test_a_window_layer_differs_from_a_full_one_past_the_window(params):
     assert float(jnp.abs(sound[8:] - faulty[8:]).max()) > 1e-2
 
 
-def test_steps_through_the_cache_match_one_segment(params):
-    """T calls of ``step`` against one ``segment`` call on the same tokens, a reset inside included."""
-    cfg = config()
+@CACHES
+def test_steps_through_the_cache_match_one_segment(params, caches):
+    """T calls of ``step`` against one ``segment`` call on the same tokens, a reset inside included.  With caches
+    of two blocks the envs stand mid-episode: one crosses a block's end, one the ring's, one is reset."""
+    cfg = config_with(caches)
     tokens, first = episode(2, 20, 3, ((7, 2), (13, 0)))
-    carry = decoder.init_carry(cfg, 3, jnp.float32)
+    carry, end = decoder.init_carry(cfg, 3, jnp.float32), [7, 20, 13]
+    if caches == TWO_BLOCKS:
+        first = first.at[0, :2].set(0.0)  # envs 0 and 1 go on with the episode the carry holds
+        carry, end = mid_episode(carry, [decode_attention.BLOCK - 9, TWO_BLOCKS - 6, 40]), [7, TWO_BLOCKS + 14, 13]
     want_logits, want_values, _ = decoder.segment(params, cfg, carry, tokens, first, jnp.float32)
     step = jax.jit(lambda c, tok, f: decoder.step(params, cfg, c, tok, f, jnp.float32))
     for t in range(tokens.shape[0]):
         carry, logits, value = step(carry, tokens[t], first[t])
         np.testing.assert_allclose(logits, want_logits[t], **TOL)
         np.testing.assert_allclose(value, want_values[t], **TOL)
-    assert carry["pos"].tolist() == [7, 20, 13]
+    assert carry["pos"].tolist() == end
 
 
-@pytest.mark.parametrize("valid", [None, (8, 3, 0)], ids=["whole", "ragged"])
-def test_prefill_then_decode_then_the_full_pass_agree(params, valid):
+@pytest.mark.parametrize("valid, caches", [(None, MAX_LEN), ((8, 3, 0), MAX_LEN), ((8, 3, 0), TWO_BLOCKS)],
+                         ids=["whole", "ragged", "ragged_prefill_ragged_caches"])
+def test_prefill_then_decode_then_the_full_pass_agree(params, valid, caches):
     """A segment on a cached prefix against the same tokens in one piece: prefill 8 tokens (ragged: only each
     env's first ``valid``), decode 4 through the cache, run the next 8 as a segment on that cache; all of it
     against the reference's full forward of every env's own tokens."""
-    cfg = config()
+    cfg = config_with(caches)
     B = 3
     tokens, first = episode(3, 20, B)
     n = np.asarray(valid if valid is not None else (8,) * B)
@@ -135,6 +160,27 @@ def test_prefill_then_decode_then_the_full_pass_agree(params, valid):
         stream = jnp.asarray(streams[b])[:, None]
         want, _, _ = reference_full(params, cfg, stream, jnp.zeros(stream.shape).at[0].set(1.0))
         np.testing.assert_allclose(got[:, b], want[-12:, 0], **TOL)
+
+
+@CACHES
+def test_the_counts_of_what_the_decode_steps_fetched(caches):
+    """``cache_read`` and ``cache_held`` as the agent counts them from a rollout's positions: equal where every
+    layer is read whole, the closed form (whole blocks up to each env's length) where the layers are ragged."""
+    from sheeprl_tpu.algos.ppo_recurrent.agent import DecoderPPOAgent
+
+    cfg = config_with(caches)
+    agent = DecoderPPOAgent(cfg, ("tokens",))
+    block = decode_attention.BLOCK
+    pos = np.asarray([[0, block - 2, block - 1, block, caches - 1, caches, 2 * caches - 1, 7]] * 2)  # (T, B)
+    counts = agent.cache_counts(pos.size, int(agent.cache_blocks(jnp.asarray(pos))))
+    sizes = [cfg.cache_len(i) for i in range(len(LAYERS))]
+    assert counts["cache_held"] == pos.size * sum(sizes)
+    if caches == MAX_LEN:
+        assert agent.ragged_sizes == [] and counts["cache_read"] == counts["cache_held"]
+    else:
+        whole_blocks = lambda n: -(-n // block) * block  # noqa: E731
+        want = sum(whole_blocks(min(int(p) + 1, size)) for size in sizes for p in pos.reshape(-1))
+        assert agent.ragged_sizes == sizes and counts["cache_read"] == want < counts["cache_held"]
 
 
 def moe_layer(params):
@@ -247,8 +293,8 @@ def hybrid_model(**changes):
     return {**model, **changes}
 
 
-def hybrid_config(**changes):
-    return DecoderConfig.from_dict(hybrid_model(**changes), vocab_size=VOCAB, max_len=MAX_LEN)
+def hybrid_config(max_len=MAX_LEN, **changes):
+    return DecoderConfig.from_dict(hybrid_model(**changes), vocab_size=VOCAB, max_len=max_len)
 
 
 def hybrid_ref_config(**changes):
@@ -276,7 +322,7 @@ def hybrid_reference_full(params, tokens, first, **how):
 def test_the_hybrid_carry_holds_two_kinds_of_state():
     cfg = hybrid_config()
     carry = decoder.init_carry(cfg, 3, jnp.float32)
-    assert [x.shape for x in carry["k"]] == [(3, MAX_LEN, 2, 16)] and len(carry["v"]) == 1
+    assert [x.shape for x in carry["k"]] == [(3, MAX_LEN, 2 * 16)] and len(carry["v"]) == 1  # a slot's heads side by side
     assert [x.shape for x in carry["conv"]] == [(3, 2, 64)] * 4
     assert decoder.carry_bytes(cfg, jnp.bfloat16) == {"pos": 4, "full_attention": 2 * MAX_LEN * 2 * 16 * 2, "conv": 4 * 2 * 64 * 2}
     assert "conv" not in decoder.init_carry(config(), 3)  # a model without the kind has no entry for it
@@ -317,26 +363,32 @@ def test_the_hybrid_reference_s_planted_faults_move_the_result(hybrid_params, fa
         assert float(jnp.abs(on_past(code)[:, :2] - on_past(0)[:, :2]).max()) > 1e-2
 
 
-def test_hybrid_steps_through_the_carry_match_one_segment(hybrid_params):
-    """T calls of ``step`` (a window shifted, a slot written) against one ``segment`` call, resets inside."""
-    cfg = hybrid_config()
+@CACHES
+def test_hybrid_steps_through_the_carry_match_one_segment(hybrid_params, caches):
+    """T calls of ``step`` (a window shifted, a slot written) against one ``segment`` call, resets inside.  With a
+    cache of two blocks env 1 stands mid-episode and crosses a block's end."""
+    cfg = hybrid_config(max_len=caches)
     tokens, first = episode(12, 20, 3, ((7, 2), (8, 2), (13, 0)))
-    carry = decoder.init_carry(cfg, 3, jnp.float32)
+    carry, end = decoder.init_carry(cfg, 3, jnp.float32), [7, 20, 12]
+    if caches == TWO_BLOCKS:
+        first = first.at[0, 1].set(0.0)
+        carry, end = mid_episode(carry, [3, decode_attention.BLOCK - 9, 40]), [7, decode_attention.BLOCK + 11, 12]
     want_logits, want_values, _ = decoder.segment(hybrid_params, cfg, carry, tokens, first, jnp.float32)
     step = jax.jit(lambda c, tok, f: decoder.step(hybrid_params, cfg, c, tok, f, jnp.float32))
     for t in range(tokens.shape[0]):
         carry, logits, value = step(carry, tokens[t], first[t])
         np.testing.assert_allclose(logits, want_logits[t], **TOL)
         np.testing.assert_allclose(value, want_values[t], **TOL)
-    assert carry["pos"].tolist() == [7, 20, 12] and set(carry) == {"k", "v", "conv", "pos"}
+    assert carry["pos"].tolist() == end and set(carry) == {"k", "v", "conv", "pos"}
 
 
-@pytest.mark.parametrize("valid", [None, (8, 2, 1, 0)], ids=["whole", "ragged"])
-def test_hybrid_prefill_then_decode_then_the_full_pass_agree(hybrid_params, valid):
+@pytest.mark.parametrize("valid, caches", [(None, MAX_LEN), ((8, 2, 1, 0), MAX_LEN), ((8, 2, 1, 0), TWO_BLOCKS)],
+                         ids=["whole", "ragged", "ragged_prefill_ragged_cache"])
+def test_hybrid_prefill_then_decode_then_the_full_pass_agree(hybrid_params, valid, caches):
     """Prefill 8 tokens (ragged: each env's first ``valid``: 0, 1, 2 and more real tokens), decode 4 through
     the carry, run the next 8 as a segment on that carry: the logits of all 12 against the reference's full
     forward of every env's own tokens.  The window left behind is the gated input of the last two REAL tokens."""
-    cfg = hybrid_config()
+    cfg = hybrid_config(max_len=caches)
     B = 4
     tokens, first = episode(13, 20, B)
     n = np.asarray(valid if valid is not None else (8,) * B)

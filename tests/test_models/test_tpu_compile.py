@@ -308,23 +308,59 @@ def test_pixel_rollout_stores_uint8_and_nothing_re_lays_a_float_pool_on_v5e(one_
     assert 0 < compiled.memory_analysis().temp_size_in_bytes < 8 * 2**30
 
 
-def test_hybrid_decode_step_compiles_for_v5e_at_lfm2_widths_with_a_window_and_no_cache_for_a_conv_layer(one_chip):
-    """One decode step of 32 envs through the LFM2 cut (``configs/algo/decoder/lfm2_24b.yaml``, bf16): it compiles
-    for the chip, the carry it takes is one full cache (keys and values of 8,192 positions) and four windows of
-    two rows, 16.8 MB an env, and what it hands back is as large (nothing of a conv layer grows with the episode)."""
+def decode_step_of_32_envs(model_name, vocab, sharding):
+    """``(decoder config, jitted step with the carry donated, its argument shapes on ``sharding``)`` at a token
+    cell's cut: ``configs/algo/decoder/<model_name>.yaml``, bf16, caches of 8,192 positions."""
     from sheeprl_tpu.config.compose import compose
     from sheeprl_tpu.models import decoder
 
-    model = compose(["exp=ppo_tokens", "algo/decoder@algo.decoder=lfm2_24b"]).as_dict()["algo"]["decoder"]
-    dc = decoder.DecoderConfig.from_dict(model, vocab_size=8192, max_len=8192)
-    on_chip = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)  # noqa: E731
+    model = compose(["exp=ppo_tokens", f"algo/decoder@algo.decoder={model_name}"]).as_dict()["algo"]["decoder"]
+    dc = decoder.DecoderConfig.from_dict(model, vocab_size=vocab, max_len=8192)
+    on_chip = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding), tree)  # noqa: E731
     params = on_chip(jax.eval_shape(lambda k: jax.tree.map(lambda x: x.astype(jnp.bfloat16), decoder.init_params(dc, k)), jax.random.PRNGKey(0)))
     carry = on_chip(jax.eval_shape(lambda: decoder.init_carry(dc, 32)))
-    assert [x.shape for x in carry["conv"]] == [(32, 2, 2048)] * 4 and [x.shape for x in carry["k"]] == [(32, 8192, 8, 64)]
     step = jax.jit(lambda p, c, tok, first: decoder.step(p, dc, c, tok, first, jnp.bfloat16), donate_argnums=(1,))
-    compiled = step.lower(params, carry, _spec(one_chip, 32, dtype=jnp.int32), _spec(one_chip, 32)).compile()
+    return dc, step, (params, carry, _spec(sharding, 32, dtype=jnp.int32), _spec(sharding, 32))
+
+
+def test_hybrid_decode_step_compiles_for_v5e_at_lfm2_widths_with_a_window_and_no_cache_for_a_conv_layer(one_chip):
+    """One decode step of 32 envs through the LFM2 cut (``configs/algo/decoder/lfm2_24b.yaml``, bf16): it compiles
+    for the chip, the carry it takes is one full cache (keys and values of 8,192 positions, a slot's 8 heads of 64
+    side by side in 512 lanes) and four windows of two rows, 16.8 MB an env, and what it hands back is as large
+    (nothing of a conv layer grows with the episode)."""
+    from sheeprl_tpu.models import decoder
+
+    dc, step, args = decode_step_of_32_envs("lfm2_24b", 8192, one_chip)
+    carry = args[1]
+    assert [x.shape for x in carry["conv"]] == [(32, 2, 2048)] * 4 and [x.shape for x in carry["k"]] == [(32, 8192, 512)]
+    compiled = step.lower(*args).compile()
     carry_bytes = 32 * sum(decoder.carry_bytes(dc).values())
     assert carry_bytes == 32 * (2 * 8192 * 8 * 64 * 2 + 4 * 2 * 2048 * 2 + 4)
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes >= carry_bytes - 32 * 4 - 4 * 32 * 2 * 2048 * 2  # the cache is updated in place
     assert ma.temp_size_in_bytes < 2**30
+
+
+@pytest.mark.parametrize("model, vocab, caches", [("lfm2_24b", 8192, [8192]), ("trinity_mini", 25024, [2048] * 4 + [8192])],
+                         ids=["lfm2_cut", "trinity_cut"])
+def test_decode_step_reads_its_caches_through_the_ragged_kernel_and_copies_none_on_v5e(one_chip, monkeypatch, model, vocab, caches):
+    """One decode step of 32 envs at a token cell's cut, lowered as the chip lowers it (the kernel asks
+    ``jax.default_backend()`` whether Mosaic is there, and this process holds the CPU): every attention layer
+    (the full cache of 8,192 and, in Trinity, the four rings of 2,048: the dense layer has one too) is one ``decode_attention`` kernel; no
+    copy, transpose or fusion output of a whole cache's shape stands in memory beside the in-place write of the
+    token's row; the caches are aliased; the temporaries stay under 1 GiB (7 to 8 MB)."""
+    from sheeprl_tpu.models import decoder
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    dc, step, args = decode_step_of_32_envs(model, vocab, one_chip)
+    assert [x.shape for x in args[1]["k"]] == [(32, size, 512) for size in caches]
+    compiled = step.lower(*args).compile()
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines() if "custom-call(" in line and "decode_attention" in line]
+    assert len(kernels) == len(caches)
+    whole = {32 * size * 512 for size in caches}
+    standing = [x for x in _unfused_instructions(text) if x[2] in whole and x[0] in ("copy", "transpose", "fusion")]
+    assert not standing, standing
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= 2 * 2 * 32 * 512 * sum(caches)  # keys and values, bf16, updated in place
+    assert 0 < ma.temp_size_in_bytes < 2**30
