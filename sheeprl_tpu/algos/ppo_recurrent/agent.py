@@ -215,8 +215,8 @@ class DecoderPPOAgent:
     """The decoder core (``algo.core: decoder``): a token-level policy over ``models/decoder.py``.
 
     It answers the calls of :class:`LSTMCore` itself.  The observation is the one integer key
-    ``mlp_keys[0]``; the recurrent carry is the decoder's caches, convolution windows and positions; the
-    previous action is not read (the env's observation is the token emitted last)."""
+    ``mlp_keys[0]``; the recurrent carry is the decoder's caches, convolution windows, state-space states and
+    positions; the previous action is not read (the env's observation is the token emitted last)."""
 
     stores_values = True
     prev_action_width = 1  # the action itself: no one-hot of the vocabulary
@@ -233,6 +233,8 @@ class DecoderPPOAgent:
         sizes = [config.cache_len(i) for i in config.layers_of(decoder.SLIDING, decoder.FULL)]
         self.cache_held = sum(sizes)  # positions a decode step's attention layers hold an env
         self.ragged_sizes = [s for s in sizes if decode_attention.engages(s)]  # of the layers read as far as written
+        # bytes of state and convolution window a decode step reads, and writes again, of an env's Mamba-2 layers
+        self.ssm_bytes = self.carry_bytes.get(decoder.MAMBA, 0)
 
     def init(self, rng: jax.Array) -> Dict[str, Any]:
         return {"params": decoder.init_params(self.config, rng)}
@@ -260,8 +262,10 @@ class DecoderPPOAgent:
         return actions
 
     def acting_params(self, p):
-        """The weights in the compute dtype once, not at every one of the rollout's steps."""
-        return jax.tree.map(lambda x: x.astype(self.dtype), p)
+        """The weights in the compute dtype once, not at every one of the rollout's steps; what sets a state-space
+        layer's step sizes and decays stays float32 (``decoder.FLOAT32_LEAVES``)."""
+        return jax.tree_util.tree_map_with_path(
+            lambda path, x: x if path[-1].key in decoder.FLOAT32_LEAVES else x.astype(self.dtype), p)
 
     def init_aux(self) -> Dict[str, Any]:
         """What a dispatch's updates tell of the expert layers: the router's counts, summed and of the first."""
@@ -303,9 +307,12 @@ class DecoderPPOAgent:
         first, held = self.config.experts_held
         load = np.asarray(stats["load"])[:, first:first + held]  # tokens per held expert of the dispatch's updates
         steps = int(stats["steps"])
-        return {"moe_load_max": load.max(), "moe_load_mean": load.mean(),
-                "beyond_window": np.asarray(stats["beyond_window"]), "steps": steps,
-                "carry_bytes": sum(self.carry_bytes.values()), **self.cache_counts(steps, int(stats["cache_blocks"]))}
+        counts = {"moe_load_max": load.max(), "moe_load_mean": load.mean(),
+                  "beyond_window": np.asarray(stats["beyond_window"]), "steps": steps,
+                  "carry_bytes": sum(self.carry_bytes.values()), **self.cache_counts(steps, int(stats["cache_blocks"]))}
+        if self.ssm_bytes:  # a model with state-space layers: every env step reads each layer's state and window and writes them
+            counts["ssm_state_bytes"] = steps * 2 * self.ssm_bytes
+        return counts
 
 
 def build_decoder_agent(fabric: Any, cfg: Any, action_space: Any, max_len: int, agent_state: Optional[Any] = None):

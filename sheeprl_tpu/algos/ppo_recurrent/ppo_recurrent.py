@@ -13,10 +13,11 @@ TPU-native differences:
   are subsets of the env axis;
 * the whole optimization phase (forward scan, GAE, epochs × env-minibatch
   updates) is one jitted dispatch, as in the other algorithms here;
-* the recurrent carry is a pytree: ``(c, h)`` for the LSTM core, window and
-  full attention caches with each env's position for the decoder core
-  (``algo.core: decoder``, ``exp=ppo_tokens``, howto/ppo_tokens.md).  The
-  train phase re-runs a segment from the carry at its start for both.
+* the recurrent carry is a pytree: ``(c, h)`` for the LSTM core; attention
+  caches, convolution windows and state-space states with each env's position
+  for the decoder core (``algo.core: decoder``, ``exp=ppo_tokens``,
+  howto/ppo_tokens.md).  The train phase re-runs a segment from the carry at
+  its start for both.
 """
 
 from __future__ import annotations

@@ -1,17 +1,22 @@
 """A decoder sequence block for token-level policies: pure functions over a parameter dict.
 
 What the other networks of ``models/models.py`` do not have: RMS norm, rotary
-positions, three kinds of sequence mixer (grouped-query attention with a
-sliding window, the same over the whole episode, and a gated short convolution,
-``layer_types``), a gated feed-forward, and a sparse-expert layer that is told
-which experts it holds (``experts_held``), routes over all of them and computes
-its own experts' part of the result.  What stands around a mixer (the norms, the
-output gate, which layers take rotary positions, the embedding multiplier, the
-shared expert) is data of :class:`DecoderConfig`, stated by a yaml of
-``configs/algo/decoder``; its defaults are the ``afmoe`` family's (Arcee
-Trinity), and ``lfm2_24b.yaml`` states the ``lfm2_moe`` family's (LiquidAI).
-``howto/ppo_tokens.md`` and the files of ``chipbench/configs`` say which
-equations a published ``config.json`` settles and which are assumed.
+positions, four kinds of sequence mixer (grouped-query attention with a
+sliding window, the same over the whole episode, a gated short convolution and
+a Mamba-2 state-space layer, ``layer_types``), a feed-forward in two forms
+(silu-gated with three matrices, ``relu^2`` with two), and a sparse-expert
+layer that is told which experts it holds (``experts_held``), routes over all
+of them and computes its own experts' part of the result.  What a layer is made
+of (a mixer and a feed-forward, or, with ``mixer_ffn: False``, one of them alone:
+a mixer, or the kind ``moe``) and what stands around a mixer (the norms, the
+output gate, the per-head norms, which layers take rotary positions, the
+embedding multiplier, the shared expert and its width) is data of
+:class:`DecoderConfig`, stated by a yaml of ``configs/algo/decoder``; its
+defaults are the ``afmoe`` family's (Arcee Trinity), ``lfm2_24b.yaml`` states
+the ``lfm2_moe`` family's (LiquidAI) and ``nemotron3_nano.yaml`` the
+``nemotron_h`` family's (NVIDIA).  ``howto/ppo_tokens.md`` and the files of
+``chipbench/configs`` say which equations a published ``config.json`` settles
+and which are assumed.
 
 Two entry points serve the recurrent PPO loop, and share every projection:
 
@@ -27,11 +32,18 @@ of values (a ring of ``sliding_window`` positions for a sliding layer,
 ``max_len`` for a full one; slot = position mod size; a slot is one row of
 ``num_key_value_heads * head_dim`` lanes, the heads side by side, so that a row
 is whole lanes whatever the head width), for every conv layer the gated inputs
-of the last ``conv_L_cache - 1`` tokens (oldest first), and the position of the
+of the last ``conv_L_cache - 1`` tokens (oldest first), for every Mamba-2 layer
+its state (per head ``ssm_head_dim x ssm_state_size``, float32 whatever the
+compute dtype: it accumulates over thousands of steps at decays near 1) and the
+last ``ssm_conv_kernel - 1`` inputs of its convolution, and the position of the
 next token.  A reset only zeroes the position: which slots hold
 keys of the running episode, and which of a convolution's taps reach a token of
 it (tap ``j`` of the token at position ``p`` iff ``p - j >= 0``), follows from
-the position alone, so nothing has to be cleared.
+the position alone, so nothing has to be cleared.  A state-space state has no
+positions, so it is the one thing a reset has to reach: the state before the
+token at position 0 counts for nought, in ``step`` by the position and in
+``segment`` inside a chunk of the scan, at a chunk's edge and at the segment's
+first token (:func:`_ssm_scan`).
 """
 
 from __future__ import annotations
@@ -51,6 +63,9 @@ Carry = Dict[str, Any]
 SLIDING = "sliding_attention"
 FULL = "full_attention"  # attends to the whole episode
 CONV = "conv"  # a gated short convolution: no keys, a window of gated inputs
+MAMBA = "mamba2"  # a Mamba-2 state-space mixer: no keys, a state per head and a window of convolution inputs
+MOE = "moe"  # no mixer: a layer that is a sparse feed-forward alone (with ``mixer_ffn: False``)
+FLOAT32_LEAVES = ("A_log", "dt_bias", "D")  # of a Mamba-2 layer: read in float32 wherever they are read, so never kept in less
 Q_BLOCK = 64  # queries per attention block of a segment: 64 x (prefix + T) x 32 heads of float32 scores at a time
 
 
@@ -62,7 +77,7 @@ class DecoderConfig:
     num_key_value_heads: int
     head_dim: int
     intermediate_size: int  # the dense layers' feed-forward width
-    moe_intermediate_size: int  # one expert's width (the shared expert's too)
+    moe_intermediate_size: int  # one expert's width (the shared expert's too, unless `shared_intermediate_size` says otherwise)
     num_experts: int  # the router's outputs: ALL experts of the layer
     num_experts_per_tok: int
     experts_held: Tuple[int, int]  # (first, count) of the experts whose weights live here
@@ -72,6 +87,10 @@ class DecoderConfig:
     sliding_window: int = 0  # positions a sliding layer sees; a model without such a layer has none
     conv_L_cache: int = 3  # taps of a conv layer
     num_shared_experts: int = 1  # 0: no shared expert beside the routed ones
+    shared_intermediate_size: int = 0  # the shared expert's own width; 0: `moe_intermediate_size * num_shared_experts`
+    ffn_act: str = "silu_gated"  # every feed-forward: (silu(x W1) * (x W3)) W2, or "relu2": relu(x W1)^2 W2, two matrices
+    mixer_ffn: bool = True  # a mixer layer carries a feed-forward; False: every layer is ONE part, a mixer or `moe`
+    qk_norm: bool = True  # an RMS norm per head on queries and keys
     post_norms: bool = True  # a norm on what the mixer and the feed-forward give, before the residual add
     attn_output_gate: bool = True  # o * sigmoid(a Wg) before the output projection
     rope_layers: Tuple[str, ...] = (SLIDING,)  # the kinds of attention layer that take rotary positions
@@ -83,6 +102,18 @@ class DecoderConfig:
     mup_enabled: bool = True
     load_balance_coeff: float = 1e-3
     init_std: float = 0.02  # of the seeded matrices; about 1 / sqrt(hidden_size) keeps a tiny model's signal like a wide one's
+    # a Mamba-2 layer (`mamba2` in `layer_types`): `ssm_heads x ssm_head_dim` inner channels, B and C in `ssm_groups`
+    # groups of `ssm_state_size` (head h reads group h // (heads / groups)), a causal convolution of `ssm_conv_kernel`
+    # taps with a bias over [x, B, C], a segment scanned in chunks of `ssm_chunk`; `ssm_dt_*`: the seeded step sizes
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state_size: int = 0
+    ssm_conv_kernel: int = 4
+    ssm_chunk: int = 128
+    ssm_dt_min: float = 0.001
+    ssm_dt_max: float = 0.1
+    ssm_dt_floor: float = 1e-4
 
     @staticmethod
     def from_dict(d: Dict[str, Any], vocab_size: int, max_len: int) -> "DecoderConfig":
@@ -90,9 +121,18 @@ class DecoderConfig:
         kw = {k: v for k, v in d.items() if k in fields}
         kw["layer_types"] = tuple(kw["layer_types"])
         kw["rope_layers"] = tuple(kw.get("rope_layers", (SLIDING,)))
-        unknown = set(kw["layer_types"]) - {SLIDING, FULL, CONV}
+        unknown = set(kw["layer_types"]) - {SLIDING, FULL, CONV, MAMBA, MOE}
         if unknown or (SLIDING in kw["layer_types"] and not kw.get("sliding_window")):
             raise ValueError(f"layer_types {kw['layer_types']}: unknown kinds {sorted(unknown)}, or a sliding layer without sliding_window")
+        if MOE in kw["layer_types"] and kw.get("mixer_ffn", True):
+            raise ValueError("a `moe` layer is a feed-forward alone: it needs `mixer_ffn: False` (a mixer layer then carries none)")
+        if MAMBA in kw["layer_types"] and (
+            not (kw.get("ssm_heads") and kw.get("ssm_head_dim") and kw.get("ssm_state_size"))
+            or kw["ssm_heads"] % kw.get("ssm_groups", 1) or kw["ssm_heads"] * kw["ssm_head_dim"] % kw.get("ssm_groups", 1)
+        ):
+            raise ValueError("a `mamba2` layer needs ssm_heads, ssm_head_dim and ssm_state_size, and ssm_groups that divides the heads")
+        if kw.get("ffn_act", "silu_gated") not in ("silu_gated", "relu2"):
+            raise ValueError(f"ffn_act {kw['ffn_act']!r}: silu_gated or relu2")
         kw["experts_held"] = tuple(int(x) for x in kw["experts_held"])
         return DecoderConfig(**{**kw, "vocab_size": int(vocab_size), "max_len": int(max_len)})
 
@@ -108,12 +148,30 @@ class DecoderConfig:
         return tuple(i for i, kind in enumerate(self.layer_types) if kind in kinds)
 
     def carry_slot(self, layer: int) -> int:
-        """Where the carry keeps layer ``layer``'s state, among those of its kind (attention, or conv)."""
-        kinds = (CONV,) if self.layer_types[layer] == CONV else (SLIDING, FULL)
+        """Where the carry keeps layer ``layer``'s state, among those of its kind (attention, conv, or state-space)."""
+        kind = self.layer_types[layer]
+        kinds = (kind,) if kind in (CONV, MAMBA) else (SLIDING, FULL)
         return self.layers_of(*kinds).index(layer)
 
+    def ffn_of(self, layer: int) -> Optional[str]:
+        """The feed-forward layer ``layer`` carries: ``"mlp"`` (dense), ``"moe"``, or none (a mixer alone)."""
+        if self.layer_types[layer] == MOE:
+            return "moe"
+        if not self.mixer_ffn:
+            return None
+        return "mlp" if layer < self.num_dense_layers else "moe"
+
     def moe_layers(self) -> Tuple[int, ...]:
-        return tuple(i for i in range(len(self.layer_types)) if i >= self.num_dense_layers)
+        return tuple(i for i in range(len(self.layer_types)) if self.ffn_of(i) == "moe")
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels of a Mamba-2 layer's convolution: x, then B and C of every group."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state_size
 
 
 # ----------------------------------------------------------------------------
@@ -121,7 +179,10 @@ class DecoderConfig:
 # ----------------------------------------------------------------------------
 
 def init_params(dc: DecoderConfig, key: jax.Array, std: Optional[float] = None) -> Params:
-    """Seeded float32 parameters: normal(0, ``std``) matrices (``init_std`` unless given), unit norms, zero selection bias."""
+    """Seeded float32 parameters: normal(0, ``std``) matrices (``init_std`` unless given), unit norms, zero selection
+    bias; of a Mamba-2 layer besides, as the family's ``_init_weights`` has them: taps uniform on
+    ``+-1 / sqrt(ssm_conv_kernel)``, zero conv bias, ``A_log = log(1 .. heads)``, ``D`` ones, ``dt_bias`` the inverse
+    softplus of a log-uniform draw on [``ssm_dt_min``, ``ssm_dt_max``] floored at ``ssm_dt_floor``."""
     std = dc.init_std if std is None else std
     H, D = dc.hidden_size, dc.head_dim
     Q, KV = dc.num_attention_heads * D, dc.num_key_value_heads * D
@@ -131,26 +192,45 @@ def init_params(dc: DecoderConfig, key: jax.Array, std: Optional[float] = None) 
         return jax.random.normal(next(keys), shape, jnp.float32) * std
 
     def ffn(width, lead=()):
+        if dc.ffn_act == "relu2":
+            return {"w1": mat(*lead, H, width), "w2": mat(*lead, width, H)}
         return {"w1": mat(*lead, H, width), "w3": mat(*lead, H, width), "w2": mat(*lead, width, H)}
 
     params: Params = {"embed": mat(dc.vocab_size, H)}
     for i, kind in enumerate(dc.layer_types):
-        layer = {"norm_in": jnp.ones((H,)), "norm_pre_mlp": jnp.ones((H,))}
-        if dc.post_norms:
-            layer.update(norm_post_attn=jnp.ones((H,)), norm_post_mlp=jnp.ones((H,)))
+        part = dc.ffn_of(i)
+        layer = {}
+        norms = [("norm_in", "norm_post_attn")] * (kind != MOE) + [("norm_pre_mlp", "norm_post_mlp")] * bool(part)
+        for before, after in norms:  # one pair for each part the layer has
+            layer[before] = jnp.ones((H,))
+            if dc.post_norms:
+                layer[after] = jnp.ones((H,))
         if kind == CONV:  # conv_w[k] weighs the gated input `conv_L_cache - 1 - k` tokens back
             layer.update(w_in=mat(H, 3 * H), conv_w=mat(dc.conv_L_cache, H), w_out=mat(H, H))
-        else:
-            layer.update(q_norm=jnp.ones((D,)), k_norm=jnp.ones((D,)), wq=mat(H, Q), wk=mat(H, KV), wv=mat(H, KV))
+        elif kind == MAMBA:  # w_in gives [z, xBC, dt]; conv_w[k] weighs the input `ssm_conv_kernel - 1 - k` tokens back
+            K, heads = dc.ssm_conv_kernel, dc.ssm_heads
+            lo, hi = math.log(dc.ssm_dt_min), math.log(dc.ssm_dt_max)
+            dt = jnp.maximum(jnp.exp(jax.random.uniform(next(keys), (heads,)) * (hi - lo) + lo), dc.ssm_dt_floor)
+            layer.update(
+                w_in=mat(H, dc.ssm_inner + dc.ssm_conv_dim + heads),
+                conv_w=jax.random.uniform(next(keys), (K, dc.ssm_conv_dim), jnp.float32, -1.0, 1.0) / math.sqrt(K),
+                conv_b=jnp.zeros((dc.ssm_conv_dim,)), dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
+                A_log=jnp.log(jnp.arange(1, heads + 1, dtype=jnp.float32)), D=jnp.ones((heads,)),
+                norm_gate=jnp.ones((dc.ssm_inner,)), w_out=mat(dc.ssm_inner, H),
+            )
+        elif kind != MOE:
+            if dc.qk_norm:
+                layer.update(q_norm=jnp.ones((D,)), k_norm=jnp.ones((D,)))
+            layer.update(wq=mat(H, Q), wk=mat(H, KV), wv=mat(H, KV))
             if dc.attn_output_gate:
                 layer["wg"] = mat(H, Q)
             layer["wo"] = mat(Q, H)
-        if i < dc.num_dense_layers:
+        if part == "mlp":
             layer["mlp"] = ffn(dc.intermediate_size)
-        else:
+        elif part == "moe":
             layer["moe"] = {"router": mat(H, dc.num_experts), "router_bias": jnp.zeros((dc.num_experts,))}
             if dc.num_shared_experts:
-                layer["moe"]["shared"] = ffn(dc.moe_intermediate_size * dc.num_shared_experts)
+                layer["moe"]["shared"] = ffn(dc.shared_intermediate_size or dc.moe_intermediate_size * dc.num_shared_experts)
             layer["moe"]["experts"] = ffn(dc.moe_intermediate_size, lead=(dc.experts_held[1],))
         params[f"layer_{i}"] = layer
     params.update(norm_out=jnp.ones((H,)), head=mat(H, dc.vocab_size), value_head=mat(H, 1))
@@ -158,10 +238,11 @@ def init_params(dc: DecoderConfig, key: jax.Array, std: Optional[float] = None) 
 
 
 def init_carry(dc: DecoderConfig, batch: int, dtype: Any = jnp.bfloat16) -> Carry:
-    """``k`` and ``v`` hold one buffer per attention layer and ``conv`` one window per conv layer, each in the
+    """``k`` and ``v`` hold one buffer per attention layer, ``conv`` one window per conv layer, ``ssm`` one state
+    (float32 whatever ``dtype``) and ``ssm_window`` one window of convolution inputs per Mamba-2 layer, each in the
     layers' order; a kind the model lacks has no entry."""
     shape = lambda i: (batch, dc.cache_len(i), dc.num_key_value_heads * dc.head_dim)  # noqa: E731
-    attn, conv = dc.layers_of(SLIDING, FULL), dc.layers_of(CONV)
+    attn, conv, ssm = dc.layers_of(SLIDING, FULL), dc.layers_of(CONV), dc.layers_of(MAMBA)
     carry = {
         "k": [jnp.zeros(shape(i), dtype) for i in attn],
         "v": [jnp.zeros(shape(i), dtype) for i in attn],
@@ -169,6 +250,9 @@ def init_carry(dc: DecoderConfig, batch: int, dtype: Any = jnp.bfloat16) -> Carr
     }
     if conv:
         carry["conv"] = [jnp.zeros((batch, dc.conv_L_cache - 1, dc.hidden_size), dtype) for _ in conv]
+    if ssm:
+        carry["ssm"] = [jnp.zeros((batch, dc.ssm_heads, dc.ssm_head_dim, dc.ssm_state_size), jnp.float32) for _ in ssm]
+        carry["ssm_window"] = [jnp.zeros((batch, dc.ssm_conv_kernel - 1, dc.ssm_conv_dim), dtype) for _ in ssm]
     return carry
 
 
@@ -181,6 +265,8 @@ def carry_bytes(dc: DecoderConfig, dtype: Any = jnp.bfloat16) -> Dict[str, int]:
         out[dc.layer_types[i]] = out.get(dc.layer_types[i], 0) + size(k) + size(v)
     if "conv" in shapes:
         out[CONV] = sum(size(z) for z in shapes["conv"])
+    if "ssm" in shapes:  # what a decode step reads and writes of it whole, every step: the state and the window
+        out[MAMBA] = sum(size(z) for z in shapes["ssm"] + shapes["ssm_window"])
     return out
 
 
@@ -256,9 +342,13 @@ def _per_head(cache: jax.Array, dc: DecoderConfig) -> jax.Array:
     return cache.reshape(cache.shape[:2] + (dc.num_key_value_heads, dc.head_dim))
 
 
-def _ffn(w: Params, x: jax.Array) -> jax.Array:
+def _ffn(w: Params, x: jax.Array, dot=jnp.matmul) -> jax.Array:
+    """The feed-forward in the form its matrices state: ``(silu(x W1) * (x W3)) W2`` with three, ``relu(x W1)^2 W2``
+    with two.  ``dot`` is the product: plain, or grouped over sorted rows (:func:`held_experts`)."""
     dt = x.dtype
-    return (jax.nn.silu(x @ w["w1"].astype(dt)) * (x @ w["w3"].astype(dt))) @ w["w2"].astype(dt)
+    up = dot(x, w["w1"].astype(dt))
+    h = jax.nn.silu(up) * dot(x, w["w3"].astype(dt)) if "w3" in w else jnp.square(jax.nn.relu(up))
+    return dot(h, w["w2"].astype(dt))
 
 
 def route(moe: Params, m: jax.Array, dc: DecoderConfig) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -293,8 +383,7 @@ def held_experts(w: Params, m: jax.Array, experts: jax.Array, weights: jax.Array
     groups = sizes[:held].at[held - 1].add(sizes[held])
     x = jnp.where(ours, jnp.take(m, token, axis=0), 0)
     dt = x.dtype
-    h = jax.nn.silu(jax.lax.ragged_dot(x, w["w1"].astype(dt), groups)) * jax.lax.ragged_dot(x, w["w3"].astype(dt), groups)
-    y = jax.lax.ragged_dot(h, w["w2"].astype(dt), groups)
+    y = _ffn(w, x, lambda rows, mats: jax.lax.ragged_dot(rows, mats, groups))
     y = jnp.where(ours, y.astype(jnp.float32) * weights.reshape(-1)[order][:, None], 0.0)
     return jnp.zeros((N, m.shape[-1]), jnp.float32).at[token].add(y).astype(dt)
 
@@ -326,8 +415,9 @@ def _qkv(layer: Params, a: jax.Array, pos: jax.Array, rotary: bool, dc: DecoderC
     q = (a @ layer["wq"].astype(dt)).reshape(lead + (KV, dc.groups, D))
     k = (a @ layer["wk"].astype(dt)).reshape(lead + (KV, D))
     v = (a @ layer["wv"].astype(dt)).reshape(lead + (KV, D))
-    q = rms_norm(q, layer["q_norm"], dc.rms_norm_eps)
-    k = rms_norm(k, layer["k_norm"], dc.rms_norm_eps)
+    if "q_norm" in layer:
+        q = rms_norm(q, layer["q_norm"], dc.rms_norm_eps)
+        k = rms_norm(k, layer["k_norm"], dc.rms_norm_eps)
     if rotary:
         q, k = rope(q, pos, dc.rope_theta), rope(k, pos, dc.rope_theta)
     return q, k, v
@@ -362,6 +452,93 @@ def _conv_taps(layer: Params, window: jax.Array, pos: jax.Array, T: int) -> jax.
     return out
 
 
+def _ssm_inputs(layer: Params, a: jax.Array, window: jax.Array, pos: jax.Array, dc: DecoderConfig):
+    """Normed rows ``a`` (B, T, H) of a Mamba-2 layer -> the gate ``z`` (B, T, inner), the heads' inputs ``x``
+    (B, T, heads, head_dim), ``B`` and ``C`` (B, T, groups, state), the step sizes ``dt`` (B, T, heads) in float32,
+    and the convolution's inputs (B, K - 1 + T, conv_dim): ``window``, the carry's, then the tokens' own."""
+    T = a.shape[1]
+    z, xbc, dt = jnp.split(a @ layer["w_in"].astype(a.dtype), [dc.ssm_inner, dc.ssm_inner + dc.ssm_conv_dim], axis=-1)
+    window = jnp.concatenate([window.astype(a.dtype), xbc], axis=1)
+    xbc = jax.nn.silu(_conv_taps(layer, window, pos, T) + layer["conv_b"].astype(a.dtype))
+    x, b, c = jnp.split(xbc, [dc.ssm_inner, dc.ssm_inner + dc.ssm_groups * dc.ssm_state_size], axis=-1)
+    lead = a.shape[:2]
+    x = x.reshape(lead + (dc.ssm_heads, dc.ssm_head_dim))
+    b, c = (m.reshape(lead + (dc.ssm_groups, dc.ssm_state_size)) for m in (b, c))
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + layer["dt_bias"].astype(jnp.float32))
+    return z, x, b, c, dt, window
+
+
+def _ssm_output(layer: Params, y: jax.Array, z: jax.Array, dc: DecoderConfig) -> jax.Array:
+    """``y`` (..., inner) float32 gated by ``silu(z)``, then RMS-normed in ``ssm_groups`` groups of channels (the
+    gate first, then the norm), then the output projection."""
+    g = y * jax.nn.silu(z.astype(jnp.float32))
+    grouped = g.reshape(g.shape[:-1] + (dc.ssm_groups, -1))
+    grouped = grouped * jax.lax.rsqrt(jnp.mean(grouped * grouped, axis=-1, keepdims=True) + dc.rms_norm_eps)
+    g = (grouped.reshape(g.shape) * layer["norm_gate"].astype(jnp.float32)).astype(z.dtype)
+    return g @ layer["w_out"].astype(z.dtype)
+
+
+def _ssm_step(layer: Params, x, b, c, dt, pos, state, dc: DecoderConfig):
+    """One token of the recurrence ``S = exp(dt A) S + dt x (outer) B``, ``y = S C + D x``, in float32: x (B, heads,
+    head_dim), b, c (B, groups, state), dt (B, heads), state (B, heads, head_dim, state).  At position 0 the
+    state before the token counts for nought.  -> y (B, inner) float32, the state after the token."""
+    f32 = jnp.float32
+    per_head = lambda m: jnp.repeat(m.astype(f32), dc.ssm_heads // dc.ssm_groups, axis=1)  # noqa: E731  (B, heads, state)
+    x = x.astype(f32)
+    decay = jnp.exp(dt * -jnp.exp(layer["A_log"].astype(f32)))
+    state = jnp.where((pos == 0)[:, None, None, None], 0.0, state)
+    state = decay[..., None, None] * state + (dt[..., None] * x)[..., None] * per_head(b)[:, :, None, :]
+    y = jnp.sum(state * per_head(c)[:, :, None, :], axis=-1) + layer["D"].astype(f32)[:, None] * x
+    return y.reshape(y.shape[0], -1), state
+
+
+def _ssm_scan(layer: Params, x, b, c, dt, cuts, state, dc: DecoderConfig):
+    """The same recurrence over ``T`` tokens in chunks of ``ssm_chunk``, from the carry's ``state`` (a constant):
+    x (B, T, heads, head_dim), b, c (B, T, groups, state), dt (B, T, heads) float32 (nought at a token that is
+    not real: it then leaves the state as it found it), ``cuts`` (B, T) the number of episode starts at or
+    before each token (0: still the carry's episode).  Inside a chunk token ``i`` reads token ``j <= i`` through
+    ``(C_i . B_j) exp(sum of dt A over (j, i])``; a chunk hands its successor the state at its end; a token reads
+    the state its chunk was handed through the decay since the chunk's start.  A reset cuts all three: two tokens
+    with different ``cuts`` do not see each other, and neither does a token see a state from before its episode
+    (the carry's at the segment's first token, a chunk's at a later chunk's edge).  Cumulative sums and decays in
+    float32, the products in the inputs' dtype with float32 accumulation.  -> y (B, T, inner) float32, the state
+    after the last token."""
+    f32 = jnp.float32
+    B, T, heads, P = x.shape
+    G, N, R = dc.ssm_groups, dc.ssm_state_size, dc.ssm_heads // dc.ssm_groups
+    Q = min(dc.ssm_chunk, T)
+    if T % Q:
+        raise ValueError(f"a segment of {T} tokens does not divide into scan chunks of {Q}")
+    chunks = lambda m: m.reshape((B, T // Q, Q) + m.shape[2:])  # noqa: E731
+    dot = lambda eq, *ms: jnp.einsum(eq, *ms, preferred_element_type=f32)  # noqa: E731
+    log_decay = chunks(dt * -jnp.exp(layer["A_log"].astype(f32)))  # (B, chunks, Q, heads), <= 0
+    upto = jnp.cumsum(log_decay, axis=2)  # from the chunk's first token to this one, both included
+    cut = chunks(cuts)
+    handed = jnp.concatenate([jnp.zeros((B, 1), cuts.dtype), cuts[:, Q - 1:-1:Q]], axis=1)  # `cuts` before each chunk's first token
+    xdt = chunks((x.astype(f32) * dt[..., None]).astype(x.dtype)).reshape(B, T // Q, Q, G, R, P)
+    bq, cq = chunks(b), chunks(c)
+    # inside a chunk
+    sees = (cut[:, :, :, None] == cut[:, :, None, :]) & jnp.tril(jnp.ones((Q, Q), bool))  # (B, chunks, i, j)
+    through = jnp.exp(jnp.where(sees[..., None], upto[:, :, :, None] - upto[:, :, None, :], -jnp.inf))  # (B, chunks, i, j, heads)
+    weights = dot("bcign,bcjgn->bcijg", cq, bq)[..., None] * through.reshape(through.shape[:4] + (G, R))
+    y = dot("bcijgr,bcjgrp->bcigrp", weights.astype(x.dtype), xdt)
+    # what a chunk adds to the state at its end, and what it lets through of the state it was handed
+    to_end = jnp.where(cut == cut[:, :, -1:], 1.0, 0.0)[..., None] * jnp.exp(upto[:, :, -1:] - upto)  # (B, chunks, j, heads)
+    added = dot("bcjgrp,bcjgn->bcgrpn", (xdt * to_end.reshape(to_end.shape[:3] + (G, R, 1))).astype(x.dtype), bq)
+    lets = jnp.where(cut[:, :, -1] == handed, 1.0, 0.0)[..., None] * jnp.exp(upto[:, :, -1])  # (B, chunks, heads)
+
+    def edge(s, chunk):  # the state a chunk is handed, and the one it hands on
+        add, let = chunk
+        return let.reshape(B, G, R, 1, 1) * s + add, s
+
+    last, starts = jax.lax.scan(edge, state.astype(f32).reshape(B, G, R, P, N), (jnp.moveaxis(added, 1, 0), jnp.moveaxis(lets, 1, 0)))
+    starts = jnp.moveaxis(starts, 0, 1)  # (B, chunks, G, R, P, N)
+    since = jnp.where(cut == handed[..., None], 1.0, 0.0)[..., None] * jnp.exp(upto)  # (B, chunks, i, heads)
+    y = y + dot("bcign,bcgrpn->bcigrp", cq, starts.astype(x.dtype)) * since.reshape(since.shape[:3] + (G, R, 1))
+    y = y.reshape(B, T, heads, P) + layer["D"].astype(f32)[:, None] * x.astype(f32)
+    return y.reshape(B, T, heads * P), last.reshape(B, heads, P, N)
+
+
 def _heads(params: Params, x: jax.Array, dc: DecoderConfig) -> Tuple[jax.Array, jax.Array]:
     with jax.named_scope("policy.head"):
         h = rms_norm(x, params["norm_out"], dc.rms_norm_eps)
@@ -381,46 +558,61 @@ def _next_carry(carry: Carry, new: Carry, pos: jax.Array) -> Carry:
 
 
 def _scope(kind: str) -> str:
-    return {SLIDING: "policy.attn.window", FULL: "policy.attn.full", CONV: "policy.conv"}[kind]
+    return {SLIDING: "policy.attn.window", FULL: "policy.attn.full", CONV: "policy.conv", MAMBA: "policy.ssm"}[kind]
 
 
 # ----------------------------------------------------------------------------
 # one token through the caches
 # ----------------------------------------------------------------------------
 
+def _step_mixer(layer: Params, x, pos, carry: Carry, new: Carry, dc: DecoderConfig, i: int, dtype: Any):
+    """Layer ``i``'s mixer on one token an env, (B, H) rows at positions ``pos``: reads the layer's state in
+    ``carry``, appends the state after the token to ``new``, returns the rows with the mixer's part added."""
+    kind, n = dc.layer_types[i], dc.carry_slot(i)
+    a = rms_norm(x, layer["norm_in"], dc.rms_norm_eps)
+    if kind == CONV:
+        z, gate = _conv_gates(layer, a)
+        state = carry["conv"][n]
+        window = jnp.concatenate([state.astype(dtype), z[:, None]], axis=1)
+        y = gate * _conv_taps(layer, window, pos[:, None], 1)[:, 0]
+        new["conv"].append(window[:, 1:].astype(state.dtype))  # the window moves on by one token
+        return _after_mixer(layer, x, y @ layer["w_out"].astype(dtype), dc)
+    if kind == MAMBA:
+        z, xs, b, c, dt, window = _ssm_inputs(layer, a[:, None], carry["ssm_window"][n], pos[:, None], dc)
+        with jax.named_scope("policy.ssm.scan"):
+            y, state = _ssm_step(layer, xs[:, 0], b[:, 0], c[:, 0], dt[:, 0], pos, carry["ssm"][n], dc)
+        new["ssm"].append(state)
+        new["ssm_window"].append(window[:, 1:].astype(carry["ssm_window"][n].dtype))
+        return _after_mixer(layer, x, _ssm_output(layer, y, z[:, 0], dc), dc)
+    size = dc.cache_len(i)
+    q, k, v = _qkv(layer, a, pos, kind in dc.rope_layers, dc)
+    slot = jnp.mod(pos, size)
+    write = jax.vmap(lambda c, s, row: jax.lax.dynamic_update_slice(c, row.reshape(1, -1).astype(c.dtype), (s, 0)))
+    ck, cv = write(carry["k"][n], slot, k), write(carry["v"][n], slot, v)
+    if decode_attention.engages(size):  # a cache with blocks to skip is read as far as each env has written it
+        # a ring keeps the last `size` positions and nothing older: unwrapped, and in a full cache, slots [0, pos]
+        o = decode_attention.decode_attention(q, ck, cv, jnp.minimum(pos + 1, size)).astype(dtype)
+    else:
+        mask = slot_positions(pos, size) >= 0
+        o = _attend(q[:, None], _per_head(ck, dc).astype(dtype), _per_head(cv, dc).astype(dtype), mask[:, None])[:, 0]
+    new["k"].append(ck)
+    new["v"].append(cv)
+    return _after_attention(layer, x, a, o, dc)
+
+
 def step(params: Params, dc: DecoderConfig, carry: Carry, tokens: jax.Array, is_first: jax.Array, dtype: Any):
     """``tokens`` (B,), ``is_first`` (B,) -> (carry', logits (B, V), value (B, 1)).  A reset empties the
     env's caches (its position goes to nought) before the token is read."""
     pos = jnp.where(is_first > 0, 0, carry["pos"]).astype(jnp.int32)
     x = _embed(params, tokens, dc, dtype)
-    new: Carry = {"k": [], "v": [], "conv": []}
+    new: Carry = {"k": [], "v": [], "conv": [], "ssm": [], "ssm_window": []}
     for i, kind in enumerate(dc.layer_types):
         layer = params[f"layer_{i}"]
-        with jax.named_scope(_scope(kind)):
-            a = rms_norm(x, layer["norm_in"], dc.rms_norm_eps)
-            if kind == CONV:
-                z, gate = _conv_gates(layer, a)
-                state = carry["conv"][dc.carry_slot(i)]
-                window = jnp.concatenate([state.astype(dtype), z[:, None]], axis=1)
-                y = gate * _conv_taps(layer, window, pos[:, None], 1)[:, 0]
-                x = _after_mixer(layer, x, y @ layer["w_out"].astype(dtype), dc)
-                new["conv"].append(window[:, 1:].astype(state.dtype))  # the window moves on by one token
-            else:
-                n, size = dc.carry_slot(i), dc.cache_len(i)
-                q, k, v = _qkv(layer, a, pos, kind in dc.rope_layers, dc)
-                slot = jnp.mod(pos, size)
-                write = jax.vmap(lambda c, s, row: jax.lax.dynamic_update_slice(c, row.reshape(1, -1).astype(c.dtype), (s, 0)))
-                ck, cv = write(carry["k"][n], slot, k), write(carry["v"][n], slot, v)
-                if decode_attention.engages(size):  # a cache with blocks to skip is read as far as each env has written it
-                    # a ring keeps the last `size` positions and nothing older: unwrapped, and in a full cache, slots [0, pos]
-                    o = decode_attention.decode_attention(q, ck, cv, jnp.minimum(pos + 1, size)).astype(dtype)
-                else:
-                    mask = slot_positions(pos, size) >= 0
-                    o = _attend(q[:, None], _per_head(ck, dc).astype(dtype), _per_head(cv, dc).astype(dtype), mask[:, None])[:, 0]
-                x = _after_attention(layer, x, a, o, dc)
-                new["k"].append(ck)
-                new["v"].append(cv)
-        x, _ = _mlp(layer, x, dc)
+        if kind != MOE:  # a `moe` layer is a feed-forward alone
+            with jax.named_scope(_scope(kind)):
+                x = _step_mixer(layer, x, pos, carry, new, dc, i, dtype)
+        if dc.ffn_of(i):
+            x, _ = _mlp(layer, x, dc)
     logits, value = _heads(params, x, dc)
     return _next_carry(carry, new, pos + 1), logits, value
 
@@ -428,6 +620,15 @@ def step(params: Params, dc: DecoderConfig, carry: Carry, tokens: jax.Array, is_
 # ----------------------------------------------------------------------------
 # a segment on a cached prefix
 # ----------------------------------------------------------------------------
+
+def _segment_ffn(layer: Params, x, dc: DecoderConfig):
+    """The layer's feed-forward over (B, T, H) rows, where it carries one: (x', router counts or None)."""
+    if "mlp" not in layer and "moe" not in layer:  # graftlint: disable=trace-python-branch  (keys of the dict, not values: a mixer layer of a model whose layers are one part)
+        return x, None
+    B, T, H = x.shape
+    y, counts = _mlp(layer, x.reshape(B * T, H), dc)
+    return y.reshape(B, T, H), counts
+
 
 def _segment_layer(layer: Params, x, pos, seg, prefix_k, prefix_v, prefix_pos, dc: DecoderConfig, kind: str):
     """One attention layer over (B, T, H) rows.  Returns (x', router counts or None, k, v of the segment)."""
@@ -455,8 +656,7 @@ def _segment_layer(layer: Params, x, pos, seg, prefix_k, prefix_v, prefix_pos, d
         o = jax.lax.map(attend, (blocks(q), blocks(mask)))
         o = jnp.moveaxis(o, 0, 1).reshape(B, T, -1)
         x = _after_attention(layer, x, a, o, dc)
-    y, counts = _mlp(layer, x.reshape(B * T, H), dc)
-    return y.reshape(B, T, H), counts, k, v
+    return _segment_ffn(layer, x, dc) + (k, v)
 
 
 def _segment_conv_layer(layer: Params, x, pos, state, dc: DecoderConfig):
@@ -469,8 +669,20 @@ def _segment_conv_layer(layer: Params, x, pos, state, dc: DecoderConfig):
         window = jnp.concatenate([state.astype(x.dtype), z], axis=1)
         y = gate * _conv_taps(layer, window, pos, T)
         x = _after_mixer(layer, x, y @ layer["w_out"].astype(x.dtype), dc)
-    y, counts = _mlp(layer, x.reshape(B * T, H), dc)
-    return y.reshape(B, T, H), counts, window
+    return _segment_ffn(layer, x, dc) + (window,)
+
+
+def _segment_ssm_layer(layer: Params, x, pos, cuts, dt_mask, state, window, dc: DecoderConfig):
+    """One Mamba-2 layer over (B, T, H) rows, from the carry's ``state`` and ``window`` (B, K - 1, conv_dim); ``cuts``
+    as :func:`_ssm_scan` reads them, ``dt_mask`` (B, T) nought at a token that is not real.  Returns (x', router
+    counts or None, the state after the last real token, the convolution's inputs: the carry's rows, then the segment's)."""
+    with jax.named_scope(_scope(MAMBA)):
+        a = rms_norm(x, layer["norm_in"], dc.rms_norm_eps)
+        z, xs, b, c, dt, window = _ssm_inputs(layer, a, window, pos, dc)
+        with jax.named_scope("policy.ssm.scan"):
+            y, state = _ssm_scan(layer, xs, b, c, dt * dt_mask[..., None], cuts, state, dc)
+        x = _after_mixer(layer, x, _ssm_output(layer, y, z, dc), dc)
+    return _segment_ffn(layer, x, dc) + (state, window)
 
 
 def segment(
@@ -489,15 +701,28 @@ def segment(
     if extend:  # real tokens an env
         n = jnp.full(pos.shape[:1], T, jnp.int32) if valid is None else valid.astype(jnp.int32)
     counts = []
-    new: Carry = {"k": [], "v": [], "conv": []}
+    new: Carry = {"k": [], "v": [], "conv": [], "ssm": [], "ssm_window": []}
+    keep = jax.vmap(lambda rows, start, size: jax.lax.dynamic_slice_in_dim(rows, start, size, axis=0), in_axes=(0, 0, None))
+    if dc.layers_of(MAMBA):  # an episode starts at position 0, at a real token: the state before it counts for nought
+        live = jnp.arange(T)[None] < n[:, None] if extend else jnp.ones(pos.shape, bool)
+        cuts = jnp.cumsum((pos == 0) & live, axis=1, dtype=jnp.int32)
     for i, kind in enumerate(dc.layer_types):
-        if kind == CONV:
+        if kind == MOE:
+            x, c = jax.checkpoint(_segment_ffn, static_argnums=(2,))(params[f"layer_{i}"], x, dc)
+        elif kind == MAMBA:
+            at = dc.carry_slot(i)
+            state, window = carry["ssm"][at], carry["ssm_window"][at]
+            run = jax.checkpoint(_segment_ssm_layer, static_argnums=(7,))
+            x, c, last, inputs = run(params[f"layer_{i}"], x, pos, cuts, live.astype(jnp.float32), state, window, dc)
+            if extend:  # the state after, and the convolution's inputs at, the last K - 1 real tokens
+                new["ssm"].append(last)
+                new["ssm_window"].append(keep(inputs, n, window.shape[1]).astype(window.dtype))
+        elif kind == CONV:
             state = carry["conv"][dc.carry_slot(i)]
             run = jax.checkpoint(_segment_conv_layer, static_argnums=(4,))
             x, c, window = run(params[f"layer_{i}"], x, pos, state, dc)
             if extend:  # the gated inputs of the last L - 1 real tokens; the carry's own rows where there are fewer
-                keep = jax.vmap(lambda rows, start: jax.lax.dynamic_slice_in_dim(rows, start, state.shape[1], axis=0))
-                new["conv"].append(keep(window, n).astype(state.dtype))
+                new["conv"].append(keep(window, n, state.shape[1]).astype(state.dtype))
         else:
             at, size = dc.carry_slot(i), dc.cache_len(i)
             prefix_pos = slot_positions(carry["pos"] - 1, size)

@@ -23,6 +23,9 @@ POLICY_SCOPES = ("policy.attn.window", "policy.attn.full", "policy.moe.route", "
                  "policy.moe.shared", "policy.head")
 HYBRID = [o.replace("decoder=tiny", "decoder=tiny_hybrid") for o in TINY]
 HYBRID_SCOPES = ("policy.conv", "policy.attn.full", "policy.moe.route", "policy.moe.experts", "policy.head")
+SSM = [o.replace("decoder=tiny", "decoder=tiny_ssm") for o in TINY]
+SSM_SCOPES = ("policy.ssm", "policy.ssm/policy.ssm.scan", "policy.attn.full", "policy.moe.route", "policy.moe.experts",
+              "policy.moe.shared", "policy.head")
 
 
 def run_probed(overrides, tmp_path_factory):
@@ -70,6 +73,11 @@ def hybrid_run(tmp_path_factory):
     return run_probed(HYBRID, tmp_path_factory)
 
 
+@pytest.fixture(scope="module")
+def ssm_run(tmp_path_factory):
+    return run_probed(SSM, tmp_path_factory)
+
+
 def test_the_lowered_phase_carries_the_policy_scopes_inside_the_known_ones(tiny_run):
     from chipbench.scopes import SCOPES  # the one list of the programs' scopes
 
@@ -114,6 +122,33 @@ def test_the_hybrid_runs_through_the_cli_with_its_scopes_its_event_and_its_count
     assert len(pulls) == 3 and {"exec.ppo_recurrent.prefill", "exec.ppo_recurrent.anakin_phase"} <= {r.name for r in hybrid_run["records"]}
     for r in pulls:
         assert r.counts["carry_bytes"] == sum(by_kind.values()) and r.counts["beyond_window"] == 0
+        assert r.counts["moe_load_max"] >= r.counts["moe_load_mean"] > 0
+
+
+def test_the_state_space_hybrid_runs_through_the_cli_with_its_scopes_its_event_and_its_counts(ssm_run):
+    """``algo/decoder@algo.decoder=tiny_ssm`` through ``cli.run`` on the fused path, prefill and checkpoint included:
+    ``policy.ssm`` and inside it ``policy.ssm.scan`` beside ``policy.attn.full`` inside the known scopes (no window
+    layer, no conv layer: neither scope), one ``decoder.carry`` event with the new kind and its bytes, and on every
+    ``stats.pull`` the bytes of state and window the dispatch's decode steps read and wrote."""
+    text = ssm_run["lowered"]
+    for name in SSM_SCOPES:
+        assert re.search(r"rollout\.policy/[^\"]*" + re.escape(name), text), f"{name} not inside rollout.policy"
+        assert re.search(r"update\.loss\)?/[^\"]*" + re.escape(name), text), f"{name} not inside update.loss"
+    assert "policy.attn.window" not in text and "policy.conv" not in text
+    (event,) = ssm_run["carry_events"]
+    state_and_window = 4 * 16 * 8 * 4 + 3 * (64 + 2 * 2 * 8) * 4  # float32 both under 32-true
+    by_kind = {"mamba2": 4 * state_and_window, "full_attention": 32 * 2 * 16 * 2 * 4, "pos": 4}
+    assert event["layers"] == {"mamba2": 4, "moe": 4, "full_attention": 1} and event["bytes_per_env"] == by_kind
+    names = {r.name for r in ssm_run["records"]}
+    assert {"exec.ppo_recurrent.prefill", "exec.ppo_recurrent.anakin_phase", "ckpt.save"} <= names
+    pulls = [r for r in ssm_run["records"] if r.name == "stats.pull"]
+    assert len(pulls) == 3
+    for r in pulls:
+        assert set(r.counts) == {"moe_load_max", "moe_load_mean", "beyond_window", "steps", "carry_bytes", "cache_read",
+                                 "cache_held", "ssm_state_bytes"}
+        assert r.counts["steps"] == 4 * 8 and r.counts["ssm_state_bytes"] == 4 * 8 * 4 * 2 * state_and_window
+        assert r.counts["carry_bytes"] == sum(by_kind.values()) and r.counts["beyond_window"] == 0
+        assert r.counts["cache_read"] == r.counts["cache_held"] == 4 * 8 * 32  # one attention layer of nine holds a cache
         assert r.counts["moe_load_max"] >= r.counts["moe_load_mean"] > 0
 
 
@@ -206,7 +241,8 @@ def test_the_hybrid_cell_matches_its_reference_and_its_readers_return_numbers(hy
     assert "cache.read_pct" in cell_metrics and harness.load_module("metrics", "cache.read_pct").read(ctx) == 100.0
 
 
-@pytest.mark.parametrize("reader, counted", [("carry.mb_per_env", ("carry_bytes",)), ("cache.read_pct", ("cache_read", "cache_held"))])
+@pytest.mark.parametrize("reader, counted", [("carry.mb_per_env", ("carry_bytes",)), ("cache.read_pct", ("cache_read", "cache_held")),
+                                             ("ssm.state_mb_per_step", ("ssm_state_bytes",))])
 def test_a_count_s_reader_finds_nothing_in_a_program_that_does_not_count_it(hybrid_rehearsal, monkeypatch, reader, counted):
     """On a checkout from before its counter a reader returns nothing and does not raise."""
     from chipbench import harness, spanlog
@@ -226,3 +262,39 @@ def test_the_decoder_core_needs_the_fused_path():
     with pytest.raises(ValueError, match="fused path"):
         run([o for o in TINY if not o.startswith("env.wrapper")] + ["env=gym", "env.id=CartPole-v1", "env.capture_video=False", "env.sync_env=True",
              "algo.mlp_keys.encoder=[state]", "log_dir=/tmp/_ppo_tokens_never"])
+
+
+@pytest.fixture(scope="module")
+def ssm_rehearsal():
+    return rehearse("nemotron3_tokens_longgen")
+
+
+def test_the_state_space_cell_matches_its_reference_and_its_readers_return_numbers(ssm_rehearsal):
+    """float32 against float32 for the state-space hybrid: the five numbers of the other token cells, the carry's
+    states after the first dispatch (``state_gap``), the first dispatch's first steps (``carry_gap``) and its steps just
+    after an episode's start (``reset_gap``); then
+    every reader of the cell's per-layer metrics that needs no device trace."""
+    from chipbench import harness
+
+    h = ssm_rehearsal
+    correct, compared, numbers, _ = harness.judge(h.program, h.cfg, h.snap, h.spec["config"], h.compiles_in_window)
+    gaps = {k: v["value"] for k, v in compared.items() if k != "compiles_in_window"}
+    assert set(gaps) == {"logprob_gap", "value_gap", "moment_gap", "change_gap", "load_gap", "state_gap", "carry_gap", "reset_gap"}
+    assert correct and max(gaps.values()) < 5e-4, gaps
+    assert numbers["first_loss_gap"]["value"] < 5e-4  # read and recorded, not judged
+    assert numbers["where"]["value"]["skipped"] == [f"layer_{i}/moe/router_bias" for i in (1, 3, 6, 8)]
+    assert sum(numbers["where"]["value"]["reset_steps"]) > 0 and len(numbers["where"]["value"]["state_gaps"]) == 4  # an episode started inside the first dispatch
+    assert len(h.snap["outputs"][0]["ssm_states"]) == 4 and "ssm_states" not in h.snap["outputs"][1]
+    ctx = {"window": h.window, "calls": h.calls, "cfg": h.cfg, "trace": None, "chips": 1, "peak": None,
+           "program": h.program, "param_shapes": h.program.param_shapes(h.snap["inputs"][0])}
+    state_and_window = 4 * 16 * 8 * 4 + 3 * (64 + 2 * 2 * 8) * 4
+    read = lambda name: harness.load_module("metrics", name).read(ctx)  # noqa: E731
+    assert read("ssm.state_mb_per_step") == pytest.approx(4 * 4 * 2 * state_and_window / 1e6)  # envs x layers x read and write
+    assert read("carry.mb_per_env") == pytest.approx((4 * state_and_window + 32 * 2 * 16 * 2 * 4 + 4) / 1e6)
+    assert read("tokens.dispatch_ms") > 0 and read("moe.load_max_over_mean") >= 1.0 and read("cache.read_pct") == 100.0
+    assert read("loop.host_ms_per_iter") >= 0.0 and h.program.flops_per_update(h.cfg, ctx["param_shapes"]) > 0
+    cell_metrics = harness.metric_names(h.spec["bench"], "nemotron3_tokens_longgen", "per_layer")
+    assert {"ssm.state_mb_per_step", "carry.mb_per_env", "cache.read_pct", "tokens.dispatch_ms", "moe.load_max_over_mean",
+            "loop.stall_ms_per_iter", "loop.untracked_ms_per_iter", "step.mfu_pct", "device.idle_pct"} <= set(cell_metrics)
+    assert "cache.beyond_window_pct" not in cell_metrics
+    assert "ssm.state_mb_per_step" not in harness.metric_names(h.spec["bench"], "lfm2_tokens_longgen", "per_layer")

@@ -364,3 +364,66 @@ def test_decode_step_reads_its_caches_through_the_ragged_kernel_and_copies_none_
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes >= 2 * 2 * 32 * 512 * sum(caches)  # keys and values, bf16, updated in place
     assert 0 < ma.temp_size_in_bytes < 2**30
+
+
+def nemotron3_cut(sharding):
+    """``(decoder config, float32 parameter shapes, the same as the rollout reads them, a carry of 32 envs)`` at the
+    Nemotron-3 cell's cut (``configs/algo/decoder/nemotron3_nano.yaml``, 16,384 ids, caches of 8,192 positions)."""
+    from sheeprl_tpu.algos.ppo_recurrent.agent import DecoderPPOAgent
+    from sheeprl_tpu.config.compose import compose
+    from sheeprl_tpu.models import decoder
+
+    model = compose(["exp=ppo_tokens", "algo/decoder@algo.decoder=nemotron3_nano"]).as_dict()["algo"]["decoder"]
+    dc = decoder.DecoderConfig.from_dict(model, vocab_size=16384, max_len=8192)
+    on_chip = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding), tree)  # noqa: E731
+    agent = DecoderPPOAgent(dc, ("tokens",), jnp.bfloat16)
+    params = jax.eval_shape(lambda k: decoder.init_params(dc, k), jax.random.PRNGKey(0))
+    return dc, on_chip(params), on_chip(jax.eval_shape(agent.acting_params, params)), on_chip(jax.eval_shape(lambda: agent.initial_state(32)))
+
+
+def test_state_space_decode_step_compiles_for_v5e_at_nemotron3_widths_and_copies_neither_cache_nor_state(one_chip, monkeypatch):
+    """One decode step of 32 envs through the Nemotron-3 cut, bf16, lowered as the chip lowers it: it compiles; the one
+    attention layer's cache of 8,192 rows of 256 lanes (2 heads of 128, 16 queries a key head: a third shape for
+    ``decode_attention``) goes through the kernel and no copy, transpose or fusion output of the cache's shape stands
+    beside it; every Mamba-2 layer's state ``f32[32,64,64,128]`` is made by ONE fusion, which also gives the state's
+    read (the update and ``S C`` share one pass over the state), written into the carry's own donated buffer: the step
+    holds no buffer of the state's shape beside the carry's four; what sets the decays stays float32."""
+    from sheeprl_tpu.models import decoder
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    dc, _, acting, carry = nemotron3_cut(one_chip)
+    assert [x.shape for x in carry["k"]] == [(32, 8192, 256)] and [(x.shape, x.dtype) for x in carry["ssm"]] == [((32, 64, 64, 128), jnp.float32)] * 4
+    assert [x.shape for x in carry["ssm_window"]] == [(32, 3, 6144)] * 4
+    assert {acting["layer_0"][k].dtype for k in decoder.FLOAT32_LEAVES} == {jnp.dtype(jnp.float32)} and acting["layer_0"]["w_in"].dtype == jnp.bfloat16
+    step = jax.jit(lambda p, c, tok, first: decoder.step(p, dc, c, tok, first, jnp.bfloat16), donate_argnums=(1,))
+    compiled = step.lower(acting, carry, _spec(one_chip, 32, dtype=jnp.int32), _spec(one_chip, 32)).compile()
+    text = compiled.as_text()
+    assert len([line for line in text.splitlines() if "custom-call(" in line and "decode_attention" in line]) == 1
+    standing = list(_unfused_instructions(text))
+    cache, state = 32 * 8192 * 256, 32 * 64 * 64 * 128
+    assert not [x for x in standing if x[2] == cache and x[0] in ("copy", "transpose", "fusion")]
+    passed_on = ("parameter", "get-tuple-element", "tuple", "bitcast")  # these make no buffer
+    states = [x[0] for x in standing if x[1] == "f32" and x[2] == state and x[0] not in passed_on]
+    assert states == ["fusion"] * 4, states  # one an SSM layer, and no copy of a state
+    ma = compiled.memory_analysis()
+    carry_bytes = 32 * sum(decoder.carry_bytes(dc).values())
+    assert carry_bytes == 32 * 16924676 and ma.alias_size_in_bytes >= carry_bytes - 32 * 4  # cache, states and windows in place
+    assert 0 < ma.temp_size_in_bytes < 2 * 4 * state  # 90 MB, 80 of them one expert layer's `w1` re-laid for the grouped product
+
+
+def test_state_space_update_compiles_for_v5e_at_nemotron3_widths(one_chip):
+    """One update of the Nemotron-3 cut at a minibatch's size (8 envs x 256 tokens from a carried state, bf16 compute,
+    float32 parameters): the loss's gradient through the chunked scan, the prefix read and the grouped product compiles
+    for the chip within what the phase has room for (the compiler counts 1.43 GB of temporaries)."""
+    from sheeprl_tpu.models import decoder
+
+    dc, params, _, carry = nemotron3_cut(one_chip)
+    carry = jax.tree.map(lambda x: jax.ShapeDtypeStruct((8,) + x.shape[1:], x.dtype, sharding=one_chip), carry)
+
+    def loss(p, c, tokens, first):
+        logits, values, load = decoder.segment(p, dc, c, tokens, first, jnp.bfloat16)
+        return jnp.mean(jax.nn.logsumexp(logits, -1)) + jnp.mean(values ** 2), load
+
+    compiled = jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
+        params, carry, _spec(one_chip, 256, 8, dtype=jnp.int32), _spec(one_chip, 256, 8)).compile()
+    assert 0 < compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
