@@ -268,10 +268,16 @@ class DecoderPPOAgent:
             lambda path, x: x if path[-1].key in decoder.FLOAT32_LEAVES else x.astype(self.dtype), p)
 
     def init_aux(self) -> Dict[str, Any]:
-        """What a dispatch's updates tell of the expert layers: the router's counts, summed and of the first."""
+        """What a dispatch's updates tell of the expert layers: the router's counts, summed and of the first, and the
+        sorted (token, expert) rows their grouped products visited."""
         counts = jnp.zeros((len(self.config.moe_layers()), self.config.num_experts), jnp.int32)
         return {"updates": jnp.zeros((), jnp.int32), "load": counts, "first_load": counts,
-                "first_losses": jnp.zeros((3,), jnp.float32)}
+                "first_losses": jnp.zeros((3,), jnp.float32), "moe_rows_run": jnp.zeros((), jnp.int32)}
+
+    def rows_run(self, load, tokens: int) -> jax.Array:
+        """Sorted rows the expert layers' grouped products visited in one update of ``tokens`` tokens, from its
+        router's counts ``load`` (expert layers, E): ``decoder.rows_run``, summed over the layers."""
+        return jnp.sum(decoder.rows_run(load, tokens * self.config.num_experts_per_tok, self.config))
 
     def after_update(self, p, load):
         """The experts' selection bias follows the router's counts of the update."""
@@ -305,11 +311,13 @@ class DecoderPPOAgent:
 
     def host_counts(self, stats) -> Dict[str, Any]:
         first, held = self.config.experts_held
-        load = np.asarray(stats["load"])[:, first:first + held]  # tokens per held expert of the dispatch's updates
+        routed = np.asarray(stats["load"])  # the router's counts of the dispatch's updates: `tokens x k` a pass over an expert layer
+        load = routed[:, first:first + held]  # tokens per held expert
         steps = int(stats["steps"])
         counts = {"moe_load_max": load.max(), "moe_load_mean": load.mean(),
                   "beyond_window": np.asarray(stats["beyond_window"]), "steps": steps,
-                  "carry_bytes": sum(self.carry_bytes.values()), **self.cache_counts(steps, int(stats["cache_blocks"]))}
+                  "carry_bytes": sum(self.carry_bytes.values()), **self.cache_counts(steps, int(stats["cache_blocks"])),
+                  "moe_rows_run": int(stats["moe_rows_run"]), "moe_rows_all": int(routed.sum())}
         if self.ssm_bytes:  # a model with state-space layers: every env step reads each layer's state and window and writes them
             counts["ssm_state_bytes"] = steps * 2 * self.ssm_bytes
         return counts

@@ -279,6 +279,7 @@ def main(fabric: Any, cfg: Any) -> None:
                             "load": aux["load"] + load,
                             "first_load": jnp.where(first, load, aux["first_load"]),
                             "first_losses": jnp.where(first, jnp.stack([pg, vl, el]), aux["first_losses"]),
+                            "moe_rows_run": aux["moe_rows_run"] + core.rows_run(load, T * env_bs),
                         }
                 return p, o_state, (pg, vl, el), aux
 
@@ -399,7 +400,7 @@ def main(fabric: Any, cfg: Any) -> None:
                 env_bs=env_bs, num_minibatches=num_minibatches,
             )
             if aux is not None:
-                stats = {**stats, **{kk: aux[kk] for kk in ("load", "first_load", "first_losses")}}
+                stats = {**stats, **{kk: aux[kk] for kk in ("load", "first_load", "first_losses", "moe_rows_run")}}
             return p, o_state, actor, k_next, losses, stats
 
         anakin_step = fabric.compile(

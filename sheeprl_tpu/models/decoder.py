@@ -67,6 +67,7 @@ MAMBA = "mamba2"  # a Mamba-2 state-space mixer: no keys, a state per head and a
 MOE = "moe"  # no mixer: a layer that is a sparse feed-forward alone (with ``mixer_ffn: False``)
 FLOAT32_LEAVES = ("A_log", "dt_bias", "D")  # of a Mamba-2 layer: read in float32 wherever they are read, so never kept in less
 Q_BLOCK = 64  # queries per attention block of a segment: 64 x (prefix + T) x 32 heads of float32 scores at a time
+ROW_TILE = 512  # rows of one tile of the grouped product as XLA runs `ragged_dot` on the TPU
 
 
 @dataclasses.dataclass(frozen=True)
@@ -364,27 +365,39 @@ def route(moe: Params, m: jax.Array, dc: DecoderConfig) -> Tuple[jax.Array, jax.
     return experts, w * dc.route_scale, counts
 
 
+def rows_run(counts: jax.Array, rows: int, dc: DecoderConfig) -> jax.Array:
+    """Sorted rows the grouped products of one pass over an expert layer visit, from the router's ``counts`` of
+    that pass (..., E): the pairs routed to the held experts, which :func:`held_experts` sorts first, in whole
+    tiles of ``ROW_TILE`` rows (of ``rows`` = ``tokens x k`` at most)."""
+    first, held = dc.experts_held
+    ours = counts[..., first:first + held].sum(-1)
+    return jnp.minimum(-(-ours // ROW_TILE) * ROW_TILE, rows)
+
+
 def held_experts(w: Params, m: jax.Array, experts: jax.Array, weights: jax.Array, dc: DecoderConfig) -> jax.Array:
     """The held experts' part of the layer's result, dropless: the ``N * k`` (token, expert) pairs are sorted by
-    expert, those of experts held elsewhere last, and the held experts run as one grouped product over all of
-    them.  No capacity and no second program: whatever share of the pairs is routed here has its rows."""
+    expert, those of experts held elsewhere last, and the held experts run as one grouped product over the pairs
+    routed to them.  No capacity and no second program: whatever share of the pairs is routed here has its rows."""
     first, held = dc.experts_held
     N, k = experts.shape
     local = experts.reshape(-1) - first
     local = jnp.where((local >= 0) & (local < held), local, held)  # held elsewhere: after every group
     order = jnp.argsort(local, stable=True)
-    sizes = jnp.zeros((held + 1,), jnp.int32).at[local].add(1)
+    groups = jnp.zeros((held + 1,), jnp.int32).at[local].add(1)[:held]
     token = order // k
     ours = (local[order] < held)[:, None]
-    # Every row has to lie in a group: a grouped product writes the rows of its groups and nothing else (on the
-    # TPU the others keep whatever the buffer held, NaNs among it, in the backward pass too).  The rows of
-    # experts held elsewhere come after the last group, so that group takes them in, as rows of nought: they
-    # give nought, take nought back, and add nought to the last expert's gradient.
-    groups = sizes[:held].at[held - 1].add(sizes[held])
     x = jnp.where(ours, jnp.take(m, token, axis=0), 0)
     dt = x.dtype
-    y = _ffn(w, x, lambda rows, mats: jax.lax.ragged_dot(rows, mats, groups))
-    y = jnp.where(ours, y.astype(jnp.float32) * weights.reshape(-1)[order][:, None], 0.0)
+
+    def dot(rows, mats):
+        # The rows of experts held elsewhere lie in NO group: a grouped product visits only the tiles that hold a
+        # row of a group, so its time follows the pairs routed here and not `N * k`.  It also writes the rows of its
+        # groups and nothing else (on the TPU the others keep whatever the buffer held, NaNs among it, in the
+        # backward pass too), so every product's result is cut to the held rows by a select, which its transpose
+        # repeats on the cotangent: nothing a product left unwritten reaches a sum, forward or backward.
+        return jnp.where(ours, jax.lax.ragged_dot(rows, mats, groups), 0)
+
+    y = jnp.where(ours, _ffn(w, x, dot).astype(jnp.float32) * weights.reshape(-1)[order][:, None], 0.0)
     return jnp.zeros((N, m.shape[-1]), jnp.float32).at[token].add(y).astype(dt)
 
 

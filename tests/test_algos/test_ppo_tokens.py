@@ -98,7 +98,10 @@ def test_spans_and_counters_of_a_run(tiny_run):
     pulls = [r for r in records if r.name == "stats.pull"]
     assert len(pulls) == 3
     for r in pulls:
-        assert set(r.counts) == {"moe_load_max", "moe_load_mean", "beyond_window", "steps", "carry_bytes", "cache_read", "cache_held"}
+        assert set(r.counts) == {"moe_load_max", "moe_load_mean", "beyond_window", "steps", "carry_bytes", "cache_read", "cache_held",
+                                 "moe_rows_run", "moe_rows_all"}
+        assert r.counts["moe_rows_all"] == 4 * 8 * 2 * 4  # tokens x k x expert layers
+        assert 0 < r.counts["moe_rows_run"] <= 256 and r.counts["moe_rows_run"] % 32 == 0  # under one tile: a pass's 32 rows, or none where no pair came here
         assert r.counts["cache_read"] == r.counts["cache_held"] == 4 * 8 * (4 * 8 + 32)  # caches this short are read whole
         assert r.counts["steps"] == 4 * 8 and 0 <= r.counts["beyond_window"] <= 32
         assert r.counts["moe_load_max"] >= r.counts["moe_load_mean"] > 0
@@ -145,7 +148,8 @@ def test_the_state_space_hybrid_runs_through_the_cli_with_its_scopes_its_event_a
     assert len(pulls) == 3
     for r in pulls:
         assert set(r.counts) == {"moe_load_max", "moe_load_mean", "beyond_window", "steps", "carry_bytes", "cache_read",
-                                 "cache_held", "ssm_state_bytes"}
+                                 "cache_held", "ssm_state_bytes", "moe_rows_run", "moe_rows_all"}
+        assert 0 < r.counts["moe_rows_run"] <= r.counts["moe_rows_all"]
         assert r.counts["steps"] == 4 * 8 and r.counts["ssm_state_bytes"] == 4 * 8 * 4 * 2 * state_and_window
         assert r.counts["carry_bytes"] == sum(by_kind.values()) and r.counts["beyond_window"] == 0
         assert r.counts["cache_read"] == r.counts["cache_held"] == 4 * 8 * 32  # one attention layer of nine holds a cache
@@ -212,6 +216,7 @@ def test_the_cell_s_readers_return_numbers(rehearsal):
     assert harness.load_module("metrics", "moe.load_max_over_mean").read(ctx) >= 1.0
     assert 0.0 < harness.load_module("metrics", "cache.beyond_window_pct").read(ctx) <= 100.0
     assert harness.load_module("metrics", "cache.read_pct").read(ctx) == 100.0  # the tiny caches are read whole
+    assert 50.0 < harness.load_module("metrics", "moe.rows_run_pct").read(ctx) <= 100.0  # a tiny update's 32 rows are under one tile: all of a pass, or none
     assert harness.load_module("metrics", "loop.host_ms_per_iter").read(ctx) >= 0.0
     assert h.program.flops_per_update(h.cfg, ctx["param_shapes"]) > 0
 
@@ -239,10 +244,11 @@ def test_the_hybrid_cell_matches_its_reference_and_its_readers_return_numbers(hy
     cell_metrics = harness.metric_names(h.spec["bench"], "lfm2_tokens_longgen", "per_layer")
     assert "carry.mb_per_env" in cell_metrics and "cache.beyond_window_pct" not in cell_metrics
     assert "cache.read_pct" in cell_metrics and harness.load_module("metrics", "cache.read_pct").read(ctx) == 100.0
+    assert "moe.rows_run_pct" in cell_metrics and 50.0 < harness.load_module("metrics", "moe.rows_run_pct").read(ctx) <= 100.0
 
 
 @pytest.mark.parametrize("reader, counted", [("carry.mb_per_env", ("carry_bytes",)), ("cache.read_pct", ("cache_read", "cache_held")),
-                                             ("ssm.state_mb_per_step", ("ssm_state_bytes",))])
+                                             ("ssm.state_mb_per_step", ("ssm_state_bytes",)), ("moe.rows_run_pct", ("moe_rows_run", "moe_rows_all"))])
 def test_a_count_s_reader_finds_nothing_in_a_program_that_does_not_count_it(hybrid_rehearsal, monkeypatch, reader, counted):
     """On a checkout from before its counter a reader returns nothing and does not raise."""
     from chipbench import harness, spanlog
@@ -294,7 +300,8 @@ def test_the_state_space_cell_matches_its_reference_and_its_readers_return_numbe
     assert read("tokens.dispatch_ms") > 0 and read("moe.load_max_over_mean") >= 1.0 and read("cache.read_pct") == 100.0
     assert read("loop.host_ms_per_iter") >= 0.0 and h.program.flops_per_update(h.cfg, ctx["param_shapes"]) > 0
     cell_metrics = harness.metric_names(h.spec["bench"], "nemotron3_tokens_longgen", "per_layer")
-    assert {"ssm.state_mb_per_step", "carry.mb_per_env", "cache.read_pct", "tokens.dispatch_ms", "moe.load_max_over_mean",
+    assert 50.0 < read("moe.rows_run_pct") <= 100.0
+    assert {"ssm.state_mb_per_step", "carry.mb_per_env", "cache.read_pct", "tokens.dispatch_ms", "moe.load_max_over_mean", "moe.rows_run_pct",
             "loop.stall_ms_per_iter", "loop.untracked_ms_per_iter", "step.mfu_pct", "device.idle_pct"} <= set(cell_metrics)
     assert "cache.beyond_window_pct" not in cell_metrics
     assert "ssm.state_mb_per_step" not in harness.metric_names(h.spec["bench"], "lfm2_tokens_longgen", "per_layer")

@@ -224,10 +224,12 @@ def test_routing_is_dropless_at_the_extremes(bias, all_here, tokens):
     np.testing.assert_allclose(got, want, **TOL)
 
 
-def test_gradients_through_the_grouped_product_match_the_dense_loop():
+@pytest.mark.parametrize("experts", [8, 16], ids=["3_of_8_held", "3_of_16_held"])
+def test_gradients_through_the_grouped_product_match_the_dense_loop(experts):
     """Rows of experts held elsewhere belong to no group of the grouped product; nothing of them may reach the
-    gradients (on a TPU such rows are left unwritten, so the layer cuts them off on both sides of the product)."""
-    cfg = config(experts_held=(2, 3))
+    gradients (on a TPU such rows are left unwritten, so the layer cuts them off on both sides of every product).
+    A held range that is not the first experts."""
+    cfg = config(experts_held=(2, 3), num_experts=experts)
     moe = decoder.init_params(cfg, jax.random.PRNGKey(8), std=0.1)["layer_1"]["moe"]
     m = jax.random.normal(jax.random.PRNGKey(9), (24, 64))
 
@@ -236,11 +238,72 @@ def test_gradients_through_the_grouped_product_match_the_dense_loop():
         return jnp.sum(jnp.sin(decoder._ffn(moe["shared"], m) + decoder.held_experts(moe["experts"], m, experts, weights, cfg)))
 
     def theirs(moe, m):
-        return jnp.sum(jnp.sin(ref.experts_part(moe, m, {**TINY, "experts_held": (2, 3)}, "f32", None)[0]))
+        return jnp.sum(jnp.sin(ref.experts_part(moe, m, {**TINY, "experts_held": (2, 3), "num_experts": experts}, "f32", None)[0]))
 
     got, want = jax.grad(ours, argnums=(0, 1))(moe, m), jax.grad(theirs, argnums=(0, 1))(moe, m)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(g, w, **TOL)
+
+
+def dense_loop(w, m, experts, weights, cfg):
+    """Every held expert over every token, weighted by the token's gate for it (nought where it was not chosen)."""
+    first, held = cfg.experts_held
+    out = jnp.zeros(m.shape, jnp.float32)
+    for e in range(held):
+        gate = jnp.sum(jnp.where(experts == first + e, weights, 0.0), axis=1)
+        out = out + decoder._ffn({name: mat[e] for name, mat in w.items()}, m) * gate[:, None]
+    return out
+
+
+def pairs_routed_here(n, how, tokens, cfg):
+    """Experts (tokens, k) with ``n`` of the ``tokens * k`` pairs routed to held experts (``how``: spread over them,
+    or all to ``one``) and the others to experts held elsewhere."""
+    first, held = cfg.experts_held
+    k = cfg.num_experts_per_tok
+    elsewhere = np.asarray([e for e in range(cfg.num_experts) if not first <= e < first + held])
+    flat = elsewhere[np.arange(tokens * k) % len(elsewhere)]
+    here = np.random.default_rng(n).permutation(tokens * k)[:n]
+    flat[here] = first + (np.arange(n) % held if how == "spread" else held - 1)
+    return jnp.asarray(flat.reshape(tokens, k), jnp.int32)
+
+
+@pytest.mark.parametrize("act", ["silu_gated", "relu2"])
+@pytest.mark.parametrize("held", [(0, 3), (5, 3)], ids=["the_first_experts", "experts_5_to_7"])
+@pytest.mark.parametrize("n, how", [(12, "spread"), (0, "spread"), (24, "spread"), (25, "spread"), (24, "one"), (48, "one"), (48, "spread")],
+                         ids=["an_even_router", "none_here", "half_the_pairs", "half_and_one", "half_to_one_expert",
+                              "every_pair_to_one_held_expert", "every_pair_here"])
+def test_the_held_experts_part_equals_the_dense_loop_whatever_share_is_routed_here(act, held, n, how):
+    """24 tokens' 48 sorted pairs, 3 of 16 experts held, ``n`` of the pairs routed to them: the grouped products run
+    over those ``n`` rows alone (the others lie in no group) and the part equals the dense loop, forward and in the
+    gradients to the matrices, the rows and the gates, for silu-gated experts and for ``relu^2`` ones; with no pair
+    routed here it is nought and so is every gradient."""
+    cfg = config(experts_held=held, num_experts=16, ffn_act=act)
+    w = decoder.init_params(cfg, jax.random.PRNGKey(11), std=0.1)["layer_1"]["moe"]["experts"]
+    assert set(w) == ({"w1", "w2"} if act == "relu2" else {"w1", "w2", "w3"})
+    m = jax.random.normal(jax.random.PRNGKey(12), (24, 64))
+    weights = jax.random.uniform(jax.random.PRNGKey(13), (24, 2), jnp.float32, 0.1, 1.0)
+    experts = pairs_routed_here(n, how, 24, cfg)
+    mix = jax.random.normal(jax.random.PRNGKey(14), (24, 64))
+    run = lambda layer: jax.value_and_grad(lambda w, m, weights: jnp.sum(layer(w, m, experts, weights, cfg) * mix), argnums=(0, 1, 2))(w, m, weights)  # noqa: E731
+    got, want = run(decoder.held_experts), run(dense_loop)
+    for g, d in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, d, **TOL)
+    if n == 0:
+        assert float(got[0]) == 0.0 and all(not np.asarray(g).any() for g in jax.tree.leaves(got[1]))
+
+
+def test_the_rows_counted_as_run_are_the_held_pairs_in_whole_tiles(monkeypatch):
+    """``rows_run`` (what the dispatch counts for ``moe.rows_run_pct``): the pairs routed to the held experts, the rows
+    ``held_experts`` puts into groups, rounded up to the grouped product's tiles and never more than all rows."""
+    cfg = config(experts_held=(2, 3), num_experts=16)
+    monkeypatch.setattr(decoder, "ROW_TILE", 8)
+    for n, rows in ((0, 0), (1, 8), (8, 8), (9, 16), (44, 48), (48, 48)):
+        counts = jnp.zeros((16,), jnp.int32).at[pairs_routed_here(n, "spread", 24, cfg).reshape(-1)].add(1)
+        assert int(counts[2:5].sum()) == n and int(decoder.rows_run(counts, 48, cfg)) == rows
+    stacked = jnp.stack([jnp.zeros((16,), jnp.int32).at[2].set(24), jnp.zeros((16,), jnp.int32).at[3].set(25).at[9].set(7)])
+    assert decoder.rows_run(stacked, 48, cfg).tolist() == [24, 32]  # a row of counts an expert layer
+    monkeypatch.setattr(decoder, "ROW_TILE", 512)
+    assert decoder.rows_run(stacked, 48, cfg).tolist() == [48, 48] and int(decoder.rows_run(stacked[0] * 0, 48, cfg)) == 0
 
 
 def test_the_bias_rule_over_one_update(params):

@@ -427,3 +427,30 @@ def test_state_space_update_compiles_for_v5e_at_nemotron3_widths(one_chip):
     compiled = jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
         params, carry, _spec(one_chip, 256, 8, dtype=jnp.int32), _spec(one_chip, 256, 8)).compile()
     assert 0 < compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
+
+
+def test_expert_layer_compiles_for_v5e_at_nemotron3_widths_as_one_product_a_matrix_with_every_result_cut_to_the_held_rows(one_chip):
+    """The expert layer's loss and gradient at the Nemotron-3 cut's widths (2,048 tokens, 6 of 128 experts a token, 8
+    held: 12,288 sorted (token, expert) pairs) compile for the chip as one grouped product a matrix and direction (two
+    forward, two to the rows, two to the matrices: ``relu^2``), no conditional and no loop around them (no second
+    program for an overflow: the products' time follows the rows in their groups), within the parent's temporaries
+    (228 MB there by the same compile), and a select of the rows' shape follows each product that gives rows."""
+    import re
+
+    from sheeprl_tpu.models import decoder
+
+    dc, params, _, _ = nemotron3_cut(one_chip)
+    rows = 2048 * dc.num_experts_per_tok
+
+    def loss(moe, m):
+        experts, weights, _ = decoder.route(moe, m, dc)
+        return jnp.sum(decoder.held_experts(moe["experts"], m, experts, weights, dc).astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params["layer_1"]["moe"], _spec(one_chip, 2048, dc.hidden_size, dtype=jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    products = re.findall(r"= bf16\[(\d+),[\d,]+\]\S* custom-call\(.*ragged-dot-none", text)
+    assert sorted(int(n) for n in products) == [dc.experts_held[1]] * 2 + [rows] * 4, products
+    assert " conditional(" not in text and " while(" not in text
+    assert re.search(rf"pred\[{rows},{dc.moe_intermediate_size}\]", text) and re.search(rf"pred\[{rows},{dc.hidden_size}\]", text)  # the cuts
+    assert 0 < compiled.memory_analysis().temp_size_in_bytes < 240 * 2**20
