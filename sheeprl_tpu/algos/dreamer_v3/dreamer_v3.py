@@ -96,20 +96,21 @@ def build_dv3_optimizers(fabric, cfg, params, saved_opt_state=None):
     """Optimizers + (replicated) opt state for the three param groups —
     shared by main(), __graft_entry__.py and the mesh tests so the program
     they check is the training program."""
-    wm_opt = build_optimizer(cfg.algo.world_model.optimizer, cfg.algo.world_model.clip_gradients)
-    actor_opt = build_optimizer(cfg.algo.actor.optimizer, cfg.algo.actor.clip_gradients)
-    critic_opt = build_optimizer(cfg.algo.critic.optimizer, cfg.algo.critic.clip_gradients)
-    # shard_params, not replicate: under TP the optimizer moments share the
-    # kernels' shapes, so the same column-sharding rule places them
-    # consistently with their params (no-op on a pure-data mesh)
-    opt_state = fabric.shard_params(
-        saved_opt_state
-        or {
-            "world_model": wm_opt.init(params["world_model"]),
-            "actor": actor_opt.init(params["actor"]),
-            "critic": critic_opt.init(params["critic"]),
-        }
-    )
+    with SPANS.setup_span("setup.optimizer"):
+        wm_opt = build_optimizer(cfg.algo.world_model.optimizer, cfg.algo.world_model.clip_gradients)
+        actor_opt = build_optimizer(cfg.algo.actor.optimizer, cfg.algo.actor.clip_gradients)
+        critic_opt = build_optimizer(cfg.algo.critic.optimizer, cfg.algo.critic.clip_gradients)
+        # shard_params, not replicate: under TP the optimizer moments share the
+        # kernels' shapes, so the same column-sharding rule places them
+        # consistently with their params (no-op on a pure-data mesh)
+        opt_state = fabric.shard_params(
+            saved_opt_state
+            or {
+                "world_model": wm_opt.init(params["world_model"]),
+                "actor": actor_opt.init(params["actor"]),
+                "critic": critic_opt.init(params["critic"]),
+            }
+        )
     return wm_opt, actor_opt, critic_opt, opt_state
 
 
@@ -172,9 +173,10 @@ def dreamer_family_loop(
     if state and state.get("key") is not None:
         # resume the train-dispatch RNG stream bit-exactly (rank-identical)
         key = jnp.asarray(state["key"])
-    world_model, actor, critic, params = build_agent_fn(
-        fabric, actions_dim, is_continuous, cfg, obs_space, state.get("agent")
-    )
+    with SPANS.setup_span("setup.agent"):
+        world_model, actor, critic, params = build_agent_fn(
+            fabric, actions_dim, is_continuous, cfg, obs_space, state.get("agent")
+        )
     WM = type(world_model)
     builder = optimizer_builder or build_dv3_optimizers
     wm_opt, actor_opt, critic_opt, opt_state = builder(
@@ -352,21 +354,23 @@ def dreamer_family_loop(
                 max(int(cfg.algo.learning_starts) // steps_per_iter, 1)
                 * steps_per_iter / fabric.world_size
             )
-            rb, train_phase_dev = build_device_replay(
-                fabric, cfg, capacity, num_envs, leaf_specs, _make_fused,
-                train_state=(params, opt_state) + ((sentinel.init_state(),) if sentinel is not None else ()),
-                first_window=burst,
-                batch_bytes=sampled_bytes(leaf_specs, batch_size, seq_len),
-                sequential=True, memmap_dir=memmap_dir, min_window=seq_len * 2,
-            )
+            with SPANS.setup_span("setup.replay"):  # the probe compile that sizes the ring, and the ring
+                rb, train_phase_dev = build_device_replay(
+                    fabric, cfg, capacity, num_envs, leaf_specs, _make_fused,
+                    train_state=(params, opt_state) + ((sentinel.init_state(),) if sentinel is not None else ()),
+                    first_window=burst,
+                    batch_bytes=sampled_bytes(leaf_specs, batch_size, seq_len),
+                    sequential=True, memmap_dir=memmap_dir, min_window=seq_len * 2,
+                )
         else:
-            rb = EnvIndependentReplayBuffer(
-                capacity,
-                n_envs=num_envs,
-                buffer_cls=SequentialReplayBuffer,
-                memmap=cfg.buffer.memmap,
-                memmap_dir=memmap_dir,
-            )
+            with SPANS.setup_span("setup.replay"):
+                rb = EnvIndependentReplayBuffer(
+                    capacity,
+                    n_envs=num_envs,
+                    buffer_cls=SequentialReplayBuffer,
+                    memmap=cfg.buffer.memmap,
+                    memmap_dir=memmap_dir,
+                )
     use_device_replay = isinstance(rb, DeviceReplay)
     guard_on = bool(cfg.buffer.get("transfer_guard", False)) and use_device_replay
     # a checkpoint only contains "rb" if it was saved with buffer.checkpoint
@@ -402,7 +406,8 @@ def dreamer_family_loop(
     # ---------------- env bookkeeping (reference: dreamer_v3.py:540-657) ----
     # rank-offset: each process's envs must be distinct streams or
     # multi-host DP collects the same data num_processes times
-    obs, _ = envs.reset(seed=cfg.seed + rank * num_envs)
+    with SPANS.setup_span("setup.env"):  # the envs' first reset
+        obs, _ = envs.reset(seed=cfg.seed + rank * num_envs)
     step_data: Dict[str, np.ndarray] = {}
     for k in obs_keys:
         step_data[k] = np.asarray(obs[k])[None]

@@ -130,7 +130,8 @@ def main(fabric: Any, cfg: Any) -> None:
         from sheeprl_tpu.envs.jax.registry import jax_env_from_cfg
 
         envs = None
-        venv = VectorJaxEnv(jax_env_from_cfg(cfg), num_envs)
+        with SPANS.setup_span("setup.env"):
+            venv = VectorJaxEnv(jax_env_from_cfg(cfg), num_envs)
         obs_space = venv.single_observation_space
         act_space = venv.single_action_space
     else:
@@ -160,17 +161,19 @@ def main(fabric: Any, cfg: Any) -> None:
     if state and state.get("key") is not None:
         # resume the train-dispatch RNG stream bit-exactly (rank-identical)
         key = jnp.asarray(state["key"])
-    agent, params = build_agent(
-        fabric, actions_dim, is_continuous, cfg, obs_space,
-        # population checkpoints hold STACKED (P, ...) params — restored in
-        # the population block below, not through the single-agent loader
-        None if (use_population and state) else state.get("agent"),
-    )
-    optimizer = build_optimizer(cfg.algo.optimizer, cfg.algo.max_grad_norm)
-    if use_population:
-        opt_state = None  # stacked per-member init happens in the population block
-    else:
-        opt_state = fabric.replicate(state.get("opt_state") or optimizer.init(params))
+    with SPANS.setup_span("setup.agent"):
+        agent, params = build_agent(
+            fabric, actions_dim, is_continuous, cfg, obs_space,
+            # population checkpoints hold STACKED (P, ...) params — restored in
+            # the population block below, not through the single-agent loader
+            None if (use_population and state) else state.get("agent"),
+        )
+    with SPANS.setup_span("setup.optimizer"):
+        optimizer = build_optimizer(cfg.algo.optimizer, cfg.algo.max_grad_norm)
+        if use_population:
+            opt_state = None  # stacked per-member init happens in the population block
+        else:
+            opt_state = fabric.replicate(state.get("opt_state") or optimizer.init(params))
 
     aggregator = MetricAggregator(
         cfg.metric.aggregator.metrics if cfg.metric.log_level > 0 else {}
@@ -531,9 +534,10 @@ def main(fabric: Any, cfg: Any) -> None:
                 donate_argnums=(0, 1, 2),
                 max_recompiles=cfg.algo.get("max_recompiles"),
             )
-            actor_state = init_actor_state(
-                fabric, venv, jax.random.fold_in(key, fabric.global_rank + 1), start_iter - 1, sharded_envs
-            )
+            with SPANS.setup_span("setup.env"):  # the envs' first reset
+                actor_state = init_actor_state(
+                    fabric, venv, jax.random.fold_in(key, fabric.global_rank + 1), start_iter - 1, sharded_envs
+                )
         rb = None
     else:
         rb = ReplayBuffer(
@@ -549,7 +553,8 @@ def main(fabric: Any, cfg: Any) -> None:
     # rank-offset: each process's envs must be distinct streams or
     # multi-host DP collects the same data num_processes times
     if envs is not None:
-        obs, _ = envs.reset(seed=cfg.seed + rank * num_envs)
+        with SPANS.setup_span("setup.env"):
+            obs, _ = envs.reset(seed=cfg.seed + rank * num_envs)
     last_losses = None
     # per-rank player key stream, advanced inside policy_step_fn; the main
     # `key` stays rank-identical for train dispatches

@@ -127,7 +127,8 @@ def main(fabric: Any, cfg: Any) -> None:
         from sheeprl_tpu.envs.jax.registry import jax_env_from_cfg
 
         envs = None
-        venv = VectorJaxEnv(jax_env_from_cfg(cfg), num_envs)
+        with SPANS.setup_span("setup.env"):
+            venv = VectorJaxEnv(jax_env_from_cfg(cfg), num_envs)
         obs_space = venv.single_observation_space
         act_space = venv.single_action_space
     else:
@@ -159,22 +160,25 @@ def main(fabric: Any, cfg: Any) -> None:
                 "algo.core=decoder runs on the fused path: it needs a pure-JAX env (env=jax_*) and "
                 "algo.run_test=False (the test plays a host episode)"
             )
-        agent, params = build_decoder_agent(
-            fabric, cfg, act_space, int(venv.env.max_episode_steps), state.get("agent")
-        )
+        with SPANS.setup_span("setup.agent"):
+            agent, params = build_decoder_agent(
+                fabric, cfg, act_space, int(venv.env.max_episode_steps), state.get("agent")
+            )
         core = agent
     elif kind == "lstm":
-        agent, params = build_agent(fabric, actions_dim, is_continuous, cfg, obs_space, state.get("agent"))
+        with SPANS.setup_span("setup.agent"):
+            agent, params = build_agent(fabric, actions_dim, is_continuous, cfg, obs_space, state.get("agent"))
         core = LSTMCore(agent)
     else:
         raise ValueError(f"Unknown algo.core '{kind}'; options: lstm, decoder")
     Agent = type(agent)
     act_width = core.prev_action_width
-    optimizer = build_optimizer(cfg.algo.optimizer, cfg.algo.max_grad_norm)
-    # made in place: a copy of Adam's state beside its source does not fit beside a model that fills the chip
-    opt_state = fabric.replicate(state["opt_state"]) if state.get("opt_state") else jax.jit(
-        optimizer.init, out_shardings=fabric.replicated
-    )(params)
+    with SPANS.setup_span("setup.optimizer"):
+        optimizer = build_optimizer(cfg.algo.optimizer, cfg.algo.max_grad_norm)
+        # made in place: a copy of Adam's state beside its source does not fit beside a model that fills the chip
+        opt_state = fabric.replicate(state["opt_state"]) if state.get("opt_state") else jax.jit(
+            optimizer.init, out_shardings=fabric.replicated
+        )(params)
 
     aggregator = MetricAggregator(cfg.metric.aggregator.metrics if cfg.metric.log_level > 0 else {})
     timer.configure(cfg.metric)
@@ -409,18 +413,20 @@ def main(fabric: Any, cfg: Any) -> None:
             donate_argnums=(0, 1, 2),
             max_recompiles=cfg.algo.get("max_recompiles"),
         )
-        actor_state = init_actor_state(
-            fabric, venv, jax.random.fold_in(key, fabric.global_rank + 1),
-            start_iter - 1,
-            sharded=num_envs % fabric.local_world_size == 0,
-            extra={
-                "carry": core.initial_state(num_envs),
-                "prev_actions": jnp.zeros((num_envs, act_width), jnp.float32),
-                "is_first": jnp.ones((num_envs, 1), jnp.float32),
-            },
-        )
+        with SPANS.setup_span("setup.env"):  # the envs' first reset, and the carry they start from
+            actor_state = init_actor_state(
+                fabric, venv, jax.random.fold_in(key, fabric.global_rank + 1),
+                start_iter - 1,
+                sharded=num_envs % fabric.local_world_size == 0,
+                extra={
+                    "carry": core.initial_state(num_envs),
+                    "prev_actions": jnp.zeros((num_envs, act_width), jnp.float32),
+                    "is_first": jnp.ones((num_envs, 1), jnp.float32),
+                },
+            )
         if hasattr(venv.env, "warm_start") and hasattr(core, "prefill"):
-            actor_state = _warm_start(fabric, cfg, venv, core, params, actor_state, jax.random.fold_in(key, 7))
+            with SPANS.setup_span("setup.prefill"):
+                actor_state = _warm_start(fabric, cfg, venv, core, params, actor_state, jax.random.fold_in(key, 7))
     guard_anakin = bool(cfg.buffer.get("transfer_guard", False))
 
     from sheeprl_tpu.utils.profiler import ProfilerGate

@@ -96,6 +96,7 @@ def run_algorithm(cfg: dotdict) -> None:
 
     import sheeprl_tpu
     from sheeprl_tpu.parallel.fabric import build_fabric
+    from sheeprl_tpu.telemetry import SPANS
 
     sheeprl_tpu.register_all_algorithms()
     import_extra_modules(cfg)
@@ -104,7 +105,8 @@ def run_algorithm(cfg: dotdict) -> None:
 
     if cfg.get("matmul_precision"):
         jax.config.update("jax_default_matmul_precision", cfg.matmul_precision)
-    fabric = build_fabric(cfg)
+    with SPANS.setup_span("setup.fabric"):  # the backend's start
+        fabric = build_fabric(cfg)
     entrypoint(fabric, cfg)
     _maybe_register_models(fabric, cfg)
 
@@ -201,6 +203,11 @@ def resolve_resume_target(cfg: dotdict) -> dotdict:
 
 
 def run(argv: Optional[List[str]] = None) -> None:
+    # the run's own account of its set-up starts here: the root span `setup`
+    # stays open until the loop's first iteration (docs/telemetry.md)
+    from sheeprl_tpu import telemetry
+
+    telemetry.SPANS.begin_setup()
     argv = list(sys.argv[1:] if argv is None else argv)
     # a preemption latched during a PREVIOUS run in this interpreter was
     # honored by that run's final save; this run starts un-preempted
@@ -211,8 +218,6 @@ def run(argv: Optional[List[str]] = None) -> None:
     # over from a previous run in this interpreter must not receive THIS
     # run's final flush, and a postmortem written by this run must hold
     # this run's events — not a previous drill's fault trail
-    from sheeprl_tpu import telemetry
-
     telemetry.HUB.reset()
     # a crashed loop never reached its sentinel teardown: drop the stale
     # run-scoped Health/* and Population/* sources so they cannot leak
@@ -220,19 +225,22 @@ def run(argv: Optional[List[str]] = None) -> None:
     telemetry.HUB.unregister("health")
     telemetry.HUB.unregister("population")
     telemetry.RECORDER.clear()
-    cfg = compose(argv)
-    # arm (or explicitly clear) the fault-injection plan before anything
-    # else touches envs/checkpoints — SHEEPRL_FAULT_PLAN wins over the group
-    from sheeprl_tpu.resilience import install_from_config
+    telemetry.COMPILE_MONITOR.install()  # before the first program of the run is built
+    with telemetry.SPANS.setup_span("setup.compose"):
+        cfg = compose(argv)
+        # arm (or explicitly clear) the fault-injection plan before anything
+        # else touches envs/checkpoints — SHEEPRL_FAULT_PLAN wins over the group
+        from sheeprl_tpu.resilience import install_from_config
 
-    install_from_config(cfg)
-    cfg = resolve_resume_target(cfg)
-    if cfg.checkpoint.get("resume_from"):
-        cfg = resume_from_checkpoint(cfg)
+        install_from_config(cfg)
+        cfg = resolve_resume_target(cfg)
+        if cfg.checkpoint.get("resume_from"):
+            cfg = resume_from_checkpoint(cfg)
     import sheeprl_tpu
 
-    sheeprl_tpu.register_all_algorithms()
-    import_extra_modules(cfg)
+    with telemetry.SPANS.setup_span("setup.register"):
+        sheeprl_tpu.register_all_algorithms()
+        import_extra_modules(cfg)
     check_configs(cfg)
     from sheeprl_tpu.utils.utils import print_config
 

@@ -34,7 +34,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from sheeprl_tpu.telemetry.recorder import RECORDER
-from sheeprl_tpu.telemetry.spans import span
+from sheeprl_tpu.telemetry.spans import SPANS, span
 
 
 @dataclass(frozen=True)
@@ -780,7 +780,8 @@ class Fabric:
         (this rank's shard, falling back to shard 0)."""
         from sheeprl_tpu.utils.checkpoint import load_checkpoint
 
-        return load_checkpoint(path, rank=self.global_rank)
+        with SPANS.setup_span("setup.resume"):
+            return load_checkpoint(path, rank=self.global_rank)
 
     # -- misc ---------------------------------------------------------------
     def print(self, *args: Any, **kwargs: Any) -> None:

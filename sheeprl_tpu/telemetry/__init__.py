@@ -95,11 +95,13 @@ def setup_run(cfg: Any, log_dir: Optional[str], rank: int = 0) -> None:
 
 
 def shutdown_run() -> None:
-    """End-of-run teardown: close the iteration span a raising loop left open,
-    stop an open trace window and the introspection server.  Called from the
+    """End-of-run teardown: close the iteration span a raising loop left open
+    (and ``setup``, where the run never reached a loop), stop an open trace
+    window and the introspection server.  Called from the
     ``finally`` path of ``cli.run``; the span log stays (``SPANS.records()`` is
     read after the run)."""
     SPANS.end_iteration()
+    SPANS.end_setup()
     TRACER.close()
     global _SERVER
     with _SERVER_LOCK:

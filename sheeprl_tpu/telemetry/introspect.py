@@ -10,7 +10,8 @@ every handler is a dict read) to expose the telemetry subsystem:
 * ``GET /healthz``     — liveness: pid, uptime, run dir, hub sources
 * ``GET /metrics``     — every hub metric in Prometheus text exposition
   format (``text/plain; version=0.0.4``), ready for a scrape config
-* ``GET /v1/phase``    — the span tracker's current phase breakdown
+* ``GET /v1/phase``    — the span tracker's current phase breakdown, and
+  under ``open`` every span open now with its age
 * ``GET /v1/recorder`` — the flight recorder's newest events (``?n=``)
 
 Armed per run via ``telemetry.introspect.port`` (``0`` binds an
@@ -185,7 +186,7 @@ def _make_handler(server: IntrospectionServer):
                         200, prometheus_text(metrics).encode(), PROMETHEUS_CONTENT_TYPE
                     )
                 elif path == "/v1/phase":
-                    self._reply_json(200, SPANS.breakdown())
+                    self._reply_json(200, dict(SPANS.breakdown(), open=SPANS.open_spans()))
                 elif path == "/v1/recorder":
                     qs = parse_qs(parsed.query)
                     n = None

@@ -16,6 +16,7 @@ reconstruct the last minutes of a dead run from one file.
 from __future__ import annotations
 
 import os
+import sys
 import threading
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -29,6 +30,25 @@ class RecompileLimitExceeded(RuntimeError):
 
 #: JAX's own event around every backend compile (or persistent-cache read)
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+#: JAX's duration events of one compile -> the closed span record each becomes
+COMPILE_RECORDS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    BACKEND_COMPILE_EVENT: "compile.backend",
+}
+
+#: JAX's plain event for an executable read from the persistent cache (on the
+#: compiling thread, inside the backend-compile event it belongs to)
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+#: a backend compile this long is also one stderr line
+COMPILE_LINE_S = 1.0
+
+#: a trace event shorter than this is not written: one program traces some
+#: 1,500 inner ``jit``s (every ``jnp`` function is one), nine in ten of them
+#: under a millisecond and nested in a longer one
+TRACE_FLOOR_S = 1e-3
 
 
 class CompileMonitor:
@@ -44,14 +64,20 @@ class CompileMonitor:
     ``fabric.compile`` programs are not all a run compiles: the replay
     ring's ``jax.jit`` scatter builds a program for every count of envs
     that finish at once.  :meth:`install` therefore also listens to JAX's
-    own compile event, counts it (``Compile/backend_compiles``) and writes
-    a ``compile.backend`` recorder event naming the span open on the
-    compiling thread and its loop iteration — which is how an operator
-    learns which step recompiled.
+    own compile events and writes each into the span log as one closed
+    record (``compile.trace``, ``compile.lower``, ``compile.backend``: the
+    one place, ``fabric.compile`` programs and plain ``jax.jit`` ones
+    alike).  A backend compile is also counted
+    (``Compile/backend_compiles``), says whether the persistent cache was
+    hit (``cache_hit``), and is a ``compile.backend`` recorder event naming
+    the span open on the compiling thread and its loop iteration — which
+    is how an operator learns which step recompiled, and whether a slow
+    start was a cold cache.
     """
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
+        self._local = threading.local()  # cache_hit: set by the cache's event, taken by the compile's
         self._stats: Dict[str, Dict[str, Any]] = {}
         self._backend = [0, 0.0]  # count, seconds
         self._backend_flushed = 0  # the count the last rolling flush logged
@@ -67,12 +93,25 @@ class CompileMonitor:
         import jax
 
         jax.monitoring.register_event_duration_secs_listener(self._on_jax_event)
+        jax.monitoring.register_event_listener(self._on_jax_mark)
 
-    def _on_jax_event(self, event: str, duration: float, **_: Any) -> None:
-        if event != BACKEND_COMPILE_EVENT:
+    def _on_jax_mark(self, event: str, **_: Any) -> None:
+        if event == CACHE_HIT_EVENT:
+            self._local.cache_hit = True
+
+    def _on_jax_event(self, event: str, duration: float, **fields: Any) -> None:
+        name = COMPILE_RECORDS.get(event)
+        if name is None:
             return
         from sheeprl_tpu.telemetry.spans import SPANS
 
+        if event != BACKEND_COMPILE_EVENT:
+            if duration >= TRACE_FLOOR_S or name != "compile.trace":
+                SPANS.closed(name, duration)
+            return
+        hit = int(getattr(self._local, "cache_hit", False))
+        self._local.cache_hit = False
+        SPANS.closed(name, duration, {"cache_hit": hit})
         with self._lock:
             self._backend[0] += 1
             self._backend[1] += float(duration)
@@ -82,7 +121,11 @@ class CompileMonitor:
             seconds=round(float(duration), 3),
             span=under.name if under is not None else None,
             iteration=under.iteration if under is not None else None,
+            cache_hit=hit,
         )
+        if duration >= COMPILE_LINE_S:
+            what = under.name if under is not None else fields.get("fun_name", "?")
+            print(f"{name} {what} {duration:.1f} s {'hit' if hit else 'miss'}", file=sys.stderr, flush=True)
 
     def backend_totals(self) -> Tuple[int, float]:
         """(compile events seen, their seconds): every program, jitted or AOT."""
@@ -135,7 +178,6 @@ class CompileMonitor:
             st = self._stats.get(name)
             if st is not None:
                 st["seconds"] += float(seconds)
-        RECORDER.record("compile", name=name, seconds=round(float(seconds), 3))
 
     @staticmethod
     def default_limit() -> Optional[int]:
@@ -169,11 +211,6 @@ class CompileMonitor:
                 }
                 for name, st in self._stats.items()
             }
-
-    def delta_report(self, mark: Tuple[int, float]) -> str:
-        """One human line of what compiled since ``mark`` (from totals())."""
-        count, seconds = self.totals()
-        return f"{count - mark[0]} executables / {seconds - mark[1]:.1f}s compile"
 
     def compile_metrics(self) -> Dict[str, float]:
         """Aggregate counters for the hub flush (see metric.flush_metrics)."""

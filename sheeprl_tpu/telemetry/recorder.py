@@ -130,6 +130,10 @@ class FlightRecorder:
             from sheeprl_tpu.telemetry.spans import SPANS
 
             doc["phase_breakdown"] = SPANS.breakdown()
+            # what the run was inside when it ended: a run killed in set-up
+            # names the span it died in (the newest `compile.backend` event
+            # above is the last compile that finished)
+            doc["open_spans"] = SPANS.open_spans()
         except Exception:
             doc["phase_breakdown"] = None
         return doc

@@ -29,6 +29,16 @@ push/pop pair:
 
 Span taxonomy (docs/telemetry.md has the table with counts and parents):
 
+* ``setup``            — the run's set-up: opened as the first thing
+  ``cli.run`` does (:meth:`SpanTracker.begin_setup`), closed by the first
+  ``iter`` (or the first phase) on that thread; counts ``pre_run_ms``, the
+  process's age at ``cli.run``'s entry.  Its children ``setup.compose``,
+  ``.register``, ``.fabric``, ``.logger``, ``.env``, ``.agent``,
+  ``.optimizer``, ``.replay``, ``.resume``, ``.prefill`` (and ``.import``)
+  are opened where that work is (:meth:`SpanTracker.setup_span`)
+* ``compile.trace`` / ``compile.lower`` / ``compile.backend`` — one CLOSED
+  record per JAX compile event (:meth:`SpanTracker.closed`, written by
+  ``COMPILE_MONITOR``); ``compile.backend`` counts ``cache_hit``
 * ``iter``             — one loop iteration (:meth:`SpanTracker.iteration`,
   called beside ``profiler.step(update)``); every span below that the
   loop's thread opens is its descendant and carries its ``iteration``
@@ -67,8 +77,9 @@ Two kinds of span, chosen where the span is opened.  A PHASE (the default:
 TOP-LEVEL: opening a top-level ``update.dispatch`` ticks the trace
 scheduler (``tracer.py``), closing one feeds ``/healthz`` liveness, and
 top-level phase edges are flight-recorder events.  A BOUNDARY
-(``phase=False``: ``iter``, ``exec.*``, ``env.step``, ``player.sync``,
-``stats.pull``, ``log.flush``, ``health.poll``, ``ckpt.save``) is a record
+(``phase=False``: ``setup``, ``setup.*``, ``iter``, ``exec.*``, ``env.step``,
+``player.sync``, ``stats.pull``, ``log.flush``, ``health.poll``,
+``ckpt.save``) is a record
 and a profiler annotation and nothing else: its time stays with the phase
 it runs under (else ``Phase/other``), it makes no phase less top-level and
 writes no recorder event — so ``Phase/*``, ``/v1/phase``, ``/healthz`` and
@@ -87,6 +98,8 @@ phases.
 from __future__ import annotations
 
 import itertools
+import os
+import sys
 import threading
 import time
 from collections import deque
@@ -107,6 +120,9 @@ TIMER_PHASES: Dict[str, str] = {
 #: the iteration span: the frame every other span of a loop thread hangs under
 ITER = "iter"
 
+#: the set-up root: ``cli.run``'s entry to the loop's first iteration
+SETUP = "setup"
+
 #: closed spans kept in memory (a DV3 iteration closes about 20)
 RECORD_CAPACITY = 32768
 
@@ -125,6 +141,20 @@ class SpanRecord(NamedTuple):
     iteration: Optional[int]
     thread: str
     counts: Optional[Dict[str, int]]
+
+
+def process_age_ms() -> Optional[int]:
+    """Milliseconds since the OS started this process (it survives an
+    ``execv``: the interpreter, ``import jax`` and whatever the caller did
+    before ``cli.run``).  None where the platform gives no start time."""
+    try:
+        with open("/proc/self/stat") as f:
+            started = int(f.read().rsplit(")", 1)[1].split()[19])  # field 22: ticks after boot
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        return max(0, int((uptime - started / os.sysconf("SC_CLK_TCK")) * 1e3))
+    except (OSError, ValueError, IndexError):
+        return None
 
 
 _marks: Optional[Tuple[Any, Any]] = None  # jax.profiler's two annotation classes, looked up once
@@ -179,6 +209,8 @@ class SpanTracker:
         self._excl: Dict[str, float] = {}
         self._counts: Dict[str, int] = {}
         self._records: Deque[SpanRecord] = deque(maxlen=RECORD_CAPACITY)
+        self._stacks: Dict[int, Tuple[str, list]] = {}  # thread ident -> (name, its stack): the open-span view
+        self._setup: Optional[_Span] = None  # the set-up root while it is open
         self._window_start = _now()
         # liveness signal for /healthz (introspect.py): wall time of the
         # newest COMPLETED top-level update.dispatch span + total count —
@@ -192,12 +224,16 @@ class SpanTracker:
         """Apply the ``telemetry.spans`` config group."""
         cfg = cfg or {}
         self.enabled = bool(cfg.get("enabled", True))
+        if not self.enabled:
+            self._discard_setup()
 
     # -- the span stack ------------------------------------------------------
     def _stack(self) -> list:
         stack = getattr(self._local, "stack", None)
         if stack is None:
             stack = self._local.stack = []
+            with self._lock:
+                self._stacks[threading.get_ident()] = (threading.current_thread().name, stack)
         return stack
 
     def push(
@@ -218,6 +254,8 @@ class SpanTracker:
         iteration."""
         if not self.enabled:
             return None
+        if phase and self._setup is not None:
+            self.end_setup()  # a loop with no `iter` frame: its first phase ends set-up
         stack = self._stack()
         under = stack[-1] if stack else None
         origin = under if under is not None else cause
@@ -277,6 +315,25 @@ class SpanTracker:
         finally:
             self.pop(token)
 
+    def closed(self, name: str, seconds: float, counts: Optional[Dict[str, int]] = None) -> None:
+        """One record of an interval that is already over (a compile, as JAX's
+        own event reports it): it ends now and began ``seconds`` ago; parent,
+        iteration and thread are those of the span open on this thread.  No
+        profiler annotation, no part of ``Phase/*``.  Such intervals may nest
+        (an inner ``jit`` traced inside an outer one): readers take their
+        union, never their sum."""
+        if not self.enabled:
+            return
+        end = _now()
+        under = self.current()
+        parent, iteration = (under.id, under.iteration) if under is not None else (None, None)
+        record = SpanRecord(
+            name, end - max(0.0, float(seconds)), end, next(_ids), parent, iteration,
+            threading.current_thread().name, counts,
+        )
+        with self._lock:
+            self._records.append(record)
+
     def depth(self) -> int:
         return len(self._stack())
 
@@ -287,10 +344,96 @@ class SpanTracker:
         stack = self._stack()
         return stack[-1] if stack else None
 
+    # -- set-up --------------------------------------------------------------
+    def begin_setup(self) -> None:
+        """The first thing ``cli.run`` does: open the root boundary ``setup``
+        on this thread.  It counts ``pre_run_ms`` (:func:`process_age_ms` at
+        this call; left out where the platform gives none) and stays open
+        until the loop's first :meth:`iteration` (or first phase), or
+        ``telemetry.shutdown_run``.  A new run starts with the default knobs:
+        ``setup_run`` applies its config later, from inside ``get_logger``,
+        and a run that turns spans off drops what was opened here."""
+        entered, age = _now(), process_age_ms()
+        self._discard_setup()
+        self.enabled = True
+        first = "jax" not in sys.modules  # `python -m sheeprl_tpu`: the annotation below imports it
+        root = self.push(SETUP, None if age is None else {"pre_run_ms": age}, phase=False)
+        root.start = entered
+        self._setup = root
+        if first:
+            self.closed("setup.import", _now() - entered)
+
+    def end_setup(self) -> None:
+        """Close ``setup`` (and whatever leaked under it) where this thread opened it."""
+        root = self._setup
+        if root is not None and root in self._stack():
+            self._setup = None
+            self.pop(root)
+            self._announce(root)
+
+    def _discard_setup(self) -> None:
+        """Spans are off for this run: unwind ``setup`` and drop this run's records."""
+        root, self._setup = self._setup, None
+        stack = self._stack()
+        if root is None or root not in stack:
+            return
+        while stack:
+            span = stack.pop()
+            span.mark.__exit__(None, None, None)
+            if span is root:
+                break
+        with self._lock:
+            kept = [r for r in self._records if r.id < root.id]
+            self._records.clear()
+            self._records.extend(kept)
+
+    @contextmanager
+    def setup_span(self, name: str):
+        """A ``setup.*`` child, opened where the work is: a boundary whose
+        close is also one flight-recorder event and one stderr line, so a
+        slow start can be watched while it runs.  Nothing outside a run's
+        set-up (the shared constructors also serve evaluation, the server and
+        a loop that rebuilds an env mid-run)."""
+        root = self._setup
+        if root is None or root not in self._stack():
+            yield None
+            return
+        token = self.push(name, phase=False)
+        try:
+            yield token
+        finally:
+            if token in self._stack():  # not where this run's config turned spans off meanwhile
+                self.pop(token)
+                self._announce(token)
+
+    @staticmethod
+    def _announce(span: _Span) -> None:
+        seconds = _now() - span.start
+        RECORDER.record("setup", name=span.name, seconds=round(seconds, 3))
+        print(f"{span.name} {seconds:.2f} s", file=sys.stderr, flush=True)
+
+    def open_spans(self) -> List[Dict[str, Any]]:
+        """Every span open now, on any thread, oldest first, with its age:
+        what a run that hangs or is killed was inside (``/v1/phase``, the
+        postmortem)."""
+        now, alive = _now(), {t.ident for t in threading.enumerate()}
+        with self._lock:
+            for ident in [i for i, (_, stack) in self._stacks.items() if not stack and i not in alive]:
+                del self._stacks[ident]
+            stacks = [(thread, list(stack)) for thread, stack in self._stacks.values()]
+        spans = [(s, thread) for thread, stack in stacks for s in stack]
+        return [
+            {"name": s.name, "age_s": round(now - s.start, 3), "thread": thread, "iteration": s.iteration, "id": s.id}
+            for s, thread in sorted(spans, key=lambda st: st[0].start)
+        ]
+
     # -- the iteration frame -------------------------------------------------
     def iteration(self, update: int) -> None:
         """Top of a loop iteration: close the iteration span open on this
-        thread (and whatever leaked under it) and open the one for ``update``."""
+        thread (and whatever leaked under it) and open the one for ``update``.
+        The first one of a run closes ``setup``."""
+        if self._setup is not None:
+            self.end_setup()
         self.end_iteration()
         self._local.iter = self.push(ITER, iteration=int(update), phase=False)
 
@@ -379,6 +522,7 @@ class SpanTracker:
         while stack:
             stack.pop().mark.__exit__(None, None, None)
         self._local.iter = None
+        self._setup = None
         self.roll_window()
         self.enabled = True
         with self._lock:

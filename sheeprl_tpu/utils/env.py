@@ -477,19 +477,22 @@ def vectorize(cfg: Any, thunks: list) -> gym.vector.VectorEnv:
     nothing to watchdog from inside the process."""
     from gymnasium.vector import AutoresetMode
 
+    from sheeprl_tpu.telemetry.spans import SPANS
+
     def make() -> gym.vector.VectorEnv:
         return gym.vector.AsyncVectorEnv(thunks, autoreset_mode=AutoresetMode.SAME_STEP)
 
     deadline = float(cfg.env.get("step_deadline_s", 0) or 0)
-    if cfg.env.sync_env:
-        envs = gym.vector.SyncVectorEnv(thunks, autoreset_mode=AutoresetMode.SAME_STEP)
-    elif deadline > 0:
-        envs = StepDeadlineVectorEnv(
-            make,
-            deadline,
-            max_restarts=int(cfg.env.get("max_vecenv_restarts", 3) or 3),
-            window_s=float(cfg.env.get("vecenv_restart_window_s", 600.0) or 600.0),
-        )
-    else:
-        envs = make()
+    with SPANS.setup_span("setup.env"):
+        if cfg.env.sync_env:
+            envs = gym.vector.SyncVectorEnv(thunks, autoreset_mode=AutoresetMode.SAME_STEP)
+        elif deadline > 0:
+            envs = StepDeadlineVectorEnv(
+                make,
+                deadline,
+                max_restarts=int(cfg.env.get("max_vecenv_restarts", 3) or 3),
+                window_s=float(cfg.env.get("vecenv_restart_window_s", 600.0) or 600.0),
+            )
+        else:
+            envs = make()
     return _span_step(envs)

@@ -144,9 +144,16 @@ def get_logger(fabric: Any, cfg: Any, log_dir: str) -> Optional[Any]:
     ``cli.run`` can land the last metric window after a crash."""
     from sheeprl_tpu import telemetry
 
-    telemetry.setup_run(
-        cfg, log_dir, rank=fabric.global_rank if fabric is not None else 0
-    )
+    with telemetry.SPANS.setup_span("setup.logger"):
+        telemetry.setup_run(
+            cfg, log_dir, rank=fabric.global_rank if fabric is not None else 0
+        )
+        return _make_logger(fabric, cfg, log_dir)
+
+
+def _make_logger(fabric: Any, cfg: Any, log_dir: str) -> Optional[Any]:
+    from sheeprl_tpu import telemetry
+
     if fabric is not None and fabric.global_rank != 0:
         return None
     if getattr(cfg.metric, "log_level", 1) <= 0:

@@ -130,8 +130,10 @@ class TestDreamerV3:
 
     def test_the_one_count_is_the_pull_and_phase_keys_are_the_phases(self, dv3):
         records, _ = dv3
-        # a count is kept where a metric reads it: the bytes of the weight pull (none in a run that trains once)
-        assert all(r.counts is None for r in records if r.name != "player.sync")
+        # a count is kept where a metric reads it: the bytes of the weight pull (none in a run that trains once),
+        # the process's age on the set-up root and whether a backend compile was a cache read
+        counted = {"player.sync": {"bytes"}, "setup": {"pre_run_ms"}, "compile.backend": {"cache_hit"}}
+        assert all(set(r.counts or ()) <= counted.get(r.name, set()) for r in records)
         assert all((r.counts or {}).get("bytes", 0) == 0 for r in records if r.name == "player.sync")
         # the boundaries (iter, exec.*, env.step, player.sync, log.flush, ckpt.save) are no part of Phase/*
         assert set(SPANS.breakdown()["phases"]) <= {"rollout", "update.dispatch", "replay.write", "ckpt.snapshot"}
