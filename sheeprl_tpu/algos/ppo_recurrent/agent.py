@@ -169,7 +169,8 @@ class LSTMCore:
 
     The loop drives a core through these calls alone and does not know which one it holds:
     ``policy_step`` (one step on the carry), ``policy_segment`` (a rollout's segment from the carry at its
-    start; a third result where the core has a router: its counts), ``encode_prev`` (the action as the next
+    start; a third result where the core has a router: its counts; a fourth where it has a loss of its own beside
+    PPO's: that loss per step), ``encode_prev`` (the action as the next
     step's input), ``acting_params`` (the weights as the rollout reads them), ``initial_state``,
     ``stores_values`` (the rollout keeps its values: no second pass over every token), and for a core with
     more to tell or to keep: ``init_aux``/``after_update`` (state that moves with every update),
@@ -189,7 +190,7 @@ class LSTMCore:
         )
 
     def policy_segment(self, p, obs_seq, prev_actions_seq, is_first_seq, carry):
-        return self.agent.apply(p, obs_seq, prev_actions_seq, is_first_seq, carry) + (None,)
+        return self.agent.apply(p, obs_seq, prev_actions_seq, is_first_seq, carry) + (None, None)
 
     def encode_prev(self, actions):
         return one_hot_actions(actions, self.agent.actions_dim, self.agent.is_continuous)
@@ -231,8 +232,11 @@ class DecoderPPOAgent:
         self.prefill_chunk = self.window or config.max_len
         self.carry_bytes = decoder.carry_bytes(config, self.carry_dtype)  # an env, by kind of layer
         sizes = [config.cache_len(i) for i in config.layers_of(decoder.SLIDING, decoder.FULL)]
-        self.cache_held = sum(sizes)  # positions a decode step's attention layers hold an env
+        self.sparse = len(config.layers_of(decoder.SPARSE))  # layers that read the rows their indexer selects
+        self.cache_held = sum(sizes) + self.sparse * config.max_len  # positions a decode step's attention layers hold an env
         self.ragged_sizes = [s for s in sizes if decode_attention.engages(s)]  # of the layers read as far as written
+        # bytes of one position's index key, which a sparse layer's decode step scores at every written position
+        self.index_key_bytes = config.index_head_dim * jnp.dtype(self.carry_dtype).itemsize
         # bytes of state and convolution window a decode step reads, and writes again, of an env's Mamba-2 layers
         self.ssm_bytes = self.carry_bytes.get(decoder.MAMBA, 0)
 
@@ -243,14 +247,19 @@ class DecoderPPOAgent:
         return decoder.init_carry(self.config, batch, self.carry_dtype)
 
     def policy_step(self, p, carry, obs, prev_actions, is_first):
+        """One decode step; a model with sparse layers also tells the rollout the slots each selected (B, layers,
+        topk), which ``correct`` compares with the reference's choice."""
+        selected = []
         carry, logits, value = decoder.step(
-            p["params"], self.config, carry, obs[self.key][..., 0], is_first[..., 0], self.dtype
+            p["params"], self.config, carry, obs[self.key][..., 0], is_first[..., 0], self.dtype, selected
         )
+        if selected:
+            return carry, (logits, value, {"selected": jnp.stack(selected, axis=1)})
         return carry, (logits, value)
 
     def policy_segment(self, p, obs_seq, prev_actions_seq, is_first_seq, carry):
         return decoder.segment(
-            p["params"], self.config, carry, obs_seq[self.key][..., 0], is_first_seq[..., 0], self.dtype
+            p["params"], self.config, carry, obs_seq[self.key][..., 0], is_first_seq[..., 0], self.dtype, index_loss=True
         )
 
     def prefill(self, p, carry, tokens, valid):
@@ -290,12 +299,16 @@ class DecoderPPOAgent:
         pos, _ = decoder.segment_positions(rollout["is_first"][..., 0], init_carry["pos"])
         beyond = jnp.zeros((), jnp.int32) if self.window is None else jnp.sum(pos >= self.window)
         kept = ("actions", "logprobs", "values", "rewards", "dones", "is_first", "mask")
-        return {
+        stats = {
             **{k: rollout[k] for k in kept},
             "tokens": rollout[self.key], "next_tokens": venv.observe(actor["env"])[self.key],
             "next_is_first": actor["is_first"],
             "beyond_window": beyond, "steps": jnp.asarray(pos.size, jnp.int32), "cache_blocks": self.cache_blocks(pos),
         }
+        if self.sparse:  # rows fetched of the caches, index keys scored, and the slots selected, step by step
+            stats.update(sparse_rows=self.sparse * jnp.sum(jnp.minimum(pos + 1, self.config.index_topk)),
+                         index_positions=self.sparse * jnp.sum(pos + 1), selected=rollout["selected"])
+        return stats
 
     def cache_blocks(self, pos) -> jax.Array:
         """Blocks of positions the ragged attention layers fetch over decode steps at the positions ``pos``."""
@@ -303,21 +316,25 @@ class DecoderPPOAgent:
             (jnp.sum(decode_attention.blocks_read(jnp.minimum(pos + 1, size))) for size in self.ragged_sizes),
             start=jnp.zeros((), jnp.int32))
 
-    def cache_counts(self, steps: int, blocks: int) -> Dict[str, int]:
+    def cache_counts(self, steps: int, blocks: int, sparse_rows: int = 0) -> Dict[str, int]:
         """Positions ``steps`` decode steps fetched from the attention caches (whole blocks of a ragged layer, all
-        of a plain one), and positions those caches held."""
-        plain = self.cache_held - sum(self.ragged_sizes)
-        return {"cache_read": blocks * decode_attention.BLOCK + steps * plain, "cache_held": steps * self.cache_held}
+        of a plain one, the ``sparse_rows`` selected of a sparse one), and positions those caches held."""
+        plain = self.cache_held - sum(self.ragged_sizes) - self.sparse * self.config.max_len
+        return {"cache_read": blocks * decode_attention.BLOCK + steps * plain + sparse_rows, "cache_held": steps * self.cache_held}
 
     def host_counts(self, stats) -> Dict[str, Any]:
         first, held = self.config.experts_held
         routed = np.asarray(stats["load"])  # the router's counts of the dispatch's updates: `tokens x k` a pass over an expert layer
         load = routed[:, first:first + held]  # tokens per held expert
         steps = int(stats["steps"])
+        sparse_rows = int(stats["sparse_rows"]) if self.sparse else 0
         counts = {"moe_load_max": load.max(), "moe_load_mean": load.mean(),
                   "beyond_window": np.asarray(stats["beyond_window"]), "steps": steps,
-                  "carry_bytes": sum(self.carry_bytes.values()), **self.cache_counts(steps, int(stats["cache_blocks"])),
+                  "carry_bytes": sum(self.carry_bytes.values()),
+                  **self.cache_counts(steps, int(stats["cache_blocks"]), sparse_rows),
                   "moe_rows_run": int(stats["moe_rows_run"]), "moe_rows_all": int(routed.sum())}
+        if self.sparse:  # the index keys the decode steps scored: every position each env's episode had written
+            counts["index_bytes"] = int(stats["index_positions"]) * self.index_key_bytes
         if self.ssm_bytes:  # a model with state-space layers: every env step reads each layer's state and window and writes them
             counts["ssm_state_bytes"] = steps * 2 * self.ssm_bytes
         return counts

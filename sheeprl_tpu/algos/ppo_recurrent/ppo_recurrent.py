@@ -31,7 +31,7 @@ import numpy as np
 
 import optax
 
-from sheeprl_tpu.algos.ppo.loss import entropy_loss, policy_loss, value_loss
+from sheeprl_tpu.algos.ppo.loss import entropy_loss, masked_mean, policy_loss, value_loss
 from sheeprl_tpu.algos.ppo.utils import actions_for_env, normalize_obs_keys, spaces_to_dims
 from sheeprl_tpu.algos.ppo_recurrent.agent import LSTMCore, build_agent, build_decoder_agent, one_hot_actions
 from sheeprl_tpu.data.buffers import ReplayBuffer
@@ -231,7 +231,7 @@ def main(fabric: Any, cfg: Any) -> None:
             if "values" in rollout:  # graftlint: disable=trace-python-branch  (a key of the dict, not a value: the rollout kept its own values, so no second pass over every token)
                 values = rollout["values"]
             else:
-                _, values, _ = fwd(p, jnp.arange(B))
+                _, values, _, _ = fwd(p, jnp.arange(B))
                 values = values[..., 0]
             returns, advantages = gae(
                 rollout["rewards"], values, rollout["dones"], last_values, gamma, gae_lambda
@@ -249,7 +249,7 @@ def main(fabric: Any, cfg: Any) -> None:
 
                 @jax.named_scope("update.loss")
                 def loss_of(p_):
-                    a_out, new_values, load = fwd(p_, env_idx)
+                    a_out, new_values, load, own_loss = fwd(p_, env_idx)
                     acts = jnp.take(rollout["actions"], env_idx, axis=1)
                     lp, ent = _dist_stats(a_out, acts, actions_dim, is_continuous)
                     adv = jnp.take(advantages, env_idx, axis=1)
@@ -269,7 +269,11 @@ def main(fabric: Any, cfg: Any) -> None:
                         pg = policy_loss(lp, old_lp, adv, clip_coef, reduction, mk)
                         vl = value_loss(new_values[..., 0], old_v, ret, clip_coef, clip_vloss, reduction, mk)
                         el = entropy_loss(ent, reduction, mk)
-                    return pg + vf_coef * vl + ent_coef * el, ((pg, vl, el), load)
+                    loss = pg + vf_coef * vl + ent_coef * el
+                    if own_loss is not None:  # the core's own term (a sparse decoder's L_I), averaged as PPO's are
+                        with jax.named_scope("policy.attn.index_loss"):
+                            loss = loss + (jnp.mean(own_loss) if mask is None else masked_mean(own_loss, mk))
+                    return loss, ((pg, vl, el), load)
 
                 (_, ((pg, vl, el), load)), grads = jax.value_and_grad(loss_of, has_aux=True)(p)
                 with jax.named_scope("update.optim"):

@@ -293,7 +293,8 @@ def make_recurrent_rollout_fn(
     caches and positions.
 
     ``step_apply(p, carry, obs, prev_actions, is_first) -> (carry',
-    (actor_out, value))`` is the agent's single-step apply;
+    (actor_out, value))`` is the agent's single-step apply (a third member,
+    a dict of per-step arrays, joins the rollout under its keys);
     ``encode_prev_actions(actions)`` is the next-step action encoding
     (one-hot per discrete branch).  Returns ``rollout(p, actor, key) ->
     (actor', rollout, init_carry, last_values, stats)`` where ``rollout``
@@ -324,9 +325,9 @@ def make_recurrent_rollout_fn(
             env_state, rc, prev_actions, is_first, ep_ret, ep_len = carry
             pobs = prep(venv.observe(env_state))
             with jax.named_scope("rollout.policy"):
-                rc2, (actor_out, value) = step_apply(p, rc, pobs, prev_actions, is_first)
+                rc2, (actor_out, value, *told) = step_apply(p, rc, pobs, prev_actions, is_first)
                 actions, logprob = sample_fn(actor_out, k_step)
-            extra = {}
+            extra = dict(*told)
             if loss_mask is not None:
                 extra["mask"] = loss_mask(env_state)
             if store_values:
@@ -339,7 +340,7 @@ def make_recurrent_rollout_fn(
             else:
                 # truncation bootstrap with the post-step recurrent state
                 with jax.named_scope("rollout.policy"):
-                    _, (_, v_final) = step_apply(
+                    _, (_, v_final, *_) = step_apply(
                         p, rc2, prep(final_obs), prev_a_next,
                         jnp.zeros((num_envs, 1), jnp.float32),
                     )
@@ -381,7 +382,7 @@ def make_recurrent_rollout_fn(
         stats = {k: traj.pop(k) for k in ("ep_done", "ep_ret", "ep_len")}
         # bootstrap values for the post-rollout state, with the live carry
         with jax.named_scope("rollout.policy"):
-            _, (_, last_v) = step_apply(
+            _, (_, last_v, *_) = step_apply(
                 p, carry2, prep(venv.observe(env_state)), prev_actions, is_first
             )
         new_actor = {

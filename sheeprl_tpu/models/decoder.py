@@ -1,9 +1,10 @@
 """A decoder sequence block for token-level policies: pure functions over a parameter dict.
 
 What the other networks of ``models/models.py`` do not have: RMS norm, rotary
-positions, four kinds of sequence mixer (grouped-query attention with a
-sliding window, the same over the whole episode, a gated short convolution and
-a Mamba-2 state-space layer, ``layer_types``), a feed-forward in two forms
+positions, five kinds of sequence mixer (grouped-query attention with a
+sliding window, the same over the whole episode, the same over the keys a
+learned indexer selects, a gated short convolution and a Mamba-2 state-space
+layer, ``layer_types``), a feed-forward in two forms
 (silu-gated with three matrices, ``relu^2`` with two), and a sparse-expert
 layer that is told which experts it holds (``experts_held``), routes over all
 of them and computes its own experts' part of the result.  What a layer is made
@@ -13,8 +14,9 @@ output gate, the per-head norms, which layers take rotary positions, the
 embedding multiplier, the shared expert and its width) is data of
 :class:`DecoderConfig`, stated by a yaml of ``configs/algo/decoder``; its
 defaults are the ``afmoe`` family's (Arcee Trinity), ``lfm2_24b.yaml`` states
-the ``lfm2_moe`` family's (LiquidAI) and ``nemotron3_nano.yaml`` the
-``nemotron_h`` family's (NVIDIA).  ``howto/ppo_tokens.md`` and the files of
+the ``lfm2_moe`` family's (LiquidAI), ``nemotron3_nano.yaml`` the
+``nemotron_h`` family's (NVIDIA) and ``keye_vl2.yaml`` the ``KeyeVL2`` family's
+language model (Kwai-Keye).  ``howto/ppo_tokens.md`` and the files of
 ``chipbench/configs`` say which equations a published ``config.json`` settles
 and which are assumed.
 
@@ -31,7 +33,8 @@ The carry is a pytree, per env: for every attention layer a buffer of keys and
 of values (a ring of ``sliding_window`` positions for a sliding layer,
 ``max_len`` for a full one; slot = position mod size; a slot is one row of
 ``num_key_value_heads * head_dim`` lanes, the heads side by side, so that a row
-is whole lanes whatever the head width), for every conv layer the gated inputs
+is whole lanes whatever the head width; a sparse layer besides keeps one index key
+of ``index_head_dim`` lanes a position), for every conv layer the gated inputs
 of the last ``conv_L_cache - 1`` tokens (oldest first), for every Mamba-2 layer
 its state (per head ``ssm_head_dim x ssm_state_size``, float32 whatever the
 compute dtype: it accumulates over thousands of steps at decays near 1) and the
@@ -64,10 +67,13 @@ SLIDING = "sliding_attention"
 FULL = "full_attention"  # attends to the whole episode
 CONV = "conv"  # a gated short convolution: no keys, a window of gated inputs
 MAMBA = "mamba2"  # a Mamba-2 state-space mixer: no keys, a state per head and a window of convolution inputs
+SPARSE = "sparse_attention"  # attends to the keys of the episode a learned indexer selects, `index_topk` a token
+ATTENTION = (SLIDING, FULL, SPARSE)  # the kinds that cache keys and values
 MOE = "moe"  # no mixer: a layer that is a sparse feed-forward alone (with ``mixer_ffn: False``)
 FLOAT32_LEAVES = ("A_log", "dt_bias", "D")  # of a Mamba-2 layer: read in float32 wherever they are read, so never kept in less
 Q_BLOCK = 64  # queries per attention block of a segment: 64 x (prefix + T) x 32 heads of float32 scores at a time
 ROW_TILE = 512  # rows of one tile of the grouped product as XLA runs `ragged_dot` on the TPU
+SPARSE_BLOCK_BYTES = 1 << 29  # float32 scores a block of a sparse layer's segment holds at a time
 
 
 @dataclasses.dataclass(frozen=True)
@@ -115,6 +121,14 @@ class DecoderConfig:
     ssm_dt_min: float = 0.001
     ssm_dt_max: float = 0.1
     ssm_dt_floor: float = 1e-4
+    # a learned sparse attention layer (`sparse_attention` in `layer_types`): an indexer of `index_heads` query heads of
+    # `index_head_dim` on one shared key head scores every key of the episode, the attention reads the `index_topk`
+    # best; rotary positions on the first `index_rope_dim` lanes of the indexer's queries and keys
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    index_rope_dim: int = 0
+    route_score: str = "sigmoid"  # the router's scores: sigmoid with a selection bias, or softmax over all experts (no bias)
 
     @staticmethod
     def from_dict(d: Dict[str, Any], vocab_size: int, max_len: int) -> "DecoderConfig":
@@ -122,7 +136,7 @@ class DecoderConfig:
         kw = {k: v for k, v in d.items() if k in fields}
         kw["layer_types"] = tuple(kw["layer_types"])
         kw["rope_layers"] = tuple(kw.get("rope_layers", (SLIDING,)))
-        unknown = set(kw["layer_types"]) - {SLIDING, FULL, CONV, MAMBA, MOE}
+        unknown = set(kw["layer_types"]) - {*ATTENTION, CONV, MAMBA, MOE}
         if unknown or (SLIDING in kw["layer_types"] and not kw.get("sliding_window")):
             raise ValueError(f"layer_types {kw['layer_types']}: unknown kinds {sorted(unknown)}, or a sliding layer without sliding_window")
         if MOE in kw["layer_types"] and kw.get("mixer_ffn", True):
@@ -132,6 +146,10 @@ class DecoderConfig:
             or kw["ssm_heads"] % kw.get("ssm_groups", 1) or kw["ssm_heads"] * kw["ssm_head_dim"] % kw.get("ssm_groups", 1)
         ):
             raise ValueError("a `mamba2` layer needs ssm_heads, ssm_head_dim and ssm_state_size, and ssm_groups that divides the heads")
+        if SPARSE in kw["layer_types"] and not (kw.get("index_heads") and kw.get("index_head_dim") and kw.get("index_topk")):
+            raise ValueError("a `sparse_attention` layer needs index_heads, index_head_dim and index_topk")
+        if kw.get("route_score", "sigmoid") not in ("sigmoid", "softmax"):
+            raise ValueError(f"route_score {kw['route_score']!r}: sigmoid or softmax")
         if kw.get("ffn_act", "silu_gated") not in ("silu_gated", "relu2"):
             raise ValueError(f"ffn_act {kw['ffn_act']!r}: silu_gated or relu2")
         kw["experts_held"] = tuple(int(x) for x in kw["experts_held"])
@@ -151,7 +169,7 @@ class DecoderConfig:
     def carry_slot(self, layer: int) -> int:
         """Where the carry keeps layer ``layer``'s state, among those of its kind (attention, conv, or state-space)."""
         kind = self.layer_types[layer]
-        kinds = (kind,) if kind in (CONV, MAMBA) else (SLIDING, FULL)
+        kinds = (kind,) if kind in (CONV, MAMBA) else ATTENTION
         return self.layers_of(*kinds).index(layer)
 
     def ffn_of(self, layer: int) -> Optional[str]:
@@ -226,10 +244,16 @@ def init_params(dc: DecoderConfig, key: jax.Array, std: Optional[float] = None) 
             if dc.attn_output_gate:
                 layer["wg"] = mat(H, Q)
             layer["wo"] = mat(Q, H)
+            if kind == SPARSE:  # the indexer: its queries, its one key head (layer-normed), the heads' weights
+                d = dc.index_head_dim
+                layer["index"] = {"wq": mat(H, dc.index_heads * d), "wk": mat(H, d), "norm": jnp.ones((d,)),
+                                  "norm_bias": jnp.zeros((d,)), "ww": mat(H, dc.index_heads)}
         if part == "mlp":
             layer["mlp"] = ffn(dc.intermediate_size)
         elif part == "moe":
-            layer["moe"] = {"router": mat(H, dc.num_experts), "router_bias": jnp.zeros((dc.num_experts,))}
+            layer["moe"] = {"router": mat(H, dc.num_experts)}
+            if dc.route_score == "sigmoid":
+                layer["moe"]["router_bias"] = jnp.zeros((dc.num_experts,))
             if dc.num_shared_experts:
                 layer["moe"]["shared"] = ffn(dc.shared_intermediate_size or dc.moe_intermediate_size * dc.num_shared_experts)
             layer["moe"]["experts"] = ffn(dc.moe_intermediate_size, lead=(dc.experts_held[1],))
@@ -239,16 +263,18 @@ def init_params(dc: DecoderConfig, key: jax.Array, std: Optional[float] = None) 
 
 
 def init_carry(dc: DecoderConfig, batch: int, dtype: Any = jnp.bfloat16) -> Carry:
-    """``k`` and ``v`` hold one buffer per attention layer, ``conv`` one window per conv layer, ``ssm`` one state
-    (float32 whatever ``dtype``) and ``ssm_window`` one window of convolution inputs per Mamba-2 layer, each in the
-    layers' order; a kind the model lacks has no entry."""
+    """``k`` and ``v`` hold one buffer per attention layer, ``ik`` one buffer of index keys per sparse layer, ``conv``
+    one window per conv layer, ``ssm`` one state (float32 whatever ``dtype``) and ``ssm_window`` one window of
+    convolution inputs per Mamba-2 layer, each in the layers' order; a kind the model lacks has no entry."""
     shape = lambda i: (batch, dc.cache_len(i), dc.num_key_value_heads * dc.head_dim)  # noqa: E731
-    attn, conv, ssm = dc.layers_of(SLIDING, FULL), dc.layers_of(CONV), dc.layers_of(MAMBA)
+    attn, conv, ssm = dc.layers_of(*ATTENTION), dc.layers_of(CONV), dc.layers_of(MAMBA)
     carry = {
         "k": [jnp.zeros(shape(i), dtype) for i in attn],
         "v": [jnp.zeros(shape(i), dtype) for i in attn],
         "pos": jnp.zeros((batch,), jnp.int32),
     }
+    if dc.layers_of(SPARSE):
+        carry["ik"] = [jnp.zeros((batch, dc.max_len, dc.index_head_dim), dtype) for _ in dc.layers_of(SPARSE)]
     if conv:
         carry["conv"] = [jnp.zeros((batch, dc.conv_L_cache - 1, dc.hidden_size), dtype) for _ in conv]
     if ssm:
@@ -262,8 +288,10 @@ def carry_bytes(dc: DecoderConfig, dtype: Any = jnp.bfloat16) -> Dict[str, int]:
     shapes = jax.eval_shape(lambda: init_carry(dc, 1, dtype))
     size = lambda x: int(math.prod(x.shape)) * x.dtype.itemsize  # noqa: E731
     out = {"pos": size(shapes["pos"])}
-    for i, k, v in zip(dc.layers_of(SLIDING, FULL), shapes["k"], shapes["v"]):
+    for i, k, v in zip(dc.layers_of(*ATTENTION), shapes["k"], shapes["v"]):
         out[dc.layer_types[i]] = out.get(dc.layer_types[i], 0) + size(k) + size(v)
+    if "ik" in shapes:  # a sparse layer's index keys beside its keys and values
+        out[SPARSE] += sum(size(z) for z in shapes["ik"])
     if "conv" in shapes:
         out[CONV] = sum(size(z) for z in shapes["conv"])
     if "ssm" in shapes:  # what a decode step reads and writes of it whole, every step: the state and the window
@@ -273,8 +301,10 @@ def carry_bytes(dc: DecoderConfig, dtype: Any = jnp.bfloat16) -> Dict[str, int]:
 
 def update_router_bias(params: Params, load: jax.Array, dc: DecoderConfig) -> Params:
     """The selection bias after one update: ``b += coeff * sign(mean load - load_e)`` over the router's
-    counts of that update (``load``: one row of ``num_experts`` counts per expert layer)."""
+    counts of that update (``load``: one row of ``num_experts`` counts per expert layer); a softmax router has none."""
     out = dict(params)
+    if dc.route_score != "sigmoid":
+        return out
     for row, i in enumerate(dc.moe_layers()):
         counts = load[row].astype(jnp.float32)
         layer = dict(out[f"layer_{i}"])
@@ -328,14 +358,22 @@ def slot_positions(last: jax.Array, size: int) -> jax.Array:
     return last - jnp.mod(last - s, size)
 
 
-def _attend(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array) -> jax.Array:
-    """q (B, T, KV, G, D), k/v (B, S, KV, D), mask (B, T, S) -> (B, T, KV*G*D); softmax in float32."""
+def _attention_probs(q: jax.Array, k: jax.Array, mask: jax.Array) -> jax.Array:
+    """q (B, T, KV, G, D), k (B, S, KV, D), mask (B, T, S) -> the softmax over the keys, (B, KV, G, T, S) float32."""
     scores = jnp.einsum("btkgd,bskd->bkgts", q, k, preferred_element_type=jnp.float32)
     scores = scores / math.sqrt(q.shape[-1])
     scores = jnp.where(mask[:, None, None], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
+    return jax.nn.softmax(scores, axis=-1)
+
+
+def _weigh(probs: jax.Array, v: jax.Array) -> jax.Array:
     out = jnp.einsum("bkgts,bskd->btkgd", probs.astype(v.dtype), v)
     return out.reshape(out.shape[:2] + (-1,))
+
+
+def _attend(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array) -> jax.Array:
+    """q (B, T, KV, G, D), k/v (B, S, KV, D), mask (B, T, S) -> (B, T, KV*G*D); softmax in float32."""
+    return _weigh(_attention_probs(q, k, mask), v)
 
 
 def _per_head(cache: jax.Array, dc: DecoderConfig) -> jax.Array:
@@ -353,11 +391,17 @@ def _ffn(w: Params, x: jax.Array, dot=jnp.matmul) -> jax.Array:
 
 
 def route(moe: Params, m: jax.Array, dc: DecoderConfig) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Sigmoid scores over ALL experts, the ``k`` largest of ``score + bias`` (the bias has no gradient),
-    weights ``route_scale * s / (sum s + route_eps)``.  Returns (experts (N, k), weights (N, k) float32, counts (E,))."""
-    s = jax.nn.sigmoid(jnp.matmul(  # few columns: cheap in full precision, and a coarser product reorders near ties
-        m.astype(jnp.float32), moe["router"].astype(jnp.float32), precision=jax.lax.Precision.HIGHEST))
-    _, experts = jax.lax.top_k(s + jax.lax.stop_gradient(moe["router_bias"].astype(jnp.float32)), dc.num_experts_per_tok)
+    """Scores over ALL experts: sigmoid, and the ``k`` largest of ``score + bias`` (the bias has no gradient), or
+    (``route_score: softmax``) a softmax and its ``k`` largest; weights ``route_scale * s / (sum s + route_eps)``
+    over the selected.  Returns (experts (N, k), weights (N, k) float32, counts (E,))."""
+    logits = jnp.matmul(  # few columns: cheap in full precision, and a coarser product reorders near ties
+        m.astype(jnp.float32), moe["router"].astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
+    if dc.route_score == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)
+        _, experts = jax.lax.top_k(s, dc.num_experts_per_tok)
+    else:
+        s = jax.nn.sigmoid(logits)
+        _, experts = jax.lax.top_k(s + jax.lax.stop_gradient(moe["router_bias"].astype(jnp.float32)), dc.num_experts_per_tok)
     w = jnp.take_along_axis(s, experts, axis=-1)
     if dc.route_norm:
         w = w / (w.sum(axis=-1, keepdims=True) + dc.route_eps)
@@ -434,6 +478,71 @@ def _qkv(layer: Params, a: jax.Array, pos: jax.Array, rotary: bool, dc: DecoderC
     if rotary:
         q, k = rope(q, pos, dc.rope_theta), rope(k, pos, dc.rope_theta)
     return q, k, v
+
+
+def _layer_norm(x: jax.Array, w: jax.Array, b: jax.Array, eps: float) -> jax.Array:
+    x32 = x.astype(jnp.float32)
+    centred = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    y = centred * jax.lax.rsqrt(jnp.mean(centred * centred, axis=-1, keepdims=True) + eps)
+    return (y * w.astype(jnp.float32) + b.astype(jnp.float32)).astype(x.dtype)
+
+
+def _index_qkw(index: Params, a: jax.Array, pos: jax.Array, dc: DecoderConfig):
+    """The indexer on normed rows ``a`` (..., H) at positions ``pos`` (...), read as constants (the indexer takes its
+    gradient from L_I alone and gives none to what made ``a``): queries (..., heads, d), the one key (..., d)
+    layer-normed, rotary positions on the first ``index_rope_dim`` lanes of both, and the heads' weights (..., heads)
+    in float32 with ``1 / sqrt(heads)`` in them."""
+    a = jax.lax.stop_gradient(a)
+    dt, lead, r = a.dtype, a.shape[:-1], dc.index_rope_dim
+    q = (a @ index["wq"].astype(dt)).reshape(lead + (dc.index_heads, dc.index_head_dim))
+    k = _layer_norm(a @ index["wk"].astype(dt), index["norm"], index["norm_bias"], dc.rms_norm_eps)
+    if r:
+        q = jnp.concatenate([rope(q[..., :r], pos, dc.rope_theta), q[..., r:]], axis=-1)
+        k = jnp.concatenate([rope(k[..., :r], pos, dc.rope_theta), k[..., r:]], axis=-1)
+    w = (a @ index["ww"].astype(dt)).astype(jnp.float32) / math.sqrt(dc.index_heads)
+    return q, k, w
+
+
+def index_scores(q: jax.Array, w: jax.Array, keys: jax.Array) -> jax.Array:
+    """``I[b, t, s] = sum_j w[b, t, j] relu(q[b, t, j] . keys[b, s]) / sqrt(d)`` in float32: q (B, T, heads, d),
+    w (B, T, heads), keys (B, S, d) -> (B, T, S); a score of nought is +0 (the selection orders it as a float)."""
+    dots = jnp.einsum("bthd,bsd->bths", q, keys, preferred_element_type=jnp.float32)
+    scores = jnp.sum(w[..., None] * jax.nn.relu(dots), axis=2) / math.sqrt(q.shape[-1])
+    return jnp.where(scores == 0, 0.0, scores)
+
+
+def top_mask(scores: jax.Array, visible: jax.Array, k: int) -> jax.Array:
+    """The ``k`` highest ``scores`` (..., S) float32 among the ``visible`` (ties to the lower index) as a mask; every
+    visible one where there are no more than ``k``.  The ``k``-th highest is found bit by bit on an unsigned key that
+    orders as the floats do (32 counts over ``S``), and the ties at it are taken lowest index first."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.uint32)
+    key = jnp.where(visible, jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31)), jnp.uint32(0))
+    kth = jnp.zeros(scores.shape[:-1] + (1,), jnp.uint32)
+    for bit in range(31, -1, -1):
+        trial = kth | jnp.uint32(1 << bit)
+        kth = jnp.where(jnp.sum(key >= trial, axis=-1, keepdims=True) >= k, trial, kth)
+    above = key > kth
+    tie = visible & (key == kth)
+    need = k - jnp.sum(above, axis=-1, keepdims=True)
+    return visible & (above | (tie & (jnp.cumsum(tie, axis=-1) <= need)))
+
+
+def _sparse_block(B: int, T: int, S: int, heads: int) -> int:
+    """Queries a block of a sparse layer's segment takes at a time: ``B x heads x block x S`` float32 scores under
+    ``SPARSE_BLOCK_BYTES``, and a divisor of ``T``."""
+    qb = max(1, min(T, SPARSE_BLOCK_BYTES // (4 * B * heads * S)))
+    while T % qb:
+        qb -= 1
+    return qb
+
+
+def _index_kl(probs: jax.Array, scores: jax.Array, sel: jax.Array) -> jax.Array:
+    """L_I of each query: ``KL(p || softmax of the index scores over the selected keys)``, ``p`` the main attention's
+    probabilities ``probs`` (B, KV, G, T, S) summed over its heads and normalised, a constant; scores, sel (B, T, S)
+    -> (B, T) float32."""
+    p = jax.lax.stop_gradient(jnp.mean(probs, axis=(1, 2)))
+    log_q = jax.nn.log_softmax(jnp.where(sel, scores, -1e30), axis=-1)
+    return jnp.sum(jnp.where(sel, jax.scipy.special.xlogy(p, p) - p * log_q, 0.0), axis=-1)
 
 
 def _after_mixer(layer: Params, x: jax.Array, y: jax.Array, dc: DecoderConfig) -> jax.Array:
@@ -570,8 +679,13 @@ def _next_carry(carry: Carry, new: Carry, pos: jax.Array) -> Carry:
     return {**{k: v for k, v in new.items() if k in carry}, "pos": pos}
 
 
+# each env's row (B, ...) written into its cache (B, slots, lanes) at its slot (B,), in place where the cache is donated
+_write_rows = jax.vmap(lambda c, s, row: jax.lax.dynamic_update_slice(c, row.reshape(1, -1).astype(c.dtype), (s, 0)))
+
+
 def _scope(kind: str) -> str:
-    return {SLIDING: "policy.attn.window", FULL: "policy.attn.full", CONV: "policy.conv", MAMBA: "policy.ssm"}[kind]
+    return {SLIDING: "policy.attn.window", FULL: "policy.attn.full", CONV: "policy.conv", MAMBA: "policy.ssm",
+            SPARSE: "policy.attn.sparse"}[kind]
 
 
 # ----------------------------------------------------------------------------
@@ -600,8 +714,7 @@ def _step_mixer(layer: Params, x, pos, carry: Carry, new: Carry, dc: DecoderConf
     size = dc.cache_len(i)
     q, k, v = _qkv(layer, a, pos, kind in dc.rope_layers, dc)
     slot = jnp.mod(pos, size)
-    write = jax.vmap(lambda c, s, row: jax.lax.dynamic_update_slice(c, row.reshape(1, -1).astype(c.dtype), (s, 0)))
-    ck, cv = write(carry["k"][n], slot, k), write(carry["v"][n], slot, v)
+    ck, cv = _write_rows(carry["k"][n], slot, k), _write_rows(carry["v"][n], slot, v)
     if decode_attention.engages(size):  # a cache with blocks to skip is read as far as each env has written it
         # a ring keeps the last `size` positions and nothing older: unwrapped, and in a full cache, slots [0, pos]
         o = decode_attention.decode_attention(q, ck, cv, jnp.minimum(pos + 1, size)).astype(dtype)
@@ -613,15 +726,49 @@ def _step_mixer(layer: Params, x, pos, carry: Carry, new: Carry, dc: DecoderConf
     return _after_attention(layer, x, a, o, dc)
 
 
-def step(params: Params, dc: DecoderConfig, carry: Carry, tokens: jax.Array, is_first: jax.Array, dtype: Any):
+def _step_sparse(layer: Params, x, pos, carry: Carry, new: Carry, dc: DecoderConfig, i: int, dtype: Any):
+    """Layer ``i``'s learned sparse attention on one token an env, (B, H) rows at positions ``pos``: the token's index
+    key is written, the indexer scores every position of the env's episode, the ``index_topk`` best are selected (ties
+    to the lower position), and only their rows of the key and value caches are fetched and attended over.  Returns
+    the rows with the mixer's part added and the selected slots (B, topk), -1 where fewer positions were written."""
+    n, m, size = dc.carry_slot(i), dc.layers_of(SPARSE).index(i), dc.cache_len(i)
+    slot = jnp.mod(pos, size)
+    with jax.named_scope(_scope(SPARSE)):
+        a = rms_norm(x, layer["norm_in"], dc.rms_norm_eps)
+    with jax.named_scope("policy.attn.index"):
+        qi, ki, wi = _index_qkw(layer["index"], a, pos, dc)
+        cik = _write_rows(carry["ik"][m], slot, ki)
+        scores = index_scores(qi[:, None], wi[:, None], cik.astype(dtype))[:, 0]
+        visible = slot_positions(pos, size) >= 0
+        _, rows = jax.lax.top_k(jnp.where(visible, scores, -jnp.inf), min(dc.index_topk, size))
+        chosen = jnp.take_along_axis(visible, rows, axis=1)
+    with jax.named_scope(_scope(SPARSE)):
+        q, k, v = _qkv(layer, a, pos, SPARSE in dc.rope_layers, dc)
+        ck, cv = _write_rows(carry["k"][n], slot, k), _write_rows(carry["v"][n], slot, v)
+        fetch = lambda cache: _per_head(jnp.take_along_axis(cache, rows[..., None], axis=1), dc).astype(dtype)  # noqa: E731
+        o = _attend(q[:, None], fetch(ck), fetch(cv), chosen[:, None])[:, 0]
+        x = _after_attention(layer, x, a, o, dc)
+    new["k"].append(ck)
+    new["v"].append(cv)
+    new["ik"].append(cik)
+    return x, jnp.where(chosen, rows, -1)
+
+
+def step(params: Params, dc: DecoderConfig, carry: Carry, tokens: jax.Array, is_first: jax.Array, dtype: Any,
+         selected: Optional[list] = None):
     """``tokens`` (B,), ``is_first`` (B,) -> (carry', logits (B, V), value (B, 1)).  A reset empties the
-    env's caches (its position goes to nought) before the token is read."""
+    env's caches (its position goes to nought) before the token is read.  ``selected``, a list, receives each
+    sparse layer's selected slots (B, topk)."""
     pos = jnp.where(is_first > 0, 0, carry["pos"]).astype(jnp.int32)
     x = _embed(params, tokens, dc, dtype)
-    new: Carry = {"k": [], "v": [], "conv": [], "ssm": [], "ssm_window": []}
+    new: Carry = {"k": [], "v": [], "ik": [], "conv": [], "ssm": [], "ssm_window": []}
     for i, kind in enumerate(dc.layer_types):
         layer = params[f"layer_{i}"]
-        if kind != MOE:  # a `moe` layer is a feed-forward alone
+        if kind == SPARSE:  # its indexer and its attention under scopes of their own
+            x, rows = _step_sparse(layer, x, pos, carry, new, dc, i, dtype)
+            if selected is not None:
+                selected.append(rows)
+        elif kind != MOE:  # a `moe` layer is a feed-forward alone
             with jax.named_scope(_scope(kind)):
                 x = _step_mixer(layer, x, pos, carry, new, dc, i, dtype)
         if dc.ffn_of(i):
@@ -643,6 +790,74 @@ def _segment_ffn(layer: Params, x, dc: DecoderConfig):
     return y.reshape(B, T, H), counts
 
 
+def _visible(pos, seg, prefix_pos, sliding: bool, dc: DecoderConfig) -> jax.Array:
+    """(B, T, prefix + T): the keys each query of a segment sees, the prefix's (the carry's episode, for queries
+    before the segment's first reset) and the segment's own (its episode, not later than the query), within the
+    window of a sliding layer."""
+    T = pos.shape[1]
+    t = jnp.arange(T, dtype=jnp.int32)
+    on_prefix = (seg[:, :, None] == 0) & (prefix_pos[:, None, :] >= 0)
+    own = (t[None, :, None] >= t[None, None, :]) & (seg[:, :, None] == seg[:, None, :])
+    if sliding:
+        on_prefix &= pos[:, :, None] - prefix_pos[:, None, :] < dc.sliding_window
+        own &= (t[:, None] - t[None, :] < dc.sliding_window)[None]
+    return jnp.concatenate([on_prefix, own], axis=2)
+
+
+def _query_blocks(z: jax.Array, qb: int) -> jax.Array:
+    """(B, T, ...) -> (T / qb, B, qb, ...)."""
+    B, T = z.shape[:2]
+    return jnp.moveaxis(z.reshape((B, T // qb, qb) + z.shape[2:]), 1, 0)
+
+
+def _segment_select(layer: Params, x, pos, seg, prefix_ik, prefix_pos, dc: DecoderConfig) -> jax.Array:
+    """A sparse layer's selection for every query of a segment over (B, T, H) rows, made once a layer and a pass and
+    differentiated through by nothing: the indexer's scores over the carry's prefix (its index keys, constants) and
+    the segment's own, the ``index_topk`` best of those each query sees.  -> (B, T, prefix + T) bool."""
+    layer, x = jax.lax.stop_gradient((layer, x))
+    B, T, _ = x.shape
+    with jax.named_scope("policy.attn.index"):
+        a = rms_norm(x, layer["norm_in"], dc.rms_norm_eps)
+        qi, ki, wi = _index_qkw(layer["index"], a, pos, dc)
+        keys = jnp.concatenate([prefix_ik.astype(x.dtype), ki], axis=1)
+        visible = _visible(pos, seg, prefix_pos, False, dc)
+        qb = _sparse_block(B, T, keys.shape[1], dc.index_heads)
+        select = lambda args: top_mask(index_scores(args[0], args[1], keys), args[2], dc.index_topk)  # noqa: E731
+        sel = jax.lax.map(select, (_query_blocks(qi, qb), _query_blocks(wi, qb), _query_blocks(visible, qb)))
+    return jnp.moveaxis(sel, 0, 1).reshape(visible.shape)
+
+
+def _segment_sparse_layer(layer: Params, x, pos, prefix_k, prefix_v, prefix_ik, sel, dc: DecoderConfig):
+    """One sparse attention layer over (B, T, H) rows: each query attends over the keys ``sel`` (B, T, prefix + T)
+    selected for it (a mask on the blocked product), and gives its term of L_I over them.  Returns (x', router counts
+    or None, k, v and index keys of the segment, L_I (B, T) float32)."""
+    B, T, H = x.shape
+    dt = x.dtype
+    with jax.named_scope(_scope(SPARSE)):
+        a = rms_norm(x, layer["norm_in"], dc.rms_norm_eps)
+        q, k, v = _qkv(layer, a, pos, SPARSE in dc.rope_layers, dc)
+        keys = jnp.concatenate([prefix_k.astype(dt), k], axis=1)
+        values = jnp.concatenate([prefix_v.astype(dt), v], axis=1)
+    with jax.named_scope("policy.attn.index"):
+        qi, ki, wi = _index_qkw(layer["index"], a, pos, dc)
+        index_keys = jnp.concatenate([prefix_ik.astype(dt), ki], axis=1)
+
+    def block(args):
+        q_b, qi_b, wi_b, sel_b = args
+        with jax.named_scope(_scope(SPARSE)):
+            probs = _attention_probs(q_b, keys, sel_b)
+            o_b = _weigh(probs, values)
+        with jax.named_scope("policy.attn.index_loss"):
+            kl_b = _index_kl(probs, index_scores(qi_b, wi_b, index_keys), sel_b)
+        return o_b, kl_b
+
+    qb = _sparse_block(B, T, keys.shape[1], max(dc.num_attention_heads, dc.index_heads))
+    o, kl = jax.lax.map(jax.checkpoint(block), tuple(_query_blocks(z, qb) for z in (q, qi, wi, sel)))
+    with jax.named_scope(_scope(SPARSE)):
+        x = _after_attention(layer, x, a, jnp.moveaxis(o, 0, 1).reshape(B, T, -1), dc)
+    return _segment_ffn(layer, x, dc) + (k, v, ki, jnp.moveaxis(kl, 0, 1).reshape(B, T))
+
+
 def _segment_layer(layer: Params, x, pos, seg, prefix_k, prefix_v, prefix_pos, dc: DecoderConfig, kind: str):
     """One attention layer over (B, T, H) rows.  Returns (x', router counts or None, k, v of the segment)."""
     B, T, H = x.shape
@@ -653,14 +868,7 @@ def _segment_layer(layer: Params, x, pos, seg, prefix_k, prefix_v, prefix_pos, d
         q, k, v = _qkv(layer, a, pos, kind in dc.rope_layers, dc)
         keys = jnp.concatenate([prefix_k.astype(dt), k], axis=1)
         values = jnp.concatenate([prefix_v.astype(dt), v], axis=1)
-        t = jnp.arange(T, dtype=jnp.int32)
-        # the prefix: keys of the carry's episode, for queries before the segment's first reset
-        on_prefix = (seg[:, :, None] == 0) & (prefix_pos[:, None, :] >= 0)
-        own = (t[None, :, None] >= t[None, None, :]) & (seg[:, :, None] == seg[:, None, :])
-        if sliding:
-            on_prefix &= pos[:, :, None] - prefix_pos[:, None, :] < dc.sliding_window
-            own &= (t[:, None] - t[None, :] < dc.sliding_window)[None]
-        mask = jnp.concatenate([on_prefix, own], axis=2)
+        mask = _visible(pos, seg, prefix_pos, sliding, dc)
         qb = min(Q_BLOCK, T)
         if T % qb:
             raise ValueError(f"a segment of {T} tokens does not divide into query blocks of {qb}")
@@ -700,12 +908,14 @@ def _segment_ssm_layer(layer: Params, x, pos, cuts, dt_mask, state, window, dc: 
 
 def segment(
     params: Params, dc: DecoderConfig, carry: Carry, tokens: jax.Array, is_first: jax.Array, dtype: Any,
-    extend: bool = False, valid: Optional[jax.Array] = None,
+    extend: bool = False, valid: Optional[jax.Array] = None, index_loss: bool = False,
 ):
     """``tokens``, ``is_first`` (T, B) on the prefix cached in ``carry`` (constants: nothing is
     differentiated through them) -> (logits (T, B, V), values (T, B, 1), router counts (expert layers,
     E)), and with ``extend`` the carry that holds the segment as well.  ``valid`` (B,), with ``extend``:
-    only each env's first ``valid`` tokens are real (a ragged prefill); the others are not written."""
+    only each env's first ``valid`` tokens are real (a ragged prefill); the others are not written.
+    ``index_loss`` (without ``extend``) adds L_I of every token (T, B), summed over the sparse layers (None
+    for a model without one)."""
     carry = jax.lax.stop_gradient(carry)
     pos_tb, seg_tb = segment_positions(is_first, carry["pos"])
     pos, seg = pos_tb.T, seg_tb.T  # (B, T)
@@ -713,8 +923,8 @@ def segment(
     T = x.shape[1]
     if extend:  # real tokens an env
         n = jnp.full(pos.shape[:1], T, jnp.int32) if valid is None else valid.astype(jnp.int32)
-    counts = []
-    new: Carry = {"k": [], "v": [], "conv": [], "ssm": [], "ssm_window": []}
+    counts, kl = [], []
+    new: Carry = {"k": [], "v": [], "ik": [], "conv": [], "ssm": [], "ssm_window": []}
     keep = jax.vmap(lambda rows, start, size: jax.lax.dynamic_slice_in_dim(rows, start, size, axis=0), in_axes=(0, 0, None))
     if dc.layers_of(MAMBA):  # an episode starts at position 0, at a real token: the state before it counts for nought
         live = jnp.arange(T)[None] < n[:, None] if extend else jnp.ones(pos.shape, bool)
@@ -739,9 +949,16 @@ def segment(
         else:
             at, size = dc.carry_slot(i), dc.cache_len(i)
             prefix_pos = slot_positions(carry["pos"] - 1, size)
-            run = jax.checkpoint(_segment_layer, static_argnums=(7, 8))
             prefix_k, prefix_v = _per_head(carry["k"][at], dc), _per_head(carry["v"][at], dc)
-            x, c, k, v = run(params[f"layer_{i}"], x, pos, seg, prefix_k, prefix_v, prefix_pos, dc, kind)
+            if kind == SPARSE:
+                prefix_ik = carry["ik"][dc.layers_of(SPARSE).index(i)]
+                sel = _segment_select(params[f"layer_{i}"], x, pos, seg, prefix_ik, prefix_pos, dc)
+                run = jax.checkpoint(_segment_sparse_layer, static_argnums=(7,))
+                x, c, k, v, ik, layer_kl = run(params[f"layer_{i}"], x, pos, prefix_k, prefix_v, prefix_ik, sel, dc)
+                kl.append(layer_kl)
+            else:
+                run = jax.checkpoint(_segment_layer, static_argnums=(7, 8))
+                x, c, k, v = run(params[f"layer_{i}"], x, pos, seg, prefix_k, prefix_v, prefix_pos, dc, kind)
             if extend:
                 if T > size:
                     raise ValueError("a prefill segment longer than the window would write a slot twice")
@@ -750,12 +967,16 @@ def segment(
                 put = jax.vmap(lambda cache, s, rows: cache.at[s].set(rows.reshape(T, -1).astype(cache.dtype), mode="drop"))
                 new["k"].append(put(carry["k"][at], slot, k))
                 new["v"].append(put(carry["v"][at], slot, v))
+                if kind == SPARSE:
+                    new["ik"].append(put(prefix_ik, slot, ik))
         if c is not None:
             counts.append(c)
     logits, values = _heads(params, x, dc)
     logits, values = jnp.moveaxis(logits, 0, 1), jnp.moveaxis(values, 0, 1)
     load = jnp.stack(counts) if counts else jnp.zeros((0, dc.num_experts), jnp.int32)
     if not extend:
+        if index_loss:
+            return logits, values, load, (sum(kl).T if kl else None)
         return logits, values, load
     last = jnp.take_along_axis(pos, jnp.maximum(n - 1, 0)[:, None], axis=1)[:, 0]
     new_pos = jnp.where(n > 0, last + 1, carry["pos"])

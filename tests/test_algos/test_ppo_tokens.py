@@ -26,6 +26,8 @@ HYBRID_SCOPES = ("policy.conv", "policy.attn.full", "policy.moe.route", "policy.
 SSM = [o.replace("decoder=tiny", "decoder=tiny_ssm") for o in TINY]
 SSM_SCOPES = ("policy.ssm", "policy.ssm/policy.ssm.scan", "policy.attn.full", "policy.moe.route", "policy.moe.experts",
               "policy.moe.shared", "policy.head")
+SPARSE = [o.replace("decoder=tiny", "decoder=tiny_sparse") for o in TINY]
+SPARSE_SCOPES = ("policy.attn.index", "policy.attn.sparse", "policy.moe.route", "policy.moe.experts", "policy.head")
 
 
 def run_probed(overrides, tmp_path_factory):
@@ -248,7 +250,8 @@ def test_the_hybrid_cell_matches_its_reference_and_its_readers_return_numbers(hy
 
 
 @pytest.mark.parametrize("reader, counted", [("carry.mb_per_env", ("carry_bytes",)), ("cache.read_pct", ("cache_read", "cache_held")),
-                                             ("ssm.state_mb_per_step", ("ssm_state_bytes",)), ("moe.rows_run_pct", ("moe_rows_run", "moe_rows_all"))])
+                                             ("ssm.state_mb_per_step", ("ssm_state_bytes",)), ("moe.rows_run_pct", ("moe_rows_run", "moe_rows_all")),
+                                             ("index.mb_per_step", ("index_bytes",))])
 def test_a_count_s_reader_finds_nothing_in_a_program_that_does_not_count_it(hybrid_rehearsal, monkeypatch, reader, counted):
     """On a checkout from before its counter a reader returns nothing and does not raise."""
     from chipbench import harness, spanlog
@@ -305,3 +308,71 @@ def test_the_state_space_cell_matches_its_reference_and_its_readers_return_numbe
             "loop.stall_ms_per_iter", "loop.untracked_ms_per_iter", "step.mfu_pct", "device.idle_pct"} <= set(cell_metrics)
     assert "cache.beyond_window_pct" not in cell_metrics
     assert "ssm.state_mb_per_step" not in harness.metric_names(h.spec["bench"], "lfm2_tokens_longgen", "per_layer")
+
+
+@pytest.fixture(scope="module")
+def sparse_run(tmp_path_factory):
+    return run_probed(SPARSE, tmp_path_factory)
+
+
+def test_the_sparse_decoder_runs_through_the_cli_with_its_scopes_its_event_and_its_counts(sparse_run):
+    """``algo/decoder@algo.decoder=tiny_sparse`` through ``cli.run`` on the fused path, prefill and checkpoint included:
+    ``policy.attn.index`` and ``policy.attn.sparse`` inside the known scopes, ``policy.attn.index_loss`` inside the
+    update's alone (no window, full-attention or shared-expert scope), one ``decoder.carry`` event with the new kind and
+    its bytes (keys, values and index keys), and on every ``stats.pull`` the rows the decode steps fetched of their
+    caches (at most 6 a layer and step) and the index keys they scored (every written position)."""
+    text = sparse_run["lowered"]
+    for name in SPARSE_SCOPES:
+        assert re.search(r"rollout\.policy/[^\"]*" + re.escape(name), text), f"{name} not inside rollout.policy"
+        assert re.search(r"update\.loss\)?/[^\"]*" + re.escape(name), text), f"{name} not inside update.loss"
+    assert re.search(r"update\.loss\)?/[^\"]*policy\.attn\.index_loss", text)
+    assert not re.search(r"rollout\.policy/[^\"]*policy\.attn\.index_loss", text)
+    assert "policy.attn.window" not in text and "policy.attn.full" not in text and "policy.moe.shared" not in text
+    (event,) = sparse_run["carry_events"]
+    by_kind = {"sparse_attention": 2 * 32 * (2 * 2 * 16 + 8) * 4, "pos": 4}  # float32 under 32-true
+    assert event["layers"] == {"sparse_attention": 2} and event["bytes_per_env"] == by_kind
+    names = {r.name for r in sparse_run["records"]}
+    assert {"exec.ppo_recurrent.prefill", "exec.ppo_recurrent.anakin_phase", "ckpt.save"} <= names
+    pulls = [r for r in sparse_run["records"] if r.name == "stats.pull"]
+    assert len(pulls) == 3
+    for r in pulls:
+        assert set(r.counts) == {"moe_load_max", "moe_load_mean", "beyond_window", "steps", "carry_bytes", "cache_read",
+                                 "cache_held", "index_bytes", "moe_rows_run", "moe_rows_all"}
+        assert r.counts["steps"] == 4 * 8 and r.counts["cache_held"] == 4 * 8 * 2 * 32
+        assert 2 * 4 * 8 <= r.counts["cache_read"] <= 2 * 4 * 8 * 6  # 1 to 6 selected rows a layer and step
+        assert r.counts["index_bytes"] % (2 * 8 * 4) == 0 and r.counts["index_bytes"] >= 8 * 4 * r.counts["cache_read"]
+        assert r.counts["carry_bytes"] == sum(by_kind.values())
+    assert sum(r.counts["cache_read"] for r in pulls) < sum(2 * 8 * 4 * 6 for _ in pulls)  # some steps stand before the 6th position
+
+
+@pytest.fixture(scope="module")
+def sparse_rehearsal():
+    return rehearse("keye_tokens_longctx")
+
+
+def test_the_sparse_cell_matches_its_reference_and_its_readers_return_numbers(sparse_rehearsal):
+    """float32 against float32 for the learned sparse attention: the six numbers of the Trinity cell and the
+    selection itself (``select_gap``: every step of the first dispatch, the same positions as the reference's); then
+    every reader of the cell's per-layer metrics that needs no device trace."""
+    from chipbench import harness
+
+    h = sparse_rehearsal
+    correct, compared, numbers, _ = harness.judge(h.program, h.cfg, h.snap, h.spec["config"], h.compiles_in_window)
+    gaps = {k: v["value"] for k, v in compared.items() if k != "compiles_in_window"}
+    assert set(gaps) == {"logprob_gap", "value_gap", "first_loss_gap", "moment_gap", "change_gap", "load_gap", "select_gap"}
+    assert correct and max(gaps.values()) < 5e-4 and gaps["select_gap"] == 0.0, gaps
+    assert numbers["where"]["value"]["skipped"] == [] and len(numbers["where"]["value"]["select_gaps"]) == 2
+    assert h.snap["outputs"][0]["selected"].shape == (8, 4, 2, 6) and "selected" not in h.snap["outputs"][1]
+    ctx = {"window": h.window, "calls": h.calls, "cfg": h.cfg, "trace": None, "chips": 1, "peak": None,
+           "program": h.program, "param_shapes": h.program.param_shapes(h.snap["inputs"][0])}
+    read = lambda name: harness.load_module("metrics", name).read(ctx)  # noqa: E731
+    assert 4 * 2 * 32 <= read("index.mb_per_step") * 1e6 <= 4 * 2 * 32 * 32  # envs x layers x 1 to 32 positions x 8 float32 lanes
+    assert read("carry.mb_per_env") == pytest.approx((2 * 32 * (2 * 2 * 16 + 8) * 4 + 4) / 1e6)
+    assert 0.0 < read("cache.read_pct") < 6 / 32 * 100 + 1e-9  # at most 6 of 32 rows a layer
+    assert read("tokens.dispatch_ms") > 0 and read("moe.load_max_over_mean") >= 1.0 and 0.0 < read("moe.rows_run_pct") <= 100.0
+    assert read("loop.host_ms_per_iter") >= 0.0 and h.program.flops_per_update(h.cfg, ctx["param_shapes"]) > 0
+    cell_metrics = harness.metric_names(h.spec["bench"], "keye_tokens_longctx", "per_layer")
+    assert {"index.mb_per_step", "carry.mb_per_env", "cache.read_pct", "tokens.dispatch_ms", "moe.load_max_over_mean", "moe.rows_run_pct",
+            "loop.stall_ms_per_iter", "loop.untracked_ms_per_iter", "step.mfu_pct", "device.idle_pct", "setup.prefill_s"} <= set(cell_metrics)
+    assert "cache.beyond_window_pct" not in cell_metrics and "ssm.state_mb_per_step" not in cell_metrics
+    assert "index.mb_per_step" not in harness.metric_names(h.spec["bench"], "trinity_tokens_longgen", "per_layer")

@@ -454,3 +454,70 @@ def test_expert_layer_compiles_for_v5e_at_nemotron3_widths_as_one_product_a_matr
     assert " conditional(" not in text and " while(" not in text
     assert re.search(rf"pred\[{rows},{dc.moe_intermediate_size}\]", text) and re.search(rf"pred\[{rows},{dc.hidden_size}\]", text)  # the cuts
     assert 0 < compiled.memory_analysis().temp_size_in_bytes < 240 * 2**20
+
+
+def keye_cut(sharding, envs):
+    """``(decoder config, float32 parameter shapes, the same as the rollout reads them, a carry of ``envs`` envs)`` at
+    the Keye-VL-2.0 cell's cut (``configs/algo/decoder/keye_vl2.yaml``, 18,992 ids, caches of 32,768 positions)."""
+    from sheeprl_tpu.algos.ppo_recurrent.agent import DecoderPPOAgent
+    from sheeprl_tpu.config.compose import compose
+    from sheeprl_tpu.models import decoder
+
+    model = compose(["exp=ppo_tokens", "algo/decoder@algo.decoder=keye_vl2"]).as_dict()["algo"]["decoder"]
+    dc = decoder.DecoderConfig.from_dict(model, vocab_size=18992, max_len=32768)
+    on_chip = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding), tree)  # noqa: E731
+    agent = DecoderPPOAgent(dc, ("tokens",), jnp.bfloat16)
+    params = jax.eval_shape(lambda k: decoder.init_params(dc, k), jax.random.PRNGKey(0))
+    return dc, on_chip(params), on_chip(jax.eval_shape(agent.acting_params, params)), on_chip(jax.eval_shape(lambda: agent.initial_state(envs)))
+
+
+def test_sparse_decode_step_compiles_for_v5e_at_keye_widths_and_fetches_only_the_selected_rows(one_chip):
+    """One decode step of 8 envs through the Keye-VL-2.0 cut, bf16: it compiles for the chip; each of the four sparse
+    layers writes its token's key, value and index key into the carry's own donated buffers, scores the index keys,
+    and fetches 2,048 rows of its key and value caches by a gather: no copy, transpose or fusion output of a whole
+    ``(8, 32768, 512)`` cache stands beside the carry's own, and every such cache is aliased.  (The selection is
+    XLA's ``top_k``: a sort of the 32,768 scores of each env.)"""
+    import re
+
+    from sheeprl_tpu.models import decoder
+
+    dc, _, acting, carry = keye_cut(one_chip, 8)
+    assert [x.shape for x in carry["k"]] == [(8, 32768, 512)] * 4 and [x.shape for x in carry["ik"]] == [(8, 32768, 64)] * 4
+    carry_bytes = 8 * sum(decoder.carry_bytes(dc).values())
+    assert carry_bytes == 8 * (4 * 32768 * (512 + 512 + 64) * 2 + 4)  # 285.2 MB an env
+    step = jax.jit(lambda p, c, tok, first: decoder.step(p, dc, c, tok, first, jnp.bfloat16), donate_argnums=(1,))
+    compiled = step.lower(acting, carry, _spec(one_chip, 8, dtype=jnp.int32), _spec(one_chip, 8)).compile()
+    text = compiled.as_text()
+    whole = 8 * 32768 * 512
+    standing = list(_unfused_instructions(text))
+    assert not [x for x in standing if x[2] == whole and x[0] in ("copy", "transpose", "fusion", "gather")], standing
+    fetched = re.findall(r"= bf16\[8,2048,512\]\S* gather\(", text)
+    assert len(fetched) == 2 * 4, fetched  # keys and values of each layer: the selected rows and no others
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= carry_bytes - 8 * 4  # caches and index keys in place
+    assert 0 < ma.temp_size_in_bytes < 2**30
+
+
+def test_sparse_update_and_prefill_compile_for_v5e_at_keye_widths(one_chip):
+    """One update of the Keye-VL-2.0 cut at a minibatch's size (4 envs x 256 tokens on a carried prefix of 32,768
+    positions, bf16 compute, float32 parameters): the loss with L_I and its gradient, the selection made once a layer
+    outside the recomputed layers, compiles for the chip within what the phase has room for; and the prefill of 256
+    tokens for 8 envs."""
+    from sheeprl_tpu.models import decoder
+
+    dc, params, acting, carry8 = keye_cut(one_chip, 8)
+    carry = jax.tree.map(lambda x: jax.ShapeDtypeStruct((4,) + x.shape[1:], x.dtype, sharding=one_chip), carry8)
+
+    def loss(p, c, tokens, first):
+        logits, values, load, kl = decoder.segment(p, dc, c, tokens, first, jnp.bfloat16, index_loss=True)
+        return jnp.mean(jax.nn.logsumexp(logits, -1)) + jnp.mean(values ** 2) + jnp.mean(kl), load
+
+    compiled = jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
+        params, carry, _spec(one_chip, 256, 4, dtype=jnp.int32), _spec(one_chip, 256, 4)).compile()
+    update_temp = compiled.memory_analysis().temp_size_in_bytes
+    prefill = jax.jit(lambda p, c, tok, n: decoder.segment(p, dc, c, tok, jnp.zeros(tok.shape), jnp.bfloat16, extend=True, valid=n)[3],
+                      donate_argnums=(1,))
+    compiled = prefill.lower(acting, carry8, _spec(one_chip, 256, 8, dtype=jnp.int32), _spec(one_chip, 8, dtype=jnp.int32)).compile()
+    prefill_temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"update temporaries {update_temp / 1e9:.2f} GB, prefill {prefill_temp / 1e9:.2f} GB")
+    assert 0 < update_temp < 5 * 2**30 and 0 < prefill_temp < 5 * 2**30
