@@ -25,7 +25,7 @@ import numpy as np
 
 from sheeprl_tpu.models import decoder
 from sheeprl_tpu.models.models import MLP
-from sheeprl_tpu.ops import decode_attention
+from sheeprl_tpu.ops import decode_attention, segment_attention
 from sheeprl_tpu.telemetry.recorder import RECORDER
 
 
@@ -170,7 +170,8 @@ class LSTMCore:
     The loop drives a core through these calls alone and does not know which one it holds:
     ``policy_step`` (one step on the carry), ``policy_segment`` (a rollout's segment from the carry at its
     start; a third result where the core has a router: its counts; a fourth where it has a loss of its own beside
-    PPO's: that loss per step), ``encode_prev`` (the action as the next
+    PPO's: that loss per step; a fifth where a kernel of its pass reads its carry as far as the pass needs: the
+    blocks read and held), ``encode_prev`` (the action as the next
     step's input), ``acting_params`` (the weights as the rollout reads them), ``initial_state``,
     ``stores_values`` (the rollout keeps its values: no second pass over every token), and for a core with
     more to tell or to keep: ``init_aux``/``after_update`` (state that moves with every update),
@@ -190,7 +191,7 @@ class LSTMCore:
         )
 
     def policy_segment(self, p, obs_seq, prev_actions_seq, is_first_seq, carry):
-        return self.agent.apply(p, obs_seq, prev_actions_seq, is_first_seq, carry) + (None, None)
+        return self.agent.apply(p, obs_seq, prev_actions_seq, is_first_seq, carry) + (None, None, None)
 
     def encode_prev(self, actions):
         return one_hot_actions(actions, self.agent.actions_dim, self.agent.is_continuous)
@@ -235,6 +236,8 @@ class DecoderPPOAgent:
         self.sparse = len(config.layers_of(decoder.SPARSE))  # layers that read the rows their indexer selects
         self.cache_held = sum(sizes) + self.sparse * config.max_len  # positions a decode step's attention layers hold an env
         self.ragged_sizes = [s for s in sizes if decode_attention.engages(s)]  # of the layers read as far as written
+        # a sparse layer's prefix that the update reads through `ops/segment_attention.py`, as far as its queries selected
+        self.ragged_prefix = bool(self.sparse) and segment_attention.engages(config.max_len)
         # bytes of one position's index key, which a sparse layer's decode step scores at every written position
         self.index_key_bytes = config.index_head_dim * jnp.dtype(self.carry_dtype).itemsize
         # bytes of state and convolution window a decode step reads, and writes again, of an env's Mamba-2 layers
@@ -258,9 +261,17 @@ class DecoderPPOAgent:
         return carry, (logits, value)
 
     def policy_segment(self, p, obs_seq, prev_actions_seq, is_first_seq, carry):
-        return decoder.segment(
-            p["params"], self.config, carry, obs_seq[self.key][..., 0], is_first_seq[..., 0], self.dtype, index_loss=True
+        """The segment's logits, values, router counts and L_I, and where the sparse layers' prefix goes through
+        ``segment_attention``: the key blocks its kernels read and the blocks the pass's envs held (2,)."""
+        read = []
+        out = decoder.segment(
+            p["params"], self.config, carry, obs_seq[self.key][..., 0], is_first_seq[..., 0], self.dtype, index_loss=True,
+            read=read,
         )
+        if not read:
+            return out + (None,)
+        held = obs_seq[self.key].shape[1] * len(read) * (self.config.max_len // segment_attention.BLOCK)
+        return out + (jnp.stack([sum(read), jnp.asarray(held, jnp.int32)]),)
 
     def prefill(self, p, carry, tokens, valid):
         """``carry`` with the first ``valid`` (B,) of the ``(T, B)`` ``tokens`` written into it."""
@@ -278,10 +289,14 @@ class DecoderPPOAgent:
 
     def init_aux(self) -> Dict[str, Any]:
         """What a dispatch's updates tell of the expert layers: the router's counts, summed and of the first, and the
-        sorted (token, expert) rows their grouped products visited."""
+        sorted (token, expert) rows their grouped products visited; and of the sparse layers' prefix, where
+        ``segment_attention`` reads it, the key blocks read and held."""
         counts = jnp.zeros((len(self.config.moe_layers()), self.config.num_experts), jnp.int32)
-        return {"updates": jnp.zeros((), jnp.int32), "load": counts, "first_load": counts,
-                "first_losses": jnp.zeros((3,), jnp.float32), "moe_rows_run": jnp.zeros((), jnp.int32)}
+        aux = {"updates": jnp.zeros((), jnp.int32), "load": counts, "first_load": counts,
+               "first_losses": jnp.zeros((3,), jnp.float32), "moe_rows_run": jnp.zeros((), jnp.int32)}
+        if self.ragged_prefix:
+            aux["segment_blocks"] = jnp.zeros((2,), jnp.int32)
+        return aux
 
     def rows_run(self, load, tokens: int) -> jax.Array:
         """Sorted rows the expert layers' grouped products visited in one update of ``tokens`` tokens, from its
@@ -335,6 +350,9 @@ class DecoderPPOAgent:
                   "moe_rows_run": int(stats["moe_rows_run"]), "moe_rows_all": int(routed.sum())}
         if self.sparse:  # the index keys the decode steps scored: every position each env's episode had written
             counts["index_bytes"] = int(stats["index_positions"]) * self.index_key_bytes
+        if "segment_blocks" in stats:  # positions of the sparse layers' prefix the updates' kernels read, and held
+            read, held = (int(x) * segment_attention.BLOCK for x in np.asarray(stats["segment_blocks"]))
+            counts.update(segment_read=read, segment_held=held)
         if self.ssm_bytes:  # a model with state-space layers: every env step reads each layer's state and window and writes them
             counts["ssm_state_bytes"] = steps * 2 * self.ssm_bytes
         return counts

@@ -231,7 +231,7 @@ def main(fabric: Any, cfg: Any) -> None:
             if "values" in rollout:  # graftlint: disable=trace-python-branch  (a key of the dict, not a value: the rollout kept its own values, so no second pass over every token)
                 values = rollout["values"]
             else:
-                _, values, _, _ = fwd(p, jnp.arange(B))
+                _, values, _, _, _ = fwd(p, jnp.arange(B))
                 values = values[..., 0]
             returns, advantages = gae(
                 rollout["rewards"], values, rollout["dones"], last_values, gamma, gae_lambda
@@ -249,7 +249,7 @@ def main(fabric: Any, cfg: Any) -> None:
 
                 @jax.named_scope("update.loss")
                 def loss_of(p_):
-                    a_out, new_values, load, own_loss = fwd(p_, env_idx)
+                    a_out, new_values, load, own_loss, read = fwd(p_, env_idx)
                     acts = jnp.take(rollout["actions"], env_idx, axis=1)
                     lp, ent = _dist_stats(a_out, acts, actions_dim, is_continuous)
                     adv = jnp.take(advantages, env_idx, axis=1)
@@ -273,9 +273,9 @@ def main(fabric: Any, cfg: Any) -> None:
                     if own_loss is not None:  # the core's own term (a sparse decoder's L_I), averaged as PPO's are
                         with jax.named_scope("policy.attn.index_loss"):
                             loss = loss + (jnp.mean(own_loss) if mask is None else masked_mean(own_loss, mk))
-                    return loss, ((pg, vl, el), load)
+                    return loss, ((pg, vl, el), load, read)
 
-                (_, ((pg, vl, el), load)), grads = jax.value_and_grad(loss_of, has_aux=True)(p)
+                (_, ((pg, vl, el), load, read)), grads = jax.value_and_grad(loss_of, has_aux=True)(p)
                 with jax.named_scope("update.optim"):
                     updates, o_state = optimizer.update(grads, o_state, p)
                     p = optax.apply_updates(p, updates)
@@ -283,12 +283,15 @@ def main(fabric: Any, cfg: Any) -> None:
                         p = core.after_update(p, load)
                         first = aux["updates"] == 0
                         aux = {
+                            **aux,
                             "updates": aux["updates"] + 1,
                             "load": aux["load"] + load,
                             "first_load": jnp.where(first, load, aux["first_load"]),
                             "first_losses": jnp.where(first, jnp.stack([pg, vl, el]), aux["first_losses"]),
                             "moe_rows_run": aux["moe_rows_run"] + core.rows_run(load, T * env_bs),
                         }
+                        if read is not None:  # the key blocks a sparse layer's kernels read, and those held
+                            aux["segment_blocks"] = aux["segment_blocks"] + read
                 return p, o_state, (pg, vl, el), aux
 
             p, o_state, losses, aux = jax.lax.fori_loop(
@@ -408,7 +411,8 @@ def main(fabric: Any, cfg: Any) -> None:
                 env_bs=env_bs, num_minibatches=num_minibatches,
             )
             if aux is not None:
-                stats = {**stats, **{kk: aux[kk] for kk in ("load", "first_load", "first_losses", "moe_rows_run")}}
+                told = ("load", "first_load", "first_losses", "moe_rows_run", "segment_blocks")
+                stats = {**stats, **{kk: aux[kk] for kk in told if kk in aux}}
             return p, o_state, actor, k_next, losses, stats
 
         anakin_step = fabric.compile(

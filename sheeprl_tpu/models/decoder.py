@@ -27,7 +27,9 @@ Two entry points serve the recurrent PPO loop, and share every projection:
   prefix (constants) followed by the segment's own, in query blocks, so that no
   ``T x (prefix + T) x heads`` float32 array is ever whole.  With
   ``extend=True`` it also returns the carry with the segment written into it
-  (prefill).
+  (prefill).  A sparse layer's prefix of two blocks or more is read by the
+  kernels of ``ops/segment_attention.py``, each env's only where its queries
+  selected a key, and no per-head score over the prefix reaches HBM.
 
 The carry is a pytree, per env: for every attention layer a buffer of keys and
 of values (a ring of ``sliding_window`` positions for a sliding layer,
@@ -58,7 +60,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from sheeprl_tpu.ops import decode_attention
+from sheeprl_tpu.ops import decode_attention, segment_attention
 
 Params = Dict[str, Any]
 Carry = Dict[str, Any]
@@ -536,11 +538,11 @@ def _sparse_block(B: int, T: int, S: int, heads: int) -> int:
     return qb
 
 
-def _index_kl(probs: jax.Array, scores: jax.Array, sel: jax.Array) -> jax.Array:
-    """L_I of each query: ``KL(p || softmax of the index scores over the selected keys)``, ``p`` the main attention's
-    probabilities ``probs`` (B, KV, G, T, S) summed over its heads and normalised, a constant; scores, sel (B, T, S)
-    -> (B, T) float32."""
-    p = jax.lax.stop_gradient(jnp.mean(probs, axis=(1, 2)))
+def _index_kl(p: jax.Array, scores: jax.Array, sel: jax.Array) -> jax.Array:
+    """L_I of each query: ``KL(p || softmax of the index scores over the selected keys)``, ``p`` (B, T, S) the main
+    attention's probabilities summed over its heads and normalised, a constant; scores, sel (B, T, S) -> (B, T)
+    float32."""
+    p = jax.lax.stop_gradient(p)
     log_q = jax.nn.log_softmax(jnp.where(sel, scores, -1e30), axis=-1)
     return jnp.sum(jnp.where(sel, jax.scipy.special.xlogy(p, p) - p * log_q, 0.0), axis=-1)
 
@@ -827,35 +829,76 @@ def _segment_select(layer: Params, x, pos, seg, prefix_ik, prefix_pos, dc: Decod
     return jnp.moveaxis(sel, 0, 1).reshape(visible.shape)
 
 
+def _sparse_prefix(q, k, v, prefix_k, prefix_v, sel):
+    """A sparse layer's attention where the carry's prefix (B, S, KV, D) engages ``segment_attention``: its kernel
+    attends over the prefix's selected keys, each env's only as far as some query selected one, XLA over the
+    segment's own ``T``, and the two merge through their log-sum-exp into the softmax over the same selected set.
+    Returns the output (B, T, KV * G * D) in the queries' dtype, the heads' mean of the probabilities (B, T, S + T), a
+    constant for L_I, and the key blocks of the prefix the kernels read."""
+    B, S = prefix_k.shape[:2]
+    dt = q.dtype
+    rows = lambda cache: cache.reshape(B, S, -1).astype(dt)  # noqa: E731  (the carry's rows as stored)
+    sel_p, own = sel[..., :S], sel[..., S:][:, None, None]
+    o_p, lse_p = segment_attention.attend(q, rows(prefix_k), rows(prefix_v), sel_p)
+    lse_p = jnp.moveaxis(lse_p, 1, -1)  # (B, KV, G, T): -inf where a query selected nothing on the prefix
+    scores = jnp.einsum("btkgd,bskd->bkgts", q, k, preferred_element_type=jnp.float32) / math.sqrt(q.shape[-1])
+    scores = jnp.where(own, scores, segment_attention.MASKED)
+    top = jax.lax.stop_gradient(jnp.maximum(lse_p, scores.max(axis=-1)))  # every query selected a key somewhere
+    e = jnp.exp(scores - top[..., None])
+    w = jnp.exp(lse_p - top)
+    z = w + e.sum(axis=-1)
+    probs = e / z[..., None]  # the segment's own columns of the softmax
+    o = jnp.einsum("bkgts,bskd->btkgd", probs.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    o = o + jnp.moveaxis(w / z, -1, 1)[..., None] * o_p
+    with jax.named_scope("policy.attn.index_loss"):
+        lse = jax.lax.stop_gradient(jnp.moveaxis(top + jnp.log(z), -1, 1))
+        p = jnp.concatenate([segment_attention.head_mean(q, rows(prefix_k), sel_p, lse), jnp.mean(probs, axis=(1, 2))], axis=-1)
+    return o.reshape(o.shape[:2] + (-1,)).astype(dt), p, segment_attention.blocks_read(sel_p)
+
+
 def _segment_sparse_layer(layer: Params, x, pos, prefix_k, prefix_v, prefix_ik, sel, dc: DecoderConfig):
     """One sparse attention layer over (B, T, H) rows: each query attends over the keys ``sel`` (B, T, prefix + T)
-    selected for it (a mask on the blocked product), and gives its term of L_I over them.  Returns (x', router counts
-    or None, k, v and index keys of the segment, L_I (B, T) float32)."""
+    selected for it, and gives its term of L_I over them.  A prefix that ``segment_attention`` engages on is read by
+    its kernels (:func:`_sparse_prefix`); a shorter one is a mask on the blocked product.  Returns (x', router counts
+    or None, k, v and index keys of the segment, L_I (B, T) float32, key blocks of the prefix the kernels read or
+    None)."""
     B, T, H = x.shape
     dt = x.dtype
     with jax.named_scope(_scope(SPARSE)):
         a = rms_norm(x, layer["norm_in"], dc.rms_norm_eps)
         q, k, v = _qkv(layer, a, pos, SPARSE in dc.rope_layers, dc)
-        keys = jnp.concatenate([prefix_k.astype(dt), k], axis=1)
-        values = jnp.concatenate([prefix_v.astype(dt), v], axis=1)
     with jax.named_scope("policy.attn.index"):
         qi, ki, wi = _index_qkw(layer["index"], a, pos, dc)
         index_keys = jnp.concatenate([prefix_ik.astype(dt), ki], axis=1)
 
-    def block(args):
-        q_b, qi_b, wi_b, sel_b = args
-        with jax.named_scope(_scope(SPARSE)):
-            probs = _attention_probs(q_b, keys, sel_b)
-            o_b = _weigh(probs, values)
+    def index_kl(qi_b, wi_b, sel_b, p_b):
         with jax.named_scope("policy.attn.index_loss"):
-            kl_b = _index_kl(probs, index_scores(qi_b, wi_b, index_keys), sel_b)
-        return o_b, kl_b
+            return _index_kl(p_b, index_scores(qi_b, wi_b, index_keys), sel_b)
 
-    qb = _sparse_block(B, T, keys.shape[1], max(dc.num_attention_heads, dc.index_heads))
-    o, kl = jax.lax.map(jax.checkpoint(block), tuple(_query_blocks(z, qb) for z in (q, qi, wi, sel)))
+    if segment_attention.engages(prefix_k.shape[1]):
+        with jax.named_scope(_scope(SPARSE)):
+            o, p, read = _sparse_prefix(q, k, v, prefix_k, prefix_v, sel)
+        qb = _sparse_block(B, T, index_keys.shape[1], dc.index_heads)
+        kl = jax.lax.map(jax.checkpoint(lambda args: index_kl(*args)), tuple(_query_blocks(z, qb) for z in (qi, wi, sel, p)))
+    else:
+        read = None
+        with jax.named_scope(_scope(SPARSE)):
+            keys = jnp.concatenate([prefix_k.astype(dt), k], axis=1)
+            values = jnp.concatenate([prefix_v.astype(dt), v], axis=1)
+
+        def block(args):
+            q_b, qi_b, wi_b, sel_b = args
+            with jax.named_scope(_scope(SPARSE)):
+                probs = _attention_probs(q_b, keys, sel_b)
+                o_b = _weigh(probs, values)
+            return o_b, index_kl(qi_b, wi_b, sel_b, jnp.mean(probs, axis=(1, 2)))
+
+        qb = _sparse_block(B, T, keys.shape[1], max(dc.num_attention_heads, dc.index_heads))
+        o, kl = jax.lax.map(jax.checkpoint(block), tuple(_query_blocks(z, qb) for z in (q, qi, wi, sel)))
+        o = jnp.moveaxis(o, 0, 1).reshape(B, T, -1)
     with jax.named_scope(_scope(SPARSE)):
-        x = _after_attention(layer, x, a, jnp.moveaxis(o, 0, 1).reshape(B, T, -1), dc)
-    return _segment_ffn(layer, x, dc) + (k, v, ki, jnp.moveaxis(kl, 0, 1).reshape(B, T))
+        x = _after_attention(layer, x, a, o, dc)
+    return _segment_ffn(layer, x, dc) + (k, v, ki, jnp.moveaxis(kl, 0, 1).reshape(B, T), read)
 
 
 def _segment_layer(layer: Params, x, pos, seg, prefix_k, prefix_v, prefix_pos, dc: DecoderConfig, kind: str):
@@ -908,14 +951,15 @@ def _segment_ssm_layer(layer: Params, x, pos, cuts, dt_mask, state, window, dc: 
 
 def segment(
     params: Params, dc: DecoderConfig, carry: Carry, tokens: jax.Array, is_first: jax.Array, dtype: Any,
-    extend: bool = False, valid: Optional[jax.Array] = None, index_loss: bool = False,
+    extend: bool = False, valid: Optional[jax.Array] = None, index_loss: bool = False, read: Optional[list] = None,
 ):
     """``tokens``, ``is_first`` (T, B) on the prefix cached in ``carry`` (constants: nothing is
     differentiated through them) -> (logits (T, B, V), values (T, B, 1), router counts (expert layers,
     E)), and with ``extend`` the carry that holds the segment as well.  ``valid`` (B,), with ``extend``:
     only each env's first ``valid`` tokens are real (a ragged prefill); the others are not written.
     ``index_loss`` (without ``extend``) adds L_I of every token (T, B), summed over the sparse layers (None
-    for a model without one)."""
+    for a model without one).  ``read``, a list, receives for each sparse layer whose prefix the
+    ``segment_attention`` kernels read the key blocks they fetched (an env's blocks that some query selected from)."""
     carry = jax.lax.stop_gradient(carry)
     pos_tb, seg_tb = segment_positions(is_first, carry["pos"])
     pos, seg = pos_tb.T, seg_tb.T  # (B, T)
@@ -954,8 +998,10 @@ def segment(
                 prefix_ik = carry["ik"][dc.layers_of(SPARSE).index(i)]
                 sel = _segment_select(params[f"layer_{i}"], x, pos, seg, prefix_ik, prefix_pos, dc)
                 run = jax.checkpoint(_segment_sparse_layer, static_argnums=(7,))
-                x, c, k, v, ik, layer_kl = run(params[f"layer_{i}"], x, pos, prefix_k, prefix_v, prefix_ik, sel, dc)
+                x, c, k, v, ik, layer_kl, blocks = run(params[f"layer_{i}"], x, pos, prefix_k, prefix_v, prefix_ik, sel, dc)
                 kl.append(layer_kl)
+                if read is not None and blocks is not None:
+                    read.append(blocks)
             else:
                 run = jax.checkpoint(_segment_layer, static_argnums=(7, 8))
                 x, c, k, v = run(params[f"layer_{i}"], x, pos, seg, prefix_k, prefix_v, prefix_pos, dc, kind)

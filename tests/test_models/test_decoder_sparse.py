@@ -6,6 +6,7 @@ width 32 with 2 a token under a softmax router, no shared expert; two layers, ea
 
 import importlib.util
 import json
+from unittest import mock
 from pathlib import Path
 
 import jax
@@ -15,6 +16,7 @@ import pytest
 
 from sheeprl_tpu.models import decoder
 from sheeprl_tpu.models.decoder import DecoderConfig
+from sheeprl_tpu.ops import segment_attention
 
 ROOT = Path(__file__).resolve().parents[2]
 VOCAB, MAX_LEN, TOPK = 64, 32, 6
@@ -210,6 +212,48 @@ def test_the_gradients_of_the_masked_loss_and_of_l_i_match_the_reference(params)
             assert float(jnp.abs(gi).max()) > 1e-5 and not np.asarray(gp).any(), name
         else:
             assert not np.asarray(gi).any(), name
+
+
+SMALL = 16  # a key block of the segment kernels that the tiny widths' cache holds four of
+LONG = 4 * SMALL
+
+
+def test_the_segment_kernels_and_the_masked_product_agree():
+    """The tiny sparse widths on a cache of ``LONG`` positions and kernel blocks of ``SMALL``, run twice: through
+    ``ops/segment_attention.py`` (interpret mode) and through the masked product it replaces (the kernels' rule made to
+    refuse every prefix).  Each run prefills two envs in two chunks (40 and 20 tokens, then 8 more each: the second
+    chunk reads the first's prefix), then takes one differentiated segment of 16 tokens with a reset inside it.  The
+    prefill's carry, the logits, values and L_I, and the parameters' gradient agree; only the kernel path counts the
+    blocks it read (each layer: every block the envs wrote, 3 and 2)."""
+    cfg, B, T = config(max_len=LONG), 2, 16
+    params = decoder.init_params(cfg, jax.random.PRNGKey(0))
+    tokens, first = tokens_of(31, T, B), firsts(T, B, ((5, 1),))
+    weights = jax.random.normal(jax.random.PRNGKey(32), (T, B, VOCAB))
+    prefill = jax.jit(lambda c, tok, n: decoder.segment(params, cfg, c, tok, jnp.zeros(tok.shape), jnp.float32, extend=True, valid=n)[3])
+
+    def loss(p, carry):
+        read = []
+        logits, values, _, kl = decoder.segment(p, cfg, carry, tokens, first, jnp.float32, index_loss=True, read=read)
+        blocks = jnp.stack(read) if read else jnp.zeros((0,), jnp.int32)
+        return jnp.sum(logits * weights) + jnp.sum(values ** 2) + jnp.sum(kl), (logits, values, kl, blocks)
+
+    def run():
+        carry = prefill(decoder.init_carry(cfg, B, jnp.float32), tokens_of(33, 40, B), jnp.asarray([40, 20]))
+        carry = prefill(carry, tokens_of(34, 8, B), jnp.asarray([8, 8]))
+        (_, (logits, values, kl, blocks)), grads = jax.jit(jax.value_and_grad(loss, has_aux=True))(params, carry)
+        return {"prefill_carry": carry, "logits": logits, "values": values, "index_loss": kl, "gradients": grads}, blocks
+
+    with mock.patch.object(segment_attention, "BLOCK", SMALL):
+        kernel, read = run()
+        jax.clear_caches()  # a traced layer is cached by its function: let the masked product trace anew
+        with mock.patch.object(segment_attention, "engages", lambda *args, **kwargs: False):
+            masked, unread = run()
+    jax.clear_caches()
+    for what in kernel:
+        for (path, got), want in zip(jax.tree_util.tree_leaves_with_path(kernel[what]), jax.tree.leaves(masked[what])):
+            scale = max(float(jnp.max(jnp.abs(want))), 1e-6) if what == "gradients" else 1.0
+            np.testing.assert_allclose(got / scale, want / scale, **TOL, err_msg=what + jax.tree_util.keystr(path))
+    assert read.tolist() == [5, 5] and unread.size == 0
 
 
 def test_the_sixteen_shares_add_up_to_the_whole_layer():

@@ -498,13 +498,17 @@ def test_sparse_decode_step_compiles_for_v5e_at_keye_widths_and_fetches_only_the
     assert 0 < ma.temp_size_in_bytes < 2**30
 
 
-def test_sparse_update_and_prefill_compile_for_v5e_at_keye_widths(one_chip):
+def test_sparse_update_and_prefill_compile_for_v5e_at_keye_widths(one_chip, monkeypatch):
     """One update of the Keye-VL-2.0 cut at a minibatch's size (4 envs x 256 tokens on a carried prefix of 32,768
-    positions, bf16 compute, float32 parameters): the loss with L_I and its gradient, the selection made once a layer
-    outside the recomputed layers, compiles for the chip within what the phase has room for; and the prefill of 256
-    tokens for 8 envs."""
+    positions, bf16 compute, float32 parameters), lowered as the chip lowers it (the segment kernels ask
+    ``jax.default_backend()`` whether Mosaic is there): the loss with L_I and its gradient, the selection made once a
+    layer outside the recomputed layers, each sparse layer's prefix read by the ``segment_attention`` kernels (the
+    forward, its ``dq`` and the heads' mean for L_I: in the update's program the forward runs again under the layer's
+    checkpoint), compiles for the chip within what the phase has room for; and the prefill of 256 tokens for 8 envs,
+    whose layers run the forward kernel alone (the last layer's attention gives only logits, which the prefill drops)."""
     from sheeprl_tpu.models import decoder
 
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     dc, params, acting, carry8 = keye_cut(one_chip, 8)
     carry = jax.tree.map(lambda x: jax.ShapeDtypeStruct((4,) + x.shape[1:], x.dtype, sharding=one_chip), carry8)
 
@@ -515,9 +519,17 @@ def test_sparse_update_and_prefill_compile_for_v5e_at_keye_widths(one_chip):
     compiled = jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
         params, carry, _spec(one_chip, 256, 4, dtype=jnp.int32), _spec(one_chip, 256, 4)).compile()
     update_temp = compiled.memory_analysis().temp_size_in_bytes
+    def kernels(text):  # the custom calls by kernel, from their instructions' names: forward, dq, the heads' mean
+        names = [line.split(" = ")[0] for line in text.splitlines() if "custom-call(" in line]
+        count = lambda *parts: sum(all(p in n for p in parts) for n in names)  # noqa: E731
+        return count("segment_attention") - count("segment_attention_dq"), count("segment_attention_dq"), count("segment_head_mean")
+
+    forward, dq, head_mean = kernels(compiled.as_text())
+    assert forward >= 4 and dq == 4 and head_mean >= 4
     prefill = jax.jit(lambda p, c, tok, n: decoder.segment(p, dc, c, tok, jnp.zeros(tok.shape), jnp.bfloat16, extend=True, valid=n)[3],
                       donate_argnums=(1,))
     compiled = prefill.lower(acting, carry8, _spec(one_chip, 256, 8, dtype=jnp.int32), _spec(one_chip, 8, dtype=jnp.int32)).compile()
     prefill_temp = compiled.memory_analysis().temp_size_in_bytes
+    assert kernels(compiled.as_text()) == (3, 0, 0)  # the last layer's attention reaches nothing the prefill keeps
     print(f"update temporaries {update_temp / 1e9:.2f} GB, prefill {prefill_temp / 1e9:.2f} GB")
     assert 0 < update_temp < 5 * 2**30 and 0 < prefill_temp < 5 * 2**30
